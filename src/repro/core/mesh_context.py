@@ -24,7 +24,6 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
@@ -54,7 +53,7 @@ def migrate_tokens(
         me = jax.lax.axis_index("pod")
         return jnp.where(me == dst_pod, moved, buf)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=P("pod", None, None),
         out_specs=P("pod", None, None),
@@ -80,7 +79,7 @@ def migrate_kv_cache(
             me = jax.lax.axis_index("pod")
             return jnp.where(me == dst_pod, moved, x)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=P(*(("pod",) + (None,) * (nd - 1))),
             out_specs=P(*(("pod",) + (None,) * (nd - 1))),

@@ -12,11 +12,13 @@ page bounds are scalar-prefetch operands, the K/V BlockSpec index maps
 dereference ``table[b, p]`` directly, and each grid step DMAs one physical
 page into VMEM — no dense ``max_len``-width intermediate ever exists.
 
-Grid: ``(batch, kv_heads, MP)`` — page-blocks innermost, identical to the
-decode kernel. The only difference is the query block: S chunk rows × G
-query heads share each page load, carried as one ``(S*G, Dh)`` block with
-``(m, l, acc)`` online-softmax scratch persisting across the page
-dimension.
+Grid: ``(batch, MP)`` — page-blocks innermost, identical to the decode
+kernel, with the same whole-page ``(1, ps, KV, Dh)`` K/V blocks and the same
+per-page body (``paged_attention.kernel._attend_page``, a static loop over
+the KV heads). The only difference is the query block: S chunk rows × G
+query heads share each page load, carried per KV head as one ``(S*G, Dh)``
+block with ``(m, l, acc)`` online-softmax scratch persisting across the
+page dimension.
 
 Masking needs no ``kv_pos`` input at all: chunked prefill preserves the
 layout invariant (slot index == absolute position, written contiguously),
@@ -42,20 +44,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from ..paged_attention.kernel import NEG_INF, _attend_page
 
 
 def _chunked_prefill_kernel(
     table_ref, bound_ref, p0_ref,       # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref,                # tensor blocks
+    row_ref, q_ref, k_ref, v_ref,       # tensor blocks
     o_ref,                              # output
     acc_ref, m_ref, l_ref,              # VMEM scratch (persist over ip)
-    *, n_pb: int, g: int, ps: int, window: int, softcap: float, scale: float,
+    *, n_pb: int, kvh: int, ps: int, window: int, softcap: float,
+    scale: float,
 ):
-    """One lane x one KV head x one page: S*G query rows of online softmax
+    """One lane x one page, every KV head: S*G query rows of online softmax
     against the page's ps keys, causally masked per chunk row."""
     bi = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -65,44 +68,23 @@ def _chunked_prefill_kernel(
 
     @pl.when(ip < bound_ref[bi])
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # (S*G, Dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (ps, Dh) — one page
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        sg = q.shape[0]
-
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                       # (S*G, ps)
-        if softcap > 0.0:
-            logits = softcap * jnp.tanh(logits / softcap)
-
-        # row r of the chunk queries absolute position p0 + r; page ip holds
-        # absolute positions [ip*ps, (ip+1)*ps) — the layout invariant
-        row = jax.lax.broadcasted_iota(jnp.int32, (sg, ps), 0) // g
-        qp = p0_ref[bi] + row                           # (S*G, ps)
-        kp = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (sg, ps), 1)
-        mask = kp <= qp
+        # row r of the chunk queries absolute position p0 + r // G; page ip
+        # holds absolute positions [ip*ps, (ip+1)*ps) — the layout invariant
+        qp = p0_ref[bi] + row_ref[...]                  # (S*G, 1)
+        kp = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        mask = kp <= qp                                 # (S*G, ps)
         if window > 0:
             mask = mask & (qp - kp < window)
-        logits = jnp.where(mask, logits, NEG_INF)
-
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # p is zeroed on masked slots so a row with no visible key yet
-        # accumulates l == 0 and finalizes to exact zeros (bound-independent)
-        p = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        _attend_page(
+            q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
+            kvh=kvh, softcap=softcap, scale=scale,
         )
-        m_ref[...] = m_new
 
     @pl.when(ip == n_pb - 1)
     def _finalize():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def chunked_prefill_pallas(
@@ -123,34 +105,40 @@ def chunked_prefill_pallas(
     mp = page_table.shape[1]
     scale = 1.0 / (dh ** 0.5)
 
-    def page_map(bi, hi, ip, table, bound, p0_):
+    def page_map(bi, ip, table, bound, p0_):
         # beyond-bound steps re-map to the lane's last real page: the block
         # index repeats, so the pipeline skips the DMA and table padding
         # (the scratch page) is never dereferenced for an active lane
-        return (table[bi, jnp.minimum(ip, bound[bi] - 1)], 0, hi, 0)
+        return (table[bi, jnp.minimum(ip, bound[bi] - 1)], 0, 0, 0)
 
-    def lane_map(bi, hi, ip, table, bound, p0_):
-        return (bi, hi, 0, 0)
+    def lane_map(bi, ip, table, bound, p0_):
+        return (bi, 0, 0, 0)
+
+    def rows_map(bi, ip, table, bound, p0_):
+        return (0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, mp),
+        grid=(b, mp),
         in_specs=[
-            pl.BlockSpec((1, 1, sg, dh), lane_map),
-            pl.BlockSpec((1, ps, 1, dh), page_map),
-            pl.BlockSpec((1, ps, 1, dh), page_map),
+            pl.BlockSpec((sg, 1), rows_map),
+            pl.BlockSpec((1, kvh, sg, dh), lane_map),
+            pl.BlockSpec((1, ps, kvh, dh), page_map),
+            pl.BlockSpec((1, ps, kvh, dh), page_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, sg, dh), lane_map),
+        out_specs=pl.BlockSpec((1, kvh, sg, dh), lane_map),
         scratch_shapes=[
-            pltpu.VMEM((sg, dh), jnp.float32),
-            pltpu.VMEM((sg, 1), jnp.float32),
-            pltpu.VMEM((sg, 1), jnp.float32),
+            pltpu.VMEM((kvh, sg, dh), jnp.float32),
+            pltpu.VMEM((kvh, sg, 1), jnp.float32),
+            pltpu.VMEM((kvh, sg, 1), jnp.float32),
         ],
     )
     kern = functools.partial(
         _chunked_prefill_kernel,
-        n_pb=mp, g=g, ps=ps, window=window, softcap=softcap, scale=scale,
+        n_pb=mp, kvh=kvh, ps=ps, window=window, softcap=softcap, scale=scale,
     )
+    # chunk row offset of each query row (rows are chunk-major, G heads each)
+    rows = (jnp.arange(sg, dtype=jnp.int32) // g)[:, None]
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -158,5 +146,5 @@ def chunked_prefill_pallas(
         interpret=interpret,
     )(
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
-        p0.astype(jnp.int32), q, pool_k, pool_v,
+        p0.astype(jnp.int32), rows, q, pool_k, pool_v,
     )

@@ -7,7 +7,13 @@ processed together — one (G, BK) logit tile per step keeps the MXU busy at
 GQA group sizes ≥ 8 and amortizes the K/V block loads across the group
 (HBM-bandwidth-bound regime, so K/V bytes are the roofline currency).
 
-Grid: (batch, kv_heads, n_kv_blocks) — KV innermost.
+Grid: (batch, n_kv_blocks) — KV innermost. Each step's K/V block is
+``(1, BK, KV, Dh)``: its trailing ``(KV, Dh)`` equals the cache's, which is
+what Mosaic's tiling rule asks of the last two block dims, and one DMA
+serves every head; the kernel loops over the KV heads inside (a static
+unroll). The query position is a scalar-prefetch operand, and the slot
+positions and validity take a unit middle axis, ``(B, 1, T)``, so their
+``(1, 1, BK)`` blocks satisfy the same rule.
 """
 
 from __future__ import annotations
@@ -23,13 +29,15 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    qpos_ref, kvpos_ref, valid_ref,
+    qpos_ref,                                       # scalar prefetch (SMEM)
+    kvpos_ref, valid_ref,
     q_ref, k_ref, v_ref,
     o_ref,
     acc_ref, m_ref, l_ref,
-    *, nk: int, window: int, softcap: float, scale: float,
+    *, nk: int, kvh: int, window: int, softcap: float, scale: float,
 ):
-    ik = pl.program_id(2)
+    bi = pl.program_id(0)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -37,39 +45,40 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :].astype(jnp.float32)      # (G, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (BK, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                       # (G, BK)
-    if softcap > 0.0:
-        logits = softcap * jnp.tanh(logits / softcap)
-
-    qp = qpos_ref[0, 0]                             # scalar position
-    kp = kvpos_ref[0, :]                            # (BK,)
-    ok = valid_ref[0, :]
+    qp = qpos_ref[bi]                               # scalar position
+    kp = kvpos_ref[0]                               # (1, BK)
+    ok = valid_ref[0]
     mask = (kp <= qp) & (ok != 0)
     if window > 0:
         mask = mask & (qp - kp < window)
-    logits = jnp.where(mask[None, :], logits, NEG_INF)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
-    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    for h in range(kvh):
+        q = q_ref[0, h].astype(jnp.float32)         # (G, Dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)   # (BK, Dh)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                   # (G, BK)
+        if softcap > 0.0:
+            logits = softcap * jnp.tanh(logits / softcap)
+        logits = jnp.where(jnp.broadcast_to(mask, logits.shape), logits, NEG_INF)
+
+        m_prev, l_prev = m_ref[h], l_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[h] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -91,26 +100,34 @@ def decode_attention_pallas(
     nk = t // block_k
     scale = 1.0 / (dh ** 0.5)
     kern = functools.partial(
-        _decode_kernel, nk=nk, window=window, softcap=softcap, scale=scale
+        _decode_kernel, nk=nk, kvh=kvh, window=window, softcap=softcap,
+        scale=scale,
+    )
+    slots = lambda bi, ki, qpos: (bi, 0, ki)         # noqa: E731
+    lane = lambda bi, ki, qpos: (bi, 0, 0, 0)        # noqa: E731
+    block = lambda bi, ki, qpos: (bi, ki, 0, 0)      # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_k), slots),
+            pl.BlockSpec((1, 1, block_k), slots),
+            pl.BlockSpec((1, kvh, g, dh), lane),
+            pl.BlockSpec((1, block_k, kvh, dh), block),
+            pl.BlockSpec((1, block_k, kvh, dh), block),
+        ],
+        out_specs=pl.BlockSpec((1, kvh, g, dh), lane),
+        scratch_shapes=[
+            pltpu.VMEM((kvh, g, dh), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+        ],
     )
     return pl.pallas_call(
         kern,
-        grid=(b, kvh, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, hi, ki: (bi, 0)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, dh), lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, dh), lambda bi, hi, ki: (bi, ki, hi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), lambda bi, hi, ki: (bi, hi, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32),
-      kv_valid.astype(jnp.int32), q, k, v)
+    )(q_pos.astype(jnp.int32).reshape(b),
+      kv_pos.astype(jnp.int32).reshape(b, 1, t),
+      kv_valid.astype(jnp.int32).reshape(b, 1, t), q, k, v)

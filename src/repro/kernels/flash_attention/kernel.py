@@ -7,9 +7,18 @@ implementation carries them in registers/SMEM. Block shapes are MXU-aligned
 (q/kv blocks of 128 × head_dim) and all masking is position-based so the same
 kernel serves full-causal, sliding-window, and padded sequences.
 
-Grid: (batch, q_heads, n_q_blocks, n_kv_blocks) — KV innermost.
-GQA is handled in the index maps: the KV block for query head h comes from
-kv head h // group_size, so no K/V replication is materialized in HBM.
+Grid: (batch, n_q_blocks, n_kv_blocks) — KV innermost. The q/K/V blocks
+are ``(1, block, heads, Dh)``: their trailing ``(heads, Dh)`` equals the
+arrays', which is what Mosaic's tiling rule asks of the last two block
+dims, and the kernel loops over the query heads inside (a static unroll).
+GQA is handled in that loop: query head h reads kv head h // group_size of
+the same K/V block, so no K/V replication is materialized in HBM. Query
+positions take a trailing unit axis, ``(B, S, 1)``, and slot positions and
+validity a unit middle axis, ``(B, 1, T)``, so their blocks satisfy the
+same rule and arrive as a column and a row of the logit tile. The output
+is written head-major, ``(B, H, S, Dh)`` (Mosaic loads one head of a
+``(BQ, H, Dh)`` block but cannot store one), and the wrapper puts the heads
+back in place.
 """
 
 from __future__ import annotations
@@ -29,9 +38,9 @@ def _flash_kernel(
     q_ref, k_ref, v_ref,                   # tensor blocks
     o_ref,                                  # output block
     acc_ref, m_ref, l_ref,                  # VMEM scratch (persist over ik)
-    *, nk: int, window: int, softcap: float, scale: float,
+    *, nk: int, g: int, window: int, softcap: float, scale: float,
 ):
-    ik = pl.program_id(3)
+    ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -39,42 +48,44 @@ def _flash_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)     # (BQ, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)     # (BK, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)     # (BK, Dh)
-
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                      # (BQ, BK)
-    if softcap > 0.0:
-        logits = softcap * jnp.tanh(logits / softcap)
-
-    qp = qpos_ref[0, :]                            # (BQ,)
-    kp = kvpos_ref[0, :]                           # (BK,)
-    ok = valid_ref[0, :]
-    mask = (kp[None, :] <= qp[:, None]) & (ok[None, :] != 0)
+    qp = qpos_ref[0]                               # (BQ, 1)
+    kp = kvpos_ref[0]                              # (1, BK)
+    ok = valid_ref[0]
+    mask = (kp <= qp) & (ok != 0)                  # (BQ, BK)
     if window > 0:
-        mask = mask & (qp[:, None] - kp[None, :] < window)
-    logits = jnp.where(mask, logits, NEG_INF)
+        mask = mask & (qp - kp < window)
 
-    m_prev = m_ref[...]                            # (BQ, 1)
-    l_prev = l_ref[...]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)                    # (BQ, BK)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    for h in range(q_ref.shape[2]):
+        q = q_ref[0, :, h, :].astype(jnp.float32)       # (BQ, Dh)
+        k = k_ref[0, :, h // g, :].astype(jnp.float32)  # (BK, Dh)
+        v = v_ref[0, :, h // g, :].astype(jnp.float32)  # (BK, Dh)
+
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                  # (BQ, BK)
+        if softcap > 0.0:
+            logits = softcap * jnp.tanh(logits / softcap)
+        logits = jnp.where(mask, logits, NEG_INF)
+
+        m_prev = m_ref[h]                          # (BQ, 1)
+        l_prev = l_ref[h]
+        m_cur = jnp.max(logits, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)                # (BQ, BK)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[h] = m_new
+        l_ref[h] = l_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)            # fully-masked rows -> 0
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for h in range(q_ref.shape[2]):
+            l = l_ref[h]
+            l = jnp.where(l == 0.0, 1.0, l)        # fully-masked rows -> 0
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -98,36 +109,32 @@ def flash_attention_pallas(
     nq, nk = s // block_q, t // block_k
     scale = 1.0 / (dh ** 0.5)
 
-    grid = (b, h, nq, nk)
+    grid = (b, nq, nk)
     kern = functools.partial(
-        _flash_kernel, nk=nk, window=window, softcap=softcap, scale=scale
+        _flash_kernel, nk=nk, g=g, window=window, softcap=softcap, scale=scale
     )
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, hi, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, qi, ki: (bi, ki)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, qi, ki: (bi, ki)),
-            pl.BlockSpec(
-                (1, block_q, 1, dh), lambda bi, hi, qi, ki: (bi, qi, hi, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, 1, dh), lambda bi, hi, qi, ki, _g=g: (bi, ki, hi // _g, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, 1, dh), lambda bi, hi, qi, ki, _g=g: (bi, ki, hi // _g, 0)
-            ),
+            pl.BlockSpec((1, block_q, 1), lambda bi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, block_q, h, dh), lambda bi, qi, ki: (bi, qi, 0, 0)),
+            pl.BlockSpec((1, block_k, kvh, dh), lambda bi, qi, ki: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, block_k, kvh, dh), lambda bi, qi, ki: (bi, ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_q, 1, dh), lambda bi, hi, qi, ki: (bi, qi, hi, 0)
+            (1, h, block_q, dh), lambda bi, qi, ki: (bi, 0, qi, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, dh), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((h, block_q, dh), jnp.float32),
+            pltpu.VMEM((h, block_q, 1), jnp.float32),
+            pltpu.VMEM((h, block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32),
-      kv_valid.astype(jnp.int32), q, k, v)
+    )(q_pos.astype(jnp.int32).reshape(b, s, 1),
+      kv_pos.astype(jnp.int32).reshape(b, 1, t),
+      kv_valid.astype(jnp.int32).reshape(b, 1, t), q, k, v
+      ).transpose(0, 2, 1, 3)

@@ -145,11 +145,20 @@ def _moe_forward_shard_map(
     cost to a dense row-parallel MLP (one (T_loc, D) all-reduce per layer).
     A first iteration used all_to_all as if tokens were model-sharded; with
     replicated x that ships msize identical copies (measured 16× FLOP and
-    a2a inflation) — see EXPERIMENTS.md §Perf."""
-    from jax.experimental.shard_map import shard_map
+    a2a inflation) — see EXPERIMENTS.md §Perf.
+
+    The shard_map runs over an Auto-typed view of the rules' mesh. The
+    model's sharding is GSPMD-style (``pjit_rules.constrain``), and
+    ``jax.make_mesh`` now defaults to Explicit axes: over those, the output
+    would carry a data-sharded type into the rest of the model, and the
+    weight gradient of the unembedding, a contraction over that sharded
+    batch, has no unambiguous output sharding."""
+    from jax.sharding import AxisType, Mesh
     from jax.sharding import PartitionSpec as P
 
     mesh = rules["_mesh"]
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     dp = rules.get("batch") or ("data",)
     model_axis = "model"
     msize = mesh.shape[model_axis]
@@ -210,12 +219,12 @@ def _moe_forward_shard_map(
     weights = (p["w_up"], p["w_gate"], p["w_down"]) if has_gate else (
         p["w_up"], p["w_down"]
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None)) + (expert_spec,) * len(weights),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, p["router"], *weights)
     return out, aux.astype(jnp.float32)

@@ -11,7 +11,8 @@ runs afterwards — pages ARE the prefill destination.
 Compile bounding: chunk token widths are bucketed to power-of-two multiples
 of the page size and the attention table width to a power-of-two page
 count, so the jitted chunk function compiles at most
-O(log(max_chunk/page_size) * log(max_pages)) shapes. ``n_skip`` (leading
+O(log(max_chunk/page_size) * log(max_pages)) shapes — once per model
+configuration and device, shared by every prefiller. ``n_skip`` (leading
 read-only shared-prefix pages) is a traced scalar, not a compile key.
 
 The per-call unit is one B=1 chunk (``run_chunk``): the batched scheduler
@@ -22,8 +23,8 @@ engine and prime paths drain a whole suffix in one loop (``prefill_ids``).
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from functools import lru_cache, partial
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,19 @@ import numpy as np
 from ..models import ModelConfig
 from ..models.prefill import prefill_chunk_paged
 from .paged_kv import PagedKVAllocator
+
+
+@lru_cache(maxsize=None)
+def _chunk_fn(cfg: ModelConfig):
+    """The jitted chunk step of one model configuration."""
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def fn(params, pools, table, tokens, p0, true_len, n_skip):
+        return prefill_chunk_paged(
+            params, cfg, pools, table, tokens, p0, true_len, n_skip=n_skip,
+        )
+
+    return fn
 
 
 def _pow2(n: int) -> int:
@@ -54,23 +68,7 @@ class PagedPrefiller:
         self.cfg = cfg
         self.params = params
         self.alloc = allocator
-        self._fns: Dict[Tuple[int, int], object] = {}
-
-    def _fn(self, s: int, mp: int):
-        """Jitted chunk step for (chunk width s, table width mp pages)."""
-        key = (s, mp)
-        if key not in self._fns:
-            cfg = self.cfg
-
-            @partial(jax.jit, donate_argnums=(1,))
-            def fn(params, pools, table, tokens, p0, true_len, n_skip):
-                return prefill_chunk_paged(
-                    params, cfg, pools, table, tokens, p0, true_len,
-                    n_skip=n_skip,
-                )
-
-            self._fns[key] = fn
-        return self._fns[key]
+        self._fn = _chunk_fn(cfg)
 
     def run_chunk(
         self,
@@ -100,10 +98,11 @@ class PagedPrefiller:
         table = alloc.table_for(list(pages)[:mp], mp * ps)
         toks = np.zeros((1, s), np.int32)
         toks[0, :c] = np.asarray(chunk_ids, np.int32) % self.cfg.vocab_size
-        logits, pools = self._fn(s, mp)(
-            self.params, alloc.pools, jnp.asarray(table)[None, :],
-            jnp.asarray(toks), jnp.array([p0], jnp.int32),
-            jnp.array([c], jnp.int32), jnp.int32(n_skip),
+        # host-built inputs go straight to the pool's device
+        host = (table[None, :], toks, np.array([p0], np.int32),
+                np.array([c], np.int32), np.int32(n_skip))
+        logits, pools = self._fn(
+            self.params, alloc.pools, *jax.device_put(host, alloc.device)
         )
         alloc.pools = pools
         return logits[0]

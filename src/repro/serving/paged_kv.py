@@ -125,6 +125,7 @@ class PagedKVAllocator:
         n_pages: int = 256,
         dtype=None,
         share_prefixes: bool = True,
+        device: Optional[jax.Device] = None,
     ) -> None:
         assert supports_append(cfg), (
             "paged KV requires full-cache dense/moe groups "
@@ -136,16 +137,21 @@ class PagedKVAllocator:
         self.n_pages = n_pages
         self.share_prefixes = share_prefixes
         self.index = PrefixPageIndex()
-        self.pools: List[Dict[str, jnp.ndarray]] = [
-            init_paged_pool(cfg, spec.n_blocks, n_pages, page_size, dtype)
-            for spec in layer_groups(cfg)
-        ]
+        # the pool is committed to the service's device, so every jitted
+        # step that takes it runs there
+        self.device = device if device is not None else jax.devices()[0]
+        with jax.default_device(self.device):
+            self.pools: List[Dict[str, jnp.ndarray]] = jax.device_put([
+                init_paged_pool(cfg, spec.n_blocks, n_pages, page_size, dtype)
+                for spec in layer_groups(cfg)
+            ], self.device)
         # page 0 reserved as scratch; LIFO free list keeps reuse warm
         self._free: List[int] = list(range(n_pages - 1, 0, -1))
         self._ref = np.zeros(n_pages, np.int32)
         self._gather_fns: Dict[int, object] = {}
         self._scatter_fns: Dict[int, object] = {}
         self._copy_page_fn = None
+        self._import_page_fn = None
 
     # -- accounting -----------------------------------------------------
     @property
@@ -343,21 +349,34 @@ class PagedKVAllocator:
         layer against the token ground truth."""
         assert page != SCRATCH_PAGE, "refusing to import into the scratch page"
         off = 0
-        new_pools: List[Dict[str, jnp.ndarray]] = []
+        blocks: List[Dict[str, np.ndarray]] = []
         for pool in self.pools:
-            entry: Dict[str, jnp.ndarray] = {}
+            entry: Dict[str, np.ndarray] = {}
             for name in ("k", "v"):
                 a = pool[name]
                 shape = (a.shape[0],) + tuple(a.shape[2:])  # (L, ps, KV, Dh)
                 n_bytes = int(np.prod(shape)) * a.dtype.itemsize
-                block = np.frombuffer(
+                entry[name] = np.frombuffer(
                     data[off : off + n_bytes], dtype=a.dtype
                 ).reshape(shape)
                 off += n_bytes
-                entry[name] = a.at[:, page].set(jnp.asarray(block))
-            new_pools.append(entry)
+            blocks.append(entry)
         assert off == len(data), (off, len(data), self.page_bytes)
-        self.pools = new_pools
+        if self._import_page_fn is None:
+            # donated and jitted like copy_page: an eager .at[].set would
+            # hold a second copy of the whole pool per imported page
+
+            @partial(jax.jit, donate_argnums=(0,))
+            def fn(pools, blocks, d):
+                return [
+                    {n: pool[n].at[:, d].set(b[n]) for n in ("k", "v")}
+                    for pool, b in zip(pools, blocks)
+                ]
+
+            self._import_page_fn = fn
+        self.pools = self._import_page_fn(
+            self.pools, jax.device_put(blocks, self.device), jnp.int32(page)
+        )
 
     def write_through(
         self, pages: Sequence[int], dense: List[Dict], n_skip: int = 0

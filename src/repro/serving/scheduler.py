@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -75,6 +75,34 @@ from .session_cache import (
     longest_common_prefix,
     warm_source_of,
 )
+
+
+@lru_cache(maxsize=None)
+def _server_fns(cfg: ModelConfig, max_len: int):
+    """The jitted steps of a :class:`BatchedServer`, shared by every server
+    of one configuration: nodes that serve the same model trace and compile
+    each shape once per device, not once per node."""
+
+    @jax.jit
+    def prefill_one(params, tokens, true_len):
+        return prefill(params, cfg, tokens, max_len=max_len, true_len=true_len)
+
+    @jax.jit
+    def append_one(params, caches, tokens, p0, true_len):
+        return prefill_append(params, cfg, caches, tokens, p0, true_len=true_len)
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def decode(params, caches, tokens, pos):
+        return decode_step(params, cfg, caches, tokens, pos)
+
+    @partial(jax.jit, donate_argnums=(1, 3))
+    def decode_paged(params, pools, table, kv_pos, tokens, pos,
+                     shared_pages=None):
+        return decode_step_paged(
+            params, cfg, pools, table, kv_pos, tokens, pos, shared_pages,
+        )
+
+    return prefill_one, append_one, decode, decode_paged
 
 
 @dataclass
@@ -141,11 +169,16 @@ class BatchedServer:
         kv_pages: Optional[int] = None,
         share_prefixes: bool = True,
         prefill_chunk_tokens: Optional[int] = 64,
+        device: Optional[jax.Device] = None,
     ) -> None:
         assert cfg.attn_variant == "full" and cfg.arch_type in ("dense", "moe", "vlm"), (
             "batched server currently supports full-cache attention archs"
         )
-        self.cfg, self.params = cfg, params
+        # params, caches and page pool are committed to one device, so the
+        # jitted prefill and decode steps run there (one chip per node in a
+        # multi-chip fleet); host-built step inputs are put there directly
+        self.device = device if device is not None else jax.devices()[0]
+        self.cfg, self.params = cfg, jax.device_put(params, self.device)
         self.n_slots, self.max_len = n_slots, max_len
         self.stop_tokens = set(stop_tokens)
         self.session_pool = session_pool
@@ -162,6 +195,8 @@ class BatchedServer:
         self._submit_times: Dict[int, float] = {}
         self._next_tok = np.zeros((n_slots,), np.int32)
         self._req_seq = 0
+        # decode steps that ran the shared-prefix (cascade) pass
+        self.cascade_steps = 0
 
         if paged:
             # Block-granular KV: one shared page pool backs every decode
@@ -178,7 +213,7 @@ class BatchedServer:
                 kv_pages = 1 + (n_slots + cap) * (max_len // page_size)
             self.allocator = PagedKVAllocator(
                 cfg, page_size=page_size, n_pages=kv_pages,
-                share_prefixes=share_prefixes,
+                share_prefixes=share_prefixes, device=self.device,
             )
             if session_pool is not None:
                 assert session_pool.allocator is None, (
@@ -194,49 +229,30 @@ class BatchedServer:
             self._table = np.full(
                 (n_slots, max_len // page_size), SCRATCH_PAGE, np.int32
             )
-            self._kv_pos = jnp.full((n_slots, max_len), -1, jnp.int32)
+            self._kv_pos = self._put(np.full((n_slots, max_len), -1, np.int32))
             # chunked-prefill machinery: one driver shared by all lanes, a
             # strict-FIFO drain order over mid-prefill slots, and an iota
             # row for setting a lane's kv_pos once its plan completes
-            self._prefiller = PagedPrefiller(cfg, params, self.allocator)
+            self._prefiller = PagedPrefiller(cfg, self.params, self.allocator)
             self._prefill_fifo: List[int] = []
-            self._iota = jnp.arange(max_len, dtype=jnp.int32)
-
-            @partial(jax.jit, donate_argnums=(1, 3))
-            def _decode_paged(params, pools, table, kv_pos, tokens, pos,
-                              shared_pages=None):
-                return decode_step_paged(
-                    params, cfg, pools, table, kv_pos, tokens, pos,
-                    shared_pages,
-                )
-
-            self._decode_paged = _decode_paged
+            self._iota = self._put(np.arange(max_len, dtype=np.int32))
         else:
             self.allocator = None
-            self.caches = make_decode_caches(
-                cfg, n_slots, max_len,
-                dtype=jnp.float32 if cfg.compute_dtype == "float32" else None,
-            )
+            with jax.default_device(self.device):
+                self.caches = self._put(make_decode_caches(
+                    cfg, n_slots, max_len,
+                    dtype=jnp.float32 if cfg.compute_dtype == "float32" else None,
+                ))
 
-        @jax.jit
-        def _prefill_one(params, tokens, true_len):
-            return prefill(params, cfg, tokens, max_len=max_len, true_len=true_len)
-
-        @jax.jit
-        def _append_one(params, caches, tokens, p0, true_len):
-            return prefill_append(params, cfg, caches, tokens, p0, true_len=true_len)
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def _decode(params, caches, tokens, pos):
-            return decode_step(params, cfg, caches, tokens, pos)
-
-        self._prefill_one = _prefill_one
-        self._append_one = _append_one
-        self._decode = _decode
+        (self._prefill_one, self._append_one, self._decode,
+         self._decode_paged) = _server_fns(cfg, max_len)
         # host-side so mid-prefill lanes can be excluded from decode writes
         # (their entry is pushed past the trimmed table per step) without a
         # device round-trip per lane
         self._pos = np.zeros((n_slots,), np.int32)
+
+    def _put(self, x):
+        return jax.device_put(x, self.device)
 
     # ------------------------------------------------------------------
     def submit(
@@ -388,7 +404,7 @@ class BatchedServer:
         toks = np.zeros((1, s), np.int32)
         toks[0, :n] = np.asarray(ids, np.int32) % self.cfg.vocab_size
         return self._prefill_one(
-            self.params, jnp.asarray(toks), jnp.array([n], jnp.int32)
+            self.params, *self._put((toks, np.array([n], np.int32)))
         )
 
     # -- paged admission ------------------------------------------------
@@ -806,7 +822,7 @@ class BatchedServer:
             ]
             if not ready:
                 return  # every occupied lane is mid-prefill
-            tokens = jnp.asarray(self._next_tok)[:, None]
+            tokens = self._put(self._next_tok[:, None])
             # page-width bucketing: run the jitted decode at the smallest
             # power-of-two page width covering the longest *decode-ready*
             # lane, not at max_len — the kernel's grid (pallas) or the
@@ -839,24 +855,24 @@ class BatchedServer:
             if self.cfg.attn_impl == "pallas":
                 run = self._shared_prefix_run(w)
                 if run:
-                    sp = jnp.asarray(np.asarray(run, np.int32))
+                    sp = self._put(np.asarray(run, np.int32))
+                    self.cascade_steps += 1
             if w < mp:
                 logits, pools, kvp = self._decode_paged(
                     self.params, self.allocator.pools,
-                    jnp.asarray(self._table[:, :w]),
-                    self._kv_pos[:, :wp], tokens, jnp.asarray(dec_pos), sp,
+                    self._put(self._table[:, :w]),
+                    self._kv_pos[:, :wp], tokens, self._put(dec_pos), sp,
                 )
                 self._kv_pos = self._kv_pos.at[:, :wp].set(kvp)
             else:
                 logits, pools, self._kv_pos = self._decode_paged(
-                    self.params, self.allocator.pools, jnp.asarray(self._table),
-                    self._kv_pos, tokens, jnp.asarray(dec_pos), sp,
+                    self.params, self.allocator.pools, self._put(self._table),
+                    self._kv_pos, tokens, self._put(dec_pos), sp,
                 )
             self.allocator.pools = pools
         else:
-            tokens = jnp.asarray(self._next_tok)[:, None]
             logits, self.caches = self._decode(
-                self.params, self.caches, tokens, jnp.asarray(self._pos)
+                self.params, self.caches, *self._put((self._next_tok[:, None], self._pos))
             )
         nxt = np.asarray(sample(logits[:, 0]))
         now = time.perf_counter()
@@ -1034,8 +1050,17 @@ class BatchedLLMService:
         kv_pages: Optional[int] = None,
         share_prefixes: bool = True,
         prefill_chunk_tokens: Optional[int] = 64,
+        device: Optional[jax.Device] = None,
+        params=None,
     ) -> "BatchedLLMService":
-        params = init_params(jax.random.key(seed), cfg)
+        """``device`` (default ``jax.devices()[0]``) is where the service's
+        params and KV live and its steps run. ``params`` lets services that
+        share a device share one copy of the seeded weights; by default
+        they are made from ``seed`` on that device."""
+        device = device if device is not None else jax.devices()[0]
+        if params is None:
+            with jax.default_device(device):
+                params = init_params(jax.random.key(seed), cfg)
         pool = (
             SessionCachePool(capacity=session_cache_capacity)
             if session_cache_capacity > 0 and supports_append(cfg)
@@ -1045,7 +1070,7 @@ class BatchedLLMService:
             cfg, params, n_slots=n_slots, max_len=max_len, session_pool=pool,
             paged=paged and supports_append(cfg), page_size=page_size,
             kv_pages=kv_pages, share_prefixes=share_prefixes,
-            prefill_chunk_tokens=prefill_chunk_tokens,
+            prefill_chunk_tokens=prefill_chunk_tokens, device=device,
         )
         tok = get_tokenizer(cfg.vocab_size, seed=tokenizer_seed, name=model)
         return cls(model=model, server=server, tokenizer=tok)
@@ -1063,6 +1088,28 @@ class BatchedLLMService:
         return self.server.prime(cache_key, list(token_ids))
 
     # -- KV-page shipping hooks (repro.store.kv_ship) -------------------
+    def measure_prefill_ms_per_token(self, n_tokens: int = 256) -> float:
+        """Measure this server's chunked-prefill cost and adopt it as the
+        ship-vs-recompute constant (``ship_prefill_ms_per_token``): one
+        ``n_tokens`` chunk into scratch-allocated pages, timed after a
+        warm-up run of the same shape so compilation is excluded. Paged
+        servers only; the pages are returned to the pool."""
+        srv = self.server
+        assert srv.paged, "prefill cost is measured on the paged path"
+        alloc = srv.allocator
+        pages = alloc.alloc(alloc.pages_for(n_tokens))
+        assert pages is not None, "no free pages to measure prefill with"
+        ids = [int(t) % srv.cfg.vocab_size for t in range(7, 7 + n_tokens)]
+        try:
+            srv._prefiller.run_chunk(pages, ids, 0).block_until_ready()
+            t0 = time.perf_counter()
+            srv._prefiller.run_chunk(pages, ids, 0).block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3
+        finally:
+            alloc.decref(pages)
+        self.ship_prefill_ms_per_token = ms / n_tokens
+        return self.ship_prefill_ms_per_token
+
     def kv_ship_profile(self):
         """Shipping constants for the cost model; None when this server
         can't ship (full-width caches, no pool, or no measured prefill

@@ -157,6 +157,7 @@ class DistributedKVStore:
         self.delta_gaps = 0
         self.anti_entropy_ships = 0
         self.prime_failures = 0
+        self.prime_errors: List[str] = []
 
     # -- keygroups ----------------------------------------------------------
     def create_keygroup(
@@ -207,12 +208,16 @@ class DistributedKVStore:
     def _notify_apply(self, node: str, keygroup: str, key: str, vv: VersionedValue) -> None:
         # One hook raising must not poison the apply or the other hooks —
         # the replica update already happened; a warm-start failure is a
-        # performance event, not a correctness one.
+        # performance event, not a correctness one. It stays visible: the
+        # counter, and the error's text in ``prime_errors``.
         for hook in self._apply_hooks.get(node, ()):
             try:
                 hook(keygroup, key, vv)
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — recorded, not dropped
                 self.prime_failures += 1
+                self.prime_errors.append(
+                    f"{node} {keygroup}/{key}: {type(e).__name__}: {e}"
+                )
 
     # -- client-facing ops (called by the Context Manager, paper §3.3) -------
     def get(self, node: str, keygroup: str, key: str) -> Optional[VersionedValue]:

@@ -259,6 +259,10 @@ class KVShipper:
         self.duplicate_chunks = 0
         self.install_failures = 0
         self.reconciled_dropped = 0
+        # what the counters cannot say: each fallback's (dst, key, reason)
+        # and the text of each exception an installer raised
+        self.fallback_log: List[Tuple[str, str, str]] = []
+        self.install_errors: List[str] = []
 
     # -- registration -------------------------------------------------------
     def register_node(
@@ -675,6 +679,7 @@ class KVShipper:
         self._inbox_by_key.pop((stream.dst, stream.keygroup, stream.key), None)
         self._senders.pop(stream_id, None)
         self.fallbacks += 1
+        self.fallback_log.append((stream.dst, stream.key, reason))
         reg = self._nodes.get(stream.dst)
         if reg is not None:
             reg["fallback"](stream.key, stream.token_ids, reason)
@@ -716,7 +721,10 @@ class KVShipper:
                 ok = bool(reg["installer"](
                     stream.key, stream.token_ids, payloads, stream.have
                 ))
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — recorded, not dropped
+                self.install_errors.append(
+                    f"{stream.dst} {stream.key}: {type(e).__name__}: {e}"
+                )
                 ok = False
         if not ok:
             self.install_failures += 1

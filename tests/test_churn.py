@@ -234,6 +234,9 @@ def test_apply_hook_exception_does_not_poison_replication():
     store.put("a", "m", "k", ctx_with_turns(tok, 1), 1)
     net.run_until_quiet()
     assert store.prime_failures == 1
+    # counted, not dropped: the error's text is kept with the counter
+    assert len(store.prime_errors) == 1
+    assert "RuntimeError: boom" in store.prime_errors[0]
     assert fired == [("k", 1)]
     assert store.get("b", "m", "k").version == 1
     assert store.replicas_converged("m")

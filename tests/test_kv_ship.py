@@ -173,6 +173,29 @@ def test_basic_ship_installs_with_exact_byte_accounting():
     assert shipper.data_bytes() == expected
 
 
+def test_installer_error_falls_back_visibly():
+    """An installer that raises (a device error in the page install) must
+    not vanish into a silent fallback: the stream resolves into the token
+    recompute path, counted as an install failure, with the exception's
+    text and the fallback's reason on record."""
+    net, store, tok, shipper, nodes = make_harness()
+    ids = seed_context(net, store, tok)
+    nodes["a"].resident["s"] = list(ids)
+
+    def broken(key, token_ids, payloads, have):
+        raise RuntimeError("device lost")
+
+    shipper._nodes["b"]["installer"] = broken
+    assert shipper.maybe_ship("m", "s", "a", "b", ids)
+    net.run_until_quiet()
+
+    assert shipper.installed == 0 and shipper.install_failures == 1
+    assert shipper.install_errors == ["b s: RuntimeError: device lost"]
+    assert shipper.fallback_log == [("b", "s", "install-failed")]
+    assert nodes["b"].fallbacks == [("s", "install-failed")]
+    assert nodes["b"].resident["s"] == ids
+
+
 def test_dropped_chunk_is_billed_and_reshipped():
     """Mid-transfer loss: the dropped chunk's bytes ARE billed (the paper's
     traffic metric counts what crossed the wire, not what arrived) and the

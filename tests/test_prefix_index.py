@@ -179,11 +179,13 @@ def _walk(seed, n_ops=120, check_every=6):
             ["admit", "extend", "evict", "crash"], p=[0.45, 0.3, 0.2, 0.05]
         )
         key = keys[int(rng.integers(len(keys)))]
+        # a rejected put leaves the key's existing entry (if any) in place
+        rejects = pool.rejects
         if op == "admit":
             ids = ids_for(key, int(rng.integers(0, 9)))
             pool.put(key, CacheEntry(list(ids), _lane(ids)),
                      low_priority=bool(rng.integers(2)))
-            if key in pool:
+            if key in pool and pool.rejects == rejects:
                 expected[key] = ids
         elif op == "extend":
             cur = pool.peek(key)
@@ -195,7 +197,7 @@ def _walk(seed, n_ops=120, check_every=6):
             if len(ids) > WIDTH:
                 continue
             pool.put(key, CacheEntry(list(ids), _lane(ids)))
-            if key in pool:
+            if key in pool and pool.rejects == rejects:
                 expected[key] = ids
         elif op == "evict":
             pool.invalidate(key)
