@@ -42,12 +42,12 @@ ARCH = "paper-qwen1.5-0.5b"
 
 # Pallas vs reference logits: max|Δ| <= TOL_REL * max|reference logit|.
 # Both paths run the same bf16 program outside attention; they differ only
-# in the attention core (Mosaic kernel vs jnp under "highest" matmul
-# precision), which is float32 inside and rounded to bf16 at its output.
-# A rounding that lands differently moves an element by one bf16 ulp
-# (2^-8 of its magnitude); 24 layers of residual stream carry such ulps to
-# the logits. 2^-5 of the largest logit is 4-8 bf16 ulps there: room for
-# that, while a wrong mask, page or head moves logits by their own size.
+# in the attention core (Mosaic kernel at default dot precision vs jnp
+# under "highest"), whose output is rounded to bf16. A rounding that lands
+# differently moves an element by one bf16 ulp (2^-8 of its magnitude);
+# 24 layers of residual stream carry such ulps to the logits. 2^-5 of the
+# largest logit is 4-8 bf16 ulps there. Each run also plants a wrong page
+# id in the shared run and asserts that the check sees it.
 TOL_REL = 2.0 ** -5
 
 SYSTEM = (
@@ -171,7 +171,8 @@ def reference_check(cfg, params, device, seed=0):
     """Phase 5: the logits of one 100-token chunked-prefill step and of one
     2-lane decode step whose lanes share a 4-page prefix, Pallas path vs
     jnp reference path (reference under "highest" matmul precision).
-    Returns [(name, max|Δ|, max|ref|)] after asserting the tolerance."""
+    Returns [(name, max|Δ|, max|ref|)] after asserting the tolerance; the
+    last entry is a planted fault, asserted to exceed it."""
     import jax
     import numpy as np
 
@@ -181,11 +182,8 @@ def reference_check(cfg, params, device, seed=0):
     ps, n_pages, n, mp = 16, 16, 100, 8
     kcfg = cfg.replace(attn_impl="pallas")
     rcfg = cfg.replace(attn_impl="reference")
-    with jax.default_device(device):
-        pools = jax.device_put([
-            init_paged_pool(cfg, spec.n_blocks, n_pages, ps)
-            for spec in layer_groups(cfg)
-        ], device)
+    pools = [init_paged_pool(cfg, spec.n_blocks, n_pages, ps, device=device)
+             for spec in layer_groups(cfg)]
     rng = np.random.default_rng(seed)
     toks = np.zeros((1, 128), np.int32)
     toks[0, :n] = rng.integers(8, cfg.vocab_size, n)
@@ -222,15 +220,25 @@ def reference_check(cfg, params, device, seed=0):
     dp, _, _ = decode(kcfg)(params, pools_k, *dec_args, shared)
     with jax.default_matmul_precision("highest"):
         dr, _, _ = decode(rcfg)(params, pools_k, *dec_args)
+    # the check must see a fault: the same decode with one wrong page id in
+    # the shared run (the cascade pass reads page 5 where page 4 belongs)
+    bad = jax.device_put(np.array([1, 2, 3, 5], np.int32), device)
+    df, _, _ = decode(kcfg)(params, pools_k, *dec_args, bad)
 
-    results = []
-    for name, a, b in (("chunked prefill", lp, lr), ("decode", dp, dr)):
+    def delta(name, a, b):
         a = np.asarray(a, np.float32)
         b = np.asarray(b, np.float32)
         assert np.isfinite(a).all() and np.isfinite(b).all(), name
-        d, scale = float(np.abs(a - b).max()), float(np.abs(b).max())
+        return float(np.abs(a - b).max()), float(np.abs(b).max())
+
+    results = []
+    for name, a, b in (("chunked prefill", lp, lr), ("decode", dp, dr)):
+        d, scale = delta(name, a, b)
         assert d <= TOL_REL * scale, (name, d, TOL_REL * scale)
         results.append((name, d, scale))
+    d, scale = delta("planted fault", df, dr)
+    assert d > TOL_REL * scale, ("planted fault not seen", d, TOL_REL * scale)
+    results.append(("decode with a wrong shared page (must fail)", d, scale))
     return results
 
 
@@ -353,7 +361,10 @@ def main(argv=None) -> int:
                 print(f"turn: tenant={tenant} turn={r.turn} node={r.served_by} "
                       f"ctx={r.n_context_tokens} gen={r.n_generated_tokens} "
                       f"warm={r.timing.kv_warm_source} "
-                      f"response_ms={r.timing.response_time_ms:.1f}")
+                      f"response_ms={r.timing.response_time_ms:.1f} "
+                      f"queue_ms={r.timing.queue_ms:.1f} "
+                      f"ttft_ms={r.timing.ttft_ms:.1f} "
+                      f"decode_p99_ms={r.timing.decode_p99_ms:.1f}")
         srv = cluster.node("edge-0").service.server
         ship = cluster.kv_ship.stats()
         print(f"served: cascade_steps={srv.cascade_steps} "

@@ -51,9 +51,6 @@ NEG_INF = -1e30
 
 _NT = (((1,), (1,)), ((), ()))   # (M, Dh) x (N, Dh) -> (M, N)
 _NN = (((1,), (0,)), ((), ()))   # (M, K) x (K, N) -> (M, N)
-# float32 products on the MXU: the probabilities are not rounded to bf16
-# before p @ v, so the kernel computes what the float32 reference does
-_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _attend_page(
@@ -75,7 +72,7 @@ def _attend_page(
         k = k_ref[0, :, h, :].astype(jnp.float32)       # (ps, Dh)
         v = v_ref[0, :, h, :].astype(jnp.float32)
         logits = jax.lax.dot_general(
-            q, k, _NT, precision=_EXACT, preferred_element_type=jnp.float32
+            q, k, _NT, preferred_element_type=jnp.float32
         ) * scale                                       # (G, ps)
         if softcap > 0.0:
             logits = softcap * jnp.tanh(logits / softcap)
@@ -87,7 +84,7 @@ def _attend_page(
         p = jnp.exp(logits - m_new) * hmask.astype(jnp.float32)
         l_ref[st] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[st] = acc_ref[st] * alpha + jax.lax.dot_general(
-            p, v, _NN, precision=_EXACT, preferred_element_type=jnp.float32
+            p, v, _NN, preferred_element_type=jnp.float32
         )
         m_ref[st] = m_new
 

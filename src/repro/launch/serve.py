@@ -51,8 +51,6 @@ def build_cluster(
     kv_ship: bool = False,
     kv_ship_force: Optional[str] = None,
     router: Optional[str] = None,
-    inter_node_link=None,
-    client_link=None,
 ) -> Tuple["EdgeCluster", str]:
     """Build ``n_nodes`` edge nodes ``edge-0..`` serving one model and
     return ``(cluster, model_name)``.
@@ -100,8 +98,8 @@ def build_cluster(
             services[nid] = svc
     cluster = EdgeCluster.build(
         node_ids, services.__getitem__,
-        inter_node_link=inter_node_link or Link(latency_ms=3.0, bandwidth_mbps=100.0),
-        client_link=client_link or Link(latency_ms=8.0, bandwidth_mbps=20.0),
+        inter_node_link=Link(latency_ms=3.0, bandwidth_mbps=100.0),
+        client_link=Link(latency_ms=8.0, bandwidth_mbps=20.0),
         replication=replication,
         kv_ship=kv_ship,
         kv_ship_force=kv_ship_force,

@@ -118,16 +118,18 @@ def trim_kv_pos(kv_pos: jnp.ndarray, n_valid) -> jnp.ndarray:
 
 
 def init_paged_pool(
-    cfg: ModelConfig, n_layers: int, n_pages: int, page_size: int, dtype=None
+    cfg: ModelConfig, n_layers: int, n_pages: int, page_size: int, dtype=None,
+    device=None,
 ) -> Cache:
     """Physical KV page pool for one layer group: k/v of shape
-    (L, n_pages, page_size, KV, Dh). kv_pos is tracked per *sequence*
-    (B, width) by the owner, not per page."""
+    (L, n_pages, page_size, KV, Dh), made on (and committed to) ``device``
+    when one is given. kv_pos is tracked per *sequence* (B, width) by the
+    owner, not per page."""
     dt = dtype or dtype_of(cfg.compute_dtype)
-    kv, dh = cfg.n_kv_heads, cfg.d_head
+    shape = (n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
     return {
-        "k": jnp.zeros((n_layers, n_pages, page_size, kv, dh), dtype=dt),
-        "v": jnp.zeros((n_layers, n_pages, page_size, kv, dh), dtype=dt),
+        "k": jnp.zeros(shape, dtype=dt, device=device),
+        "v": jnp.zeros(shape, dtype=dt, device=device),
     }
 
 

@@ -140,11 +140,11 @@ class PagedKVAllocator:
         # the pool is committed to the service's device, so every jitted
         # step that takes it runs there
         self.device = device if device is not None else jax.devices()[0]
-        with jax.default_device(self.device):
-            self.pools: List[Dict[str, jnp.ndarray]] = jax.device_put([
-                init_paged_pool(cfg, spec.n_blocks, n_pages, page_size, dtype)
-                for spec in layer_groups(cfg)
-            ], self.device)
+        self.pools: List[Dict[str, jnp.ndarray]] = [
+            init_paged_pool(cfg, spec.n_blocks, n_pages, page_size, dtype,
+                            device=self.device)
+            for spec in layer_groups(cfg)
+        ]
         # page 0 reserved as scratch; LIFO free list keeps reuse warm
         self._free: List[int] = list(range(n_pages - 1, 0, -1))
         self._ref = np.zeros(n_pages, np.int32)

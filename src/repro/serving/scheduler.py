@@ -238,11 +238,10 @@ class BatchedServer:
             self._iota = self._put(np.arange(max_len, dtype=np.int32))
         else:
             self.allocator = None
-            with jax.default_device(self.device):
-                self.caches = self._put(make_decode_caches(
-                    cfg, n_slots, max_len,
-                    dtype=jnp.float32 if cfg.compute_dtype == "float32" else None,
-                ))
+            self.caches = self._put(make_decode_caches(
+                cfg, n_slots, max_len,
+                dtype=jnp.float32 if cfg.compute_dtype == "float32" else None,
+            ))
 
         (self._prefill_one, self._append_one, self._decode,
          self._decode_paged) = _server_fns(cfg, max_len)
@@ -1088,14 +1087,15 @@ class BatchedLLMService:
         return self.server.prime(cache_key, list(token_ids))
 
     # -- KV-page shipping hooks (repro.store.kv_ship) -------------------
-    def measure_prefill_ms_per_token(self, n_tokens: int = 256) -> float:
+    def measure_prefill_ms_per_token(self) -> float:
         """Measure this server's chunked-prefill cost and adopt it as the
         ship-vs-recompute constant (``ship_prefill_ms_per_token``): one
-        ``n_tokens`` chunk into scratch-allocated pages, timed after a
+        256-token chunk into scratch-allocated pages, timed after a
         warm-up run of the same shape so compilation is excluded. Paged
         servers only; the pages are returned to the pool."""
         srv = self.server
         assert srv.paged, "prefill cost is measured on the paged path"
+        n_tokens = 256
         alloc = srv.allocator
         pages = alloc.alloc(alloc.pages_for(n_tokens))
         assert pages is not None, "no free pages to measure prefill with"
