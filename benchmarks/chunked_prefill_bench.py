@@ -13,8 +13,9 @@ prompts start arriving, against two paged
   chunks and at most ``CHUNK_TOKENS`` prompt tokens ride along with each
   decode step, bounding the bump a resident's inter-token gap can take.
 
-Reported per mode: resident decode-gap p50/p99 (ms, from the scheduler's
-per-step wall clocks), long-prompt ttft, and aggregate tokens/s. Outputs
+Reported per mode: resident decode-gap p50/p99 (ms, each token stamped
+with the host clock at the end of the step that produced it), long-prompt
+ttft, and aggregate tokens/s. Outputs
 are asserted token-identical between the two modes — the budget is a
 latency knob, not a model change.
 
@@ -70,10 +71,11 @@ def _requests(vocab, *, n_res, res_prompt, res_new, n_long, long_prompt,
 
 def _run_mode(cfg, params, budget, res, longs, *, max_len, warm_steps):
     """One serving wave: residents first, long prompts arrive after
-    ``warm_steps`` decode steps. Returns (outputs, resident finish records,
-    long finish records, wall seconds). A same-shaped warmup wave runs
+    ``warm_steps`` decode steps. Returns (outputs, resident latencies,
+    long latencies, tokens, wall seconds). A same-shaped warmup wave runs
     first so jit compiles stay out of the timed gaps."""
     from repro.serving import BatchedServer
+    from repro.serving.scheduler import timed_step, token_latency
 
     srv = BatchedServer(
         cfg, params, n_slots=N_SLOTS, max_len=max_len, session_pool=None,
@@ -81,30 +83,48 @@ def _run_mode(cfg, params, budget, res, longs, *, max_len, warm_steps):
     )
 
     def wave():
+        submitted, token_ms = {}, {}
+
+        def submit(ids, new):
+            rid = srv.submit(list(ids), max_new=new)
+            submitted[rid] = time.perf_counter() * 1e3
+            return rid
+
+        def step():
+            _, got = timed_step(srv)
+            now = time.perf_counter() * 1e3
+            for rid in got:
+                token_ms.setdefault(rid, []).append(now)
+
         t0 = time.perf_counter()
-        rid_res = [srv.submit(list(ids), max_new=new) for ids, new in res]
+        rid_res = [submit(ids, new) for ids, new in res]
         for _ in range(warm_steps):
-            srv.step()
-        rid_long = [srv.submit(list(ids), max_new=new) for ids, new in longs]
-        fin = {f.request_id: f for f in srv.run_to_completion()}
+            step()
+        rid_long = [submit(ids, new) for ids, new in longs]
+        while srv.busy:
+            step()
         wall = time.perf_counter() - t0
+        fin = {f.request_id: f for f in srv.finished}
         srv.finished.clear()
-        return rid_res, rid_long, fin, wall
+        lat = {r: token_latency(submitted[r], token_ms.get(r, []))
+               for r in submitted}
+        return rid_res, rid_long, fin, lat, wall
 
     wave()  # identical warmup wave: every jit bucket compiles untimed
-    rid_res, rid_long, fin, wall = wave()
+    rid_res, rid_long, fin, lat, wall = wave()
     outs = {r: fin[r].token_ids for r in rid_res + rid_long}
-    return outs, [fin[r] for r in rid_res], [fin[r] for r in rid_long], wall
+    toks = sum(len(t) for t in outs.values())
+    return (outs, [lat[r] for r in rid_res], [lat[r] for r in rid_long],
+            toks, wall)
 
 
-def _mode_row(res_fin, long_fin, wall):
-    toks = sum(len(f.token_ids) for f in res_fin + long_fin)
+def _mode_row(res_lat, long_lat, toks, wall):
     return {
         "resident_decode_p50_ms":
-            float(np.mean([f.decode_p50_ms for f in res_fin])),
+            float(np.mean([r["decode_p50_ms"] for r in res_lat])),
         "resident_decode_p99_ms":
-            float(np.max([f.decode_p99_ms for f in res_fin])),
-        "long_ttft_ms": float(np.mean([f.ttft_ms for f in long_fin])),
+            float(np.max([r["decode_p99_ms"] for r in res_lat])),
+        "long_ttft_ms": float(np.mean([r["ttft_ms"] for r in long_lat])),
         "tokens_per_s": toks / wall,
     }
 
@@ -113,11 +133,11 @@ def _sweep(params, emit, *, res, longs, max_len, warm_steps):
     cfg = _cfg()
     rows, outs = {}, {}
     for name, budget in (("stall", None), ("chunked", CHUNK_TOKENS)):
-        o, rf, lf, wall = _run_mode(
+        o, rl, ll, toks, wall = _run_mode(
             cfg, params, budget, res, longs, max_len=max_len,
             warm_steps=warm_steps,
         )
-        rows[name] = _mode_row(rf, lf, wall)
+        rows[name] = _mode_row(rl, ll, toks, wall)
         outs[name] = o
         emit(
             f"chunked_prefill_{name}_resident_p99_ms",

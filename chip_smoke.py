@@ -134,20 +134,27 @@ def assert_no_hidden_fallbacks(cluster):
 
 
 def served_path(arch, seed=0):
-    """Phases 2 and 3. Returns (cluster, model, responses)."""
+    """Phases 2 and 3, with the program's tracer on. Returns (cluster,
+    model, responses, trace)."""
+    from repro.core import tracing
     from repro.launch.serve import build_cluster
 
     cluster, model = build_cluster(arch, 2, seed=seed, kv_ship=True)
-    responses = run_sessions(cluster, model, _sessions(), think_ms=2000.0)
+    tracing.start()
+    try:
+        responses = run_sessions(cluster, model, _sessions(), think_ms=2000.0)
+    finally:
+        trace = tracing.stop()
     roam = responses["C"][2]
     assert roam.served_by == "edge-1", roam.served_by
     assert roam.timing.kv_warm_source in ("pages", "tokens"), (
         "roam turn was not warmed", roam.timing.kv_warm_source,
     )
     assert_no_hidden_fallbacks(cluster)
-    srv = cluster.node("edge-0").service.server
-    assert srv.cascade_steps > 0, "the shared-prefix cascade pass never ran"
-    return cluster, model, responses
+    assert trace.counters.get("cascade_steps", 0) > 0, (
+        "the shared-prefix cascade pass never ran"
+    )
+    return cluster, model, responses, trace
 
 
 def kernel_check(server):
@@ -355,7 +362,7 @@ def main(argv=None) -> int:
               f"{installed[0]} / {installed[1]}; probe logits max|Δ| per node "
               f"{deltas}")
     else:
-        cluster, model, responses = served_path(ARCH, args.seed)
+        cluster, model, responses, trace = served_path(ARCH, args.seed)
         for tenant, rs in responses.items():
             for r in rs:
                 print(f"turn: tenant={tenant} turn={r.turn} node={r.served_by} "
@@ -367,7 +374,7 @@ def main(argv=None) -> int:
                       f"decode_p99_ms={r.timing.decode_p99_ms:.1f}")
         srv = cluster.node("edge-0").service.server
         ship = cluster.kv_ship.stats()
-        print(f"served: cascade_steps={srv.cascade_steps} "
+        print(f"served: cascade_steps={trace.counters['cascade_steps']} "
               f"prime_failures={cluster.store.prime_failures} "
               f"install_failures={ship['install_failures']} "
               f"ship_installed={ship['installed']} fallbacks={ship['fallbacks']}")

@@ -55,15 +55,20 @@ def main() -> None:
     t0 = time.perf_counter()
     for p in prompts:
         srv.submit(tok.encode(p), max_new=args.max_new)
-    fin = srv.run_to_completion()
+    done_ms = {}  # every request was submitted at t0
+    while srv.busy:
+        srv.step()
+        now = (time.perf_counter() - t0) * 1e3
+        for f in srv.finished:
+            done_ms.setdefault(f.request_id, now)
+    fin = srv.finished
     wall = time.perf_counter() - t0
 
     print(f"{len(fin)} requests completed in {wall*1e3:.0f}ms "
           f"on {args.slots} slots")
-    for f in sorted(fin, key=lambda f: f.finished_at):
-        lat = (f.finished_at - f.submitted_at) * 1e3
+    for f in sorted(fin, key=lambda f: done_ms[f.request_id]):
         print(f"  req {f.request_id}: {len(f.token_ids):2d} tokens, "
-              f"latency {lat:7.1f}ms")
+              f"latency {done_ms[f.request_id]:7.1f}ms")
     total_tokens = sum(len(f.token_ids) for f in fin)
     print(f"aggregate throughput: {total_tokens / wall:.1f} tok/s")
     mode = "paged" if args.paged else "full-width"

@@ -39,7 +39,6 @@ serialized benchmarks are unchanged.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Protocol, Tuple
 
@@ -51,6 +50,7 @@ from ..tokenizer import (
     encode_turn,
     render_turn,
 )
+from . import tracing
 from .consistency import (
     ReadResult,
     RetryPolicy,
@@ -296,13 +296,13 @@ class ContextManager:
 
         if req.mode is ContextMode.CLIENT_SIDE:
             # History ships with the request; tokenize all of it, every time.
-            t0 = time.perf_counter()
-            full: List[int] = []
-            for role, content in req.client_history or []:
-                full.extend(encode_turn(tok, role, content))
-            full.extend(encode_turn(tok, "user", req.prompt))
-            full.extend(assistant_header(tok))
-            timing.tokenize_ms = (time.perf_counter() - t0) * 1e3 * self.tokenize_scale
+            with self._tokenize_span(key) as tm:
+                full: List[int] = []
+                for role, content in req.client_history or []:
+                    full.extend(encode_turn(tok, role, content))
+                full.extend(encode_turn(tok, "user", req.prompt))
+                full.extend(assistant_header(tok))
+            timing.tokenize_ms = tm.ms * self.tokenize_scale
             pt = PreparedTurn(
                 req=req, user_id=user_id, session_id=session_id, key=key,
                 timing=timing, context_ids=[], prompt_ids=full,
@@ -374,10 +374,10 @@ class ContextManager:
                 else TokenizedContext(model=req.model)
             )
             context_ids = list(stored_tok.ids)
-            t0 = time.perf_counter()
-            prompt_ids = encode_turn(tok, "user", req.prompt)
-            prompt_ids.extend(assistant_header(tok))
-            timing.tokenize_ms = (time.perf_counter() - t0) * 1e3 * self.tokenize_scale
+            with self._tokenize_span(key) as tm:
+                prompt_ids = encode_turn(tok, "user", req.prompt)
+                prompt_ids.extend(assistant_header(tok))
+            timing.tokenize_ms = tm.ms * self.tokenize_scale
             return PreparedTurn(
                 req=req, user_id=user_id, session_id=session_id, key=key,
                 timing=timing, context_ids=context_ids, prompt_ids=prompt_ids,
@@ -388,16 +388,24 @@ class ContextManager:
             rr.value.value.copy() if rr.value is not None
             else RawContext(model=req.model)
         )
-        t0 = time.perf_counter()
-        ctx_ids = tok.encode(stored_raw.text)
-        new_ids = encode_turn(tok, "user", req.prompt)
-        new_ids.extend(assistant_header(tok))
-        timing.tokenize_ms = (time.perf_counter() - t0) * 1e3 * self.tokenize_scale
+        with self._tokenize_span(key) as tm:
+            ctx_ids = tok.encode(stored_raw.text)
+            new_ids = encode_turn(tok, "user", req.prompt)
+            new_ids.extend(assistant_header(tok))
+        timing.tokenize_ms = tm.ms * self.tokenize_scale
         # raw mode sends everything as one prompt (context param empty)
         return PreparedTurn(
             req=req, user_id=user_id, session_id=session_id, key=key,
             timing=timing, context_ids=[], prompt_ids=ctx_ids + new_ids,
             stored_raw=stored_raw, stale=rr.stale,
+        )
+
+    def _tokenize_span(self, key: str):
+        """Tokenization's one measurement: ``Timing.tokenize_ms`` is read
+        from it, and it is the ``context.tokenize`` span when tracing."""
+        return tracing.timed(
+            "context.tokenize", self.node_id, requests=key,
+            net=self.store.network,
         )
 
     # -- phase 2: infer ---------------------------------------------
@@ -435,7 +443,7 @@ class ContextManager:
         """Build the response and perform the asynchronous context update
         (local write + async replication) — after the response, off the
         client-observable path (§4.2.1)."""
-        req, timing, tok = pt.req, pt.timing, self.tokenizer
+        req, timing = pt.req, pt.timing
         timing.inference_ms = result.inference_ms
         timing.queue_ms = result.queue_ms
         timing.batch_size = result.batch_size
@@ -463,7 +471,15 @@ class ContextManager:
         )
 
         if req.mode is not ContextMode.CLIENT_SIDE:
-            t0 = time.perf_counter()
+            self._write_context(pt, result)
+        on_done(resp)
+
+    def _write_context(self, pt: PreparedTurn, result: ServiceResult) -> None:
+        """The asynchronous context update: extend the stored context with
+        the turn, write it locally and replicate it to keygroup peers."""
+        req, tok = pt.req, self.tokenizer
+        net = self.store.network
+        with tracing.span("context.write", self.node_id, requests=pt.key, net=net):
             if req.mode is ContextMode.TOKENIZED:
                 assert pt.stored_tok is not None
                 pt.stored_tok.extend(encode_turn(tok, "user", req.prompt))
@@ -479,10 +495,12 @@ class ContextManager:
                 pt.stored_raw.commit_turn()
                 new_value = pt.stored_raw
                 version = pt.stored_raw.turn
-            timing.async_update_ms = (time.perf_counter() - t0) * 1e3
-            # local write + async replication to keygroup peers
-            self.store.put(self.node_id, self.keygroup, pt.key, new_value, version)
-        on_done(resp)
+            # local write + async replication to keygroup peers (their
+            # bytes are the network's traffic counters)
+            with tracing.span("store.put"):
+                self.store.put(
+                    self.node_id, self.keygroup, pt.key, new_value, version
+                )
 
     # ---------------------------------------------------------------
     def forget(

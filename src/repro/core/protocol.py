@@ -62,7 +62,6 @@ class Timing:
     context_read_ms: float = 0.0   # includes retry backoff (10 ms each)
     inference_ms: float = 0.0
     network_down_ms: float = 0.0
-    async_update_ms: float = 0.0   # context write; NOT on the response path
     retries: int = 0
     # Session-level KV-cache reuse (repro.serving.session_cache): did this
     # turn hit the session's cached KV prefix, how many prefix tokens were
@@ -89,9 +88,11 @@ class Timing:
     queue_ms: float = 0.0
     batch_size: int = 1
     # Token-level latency (chunked paged prefill, docs/architecture.md):
-    # submit -> first generated token determined, and the per-token decode
-    # gap distribution. For a resident tenant, p99 captures the bounded
-    # bump other tenants' prefill chunks add to its steps — the metric the
+    # submit -> first generated token, and the per-token decode gap
+    # distribution. The batched service reads them on the cluster clock, a
+    # token at the end of the step that produced it, so a node counts only
+    # its own steps. For a resident tenant, p99 captures the bounded bump
+    # other tenants' prefill chunks add to its steps — the metric the
     # per-step chunk budget holds flat where a monolithic prefill stalls.
     ttft_ms: float = 0.0
     decode_p50_ms: float = 0.0

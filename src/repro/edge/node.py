@@ -15,9 +15,9 @@ new tokens — the node switch stops being a full re-prefill.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from time import perf_counter
 from typing import TYPE_CHECKING, Callable, Dict, Optional
 
+from ..core import tracing
 from ..core.consistency import RetryPolicy
 from ..core.manager import ContextManager, LLMServiceProtocol
 from ..core.protocol import OVERLOADED, Request, Response, Ticket, Timing
@@ -61,10 +61,11 @@ class EdgeNode:
     manager: ContextManager
     service: LLMServiceProtocol
     # Migration warm-start accounting: primes performed on replication
-    # arrival, and the wall time they cost (off the client-observable path —
-    # the work overlaps client think time, like the paper's async update).
+    # arrival (off the client-observable path — the work overlaps client
+    # think time, like the paper's async update). Their host time is the
+    # tracer's ``node.prime`` and ``node.page_install`` spans; their device
+    # time lands in a later host sync.
     warm_starts: int = 0
-    warm_start_ms: float = 0.0
     # Liveness (docs/architecture.md, "Failure model"): a crashed node
     # refuses new submits, fails its in-flight turns fast, and loses its
     # volatile session-KV pool; the KV *replica* survives unless the
@@ -105,6 +106,8 @@ class EdgeNode:
             retry=retry or RetryPolicy(),
         )
         node = cls(node_id=node_id, manager=mgr, service=service)
+        if hasattr(service, "node_id"):
+            service.node_id = node_id  # names the node in its trace spans
         if warm_start == "eager" and service.capabilities().prime:
             store.on_apply(node_id, node._on_replicated_context)
         return node
@@ -245,10 +248,11 @@ class EdgeNode:
 
     def _prime_tokens(self, key: str, ids) -> None:
         """The PR-2 token-recompute prime (also the shipper's fallback)."""
-        t0 = perf_counter()
-        if self.service.prime(key, list(ids)):
+        with tracing.span("node.prime", self.node_id, requests=key) as sp:
+            sp.set("tokens", len(ids))
+            warm = self.service.prime(key, list(ids))
+        if warm:
             self.warm_starts += 1
-            self.warm_start_ms += (perf_counter() - t0) * 1e3
 
     # -- KVShipper hooks ---------------------------------------------------
     def _ship_install(
@@ -262,12 +266,13 @@ class EdgeNode:
         install = getattr(self.service, "install_kv_pages", None)
         if install is None:
             return False
-        t0 = perf_counter()
-        ok = bool(install(key, list(token_ids), payloads, have_pages))
+        with tracing.span("node.page_install", self.node_id, requests=key) as sp:
+            sp.set("tokens", len(token_ids))
+            sp.set("pages", len(payloads))
+            ok = bool(install(key, list(token_ids), payloads, have_pages))
         if ok:
             self.kv_ships += 1
             self.warm_starts += 1
-            self.warm_start_ms += (perf_counter() - t0) * 1e3
         return ok
 
     def _ship_fallback(self, key: str, token_ids, reason: str) -> None:

@@ -144,6 +144,7 @@ def chunked_prefill_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, sg, dh), q.dtype),
         interpret=interpret,
+        name="chunked_prefill",
     )(
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
         p0.astype(jnp.int32), rows, q, pool_k, pool_v,

@@ -246,6 +246,7 @@ def shared_prefix_pallas(
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="shared_prefix",
     )(
         shared_pages.astype(jnp.int32), q_pos.astype(jnp.int32),
         q, pool_k, pool_v,
@@ -327,4 +328,5 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*args)

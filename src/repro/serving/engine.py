@@ -269,9 +269,6 @@ class InferenceEngine:
     _paged_decode_cache: Dict[int, object] = field(default_factory=dict, repr=False)
     _prefiller: Optional[object] = field(default=None, repr=False)
 
-    # Migration warm-start accounting (prime() runs off the serving hot path)
-    prime_count: int = 0
-    prime_ms: float = 0.0
     # keyed generations that had to leave the paged route (dense pool entry
     # from a mixed-topology pool, or page exhaustion at admission)
     paged_fallbacks: int = 0
@@ -416,22 +413,18 @@ class InferenceEngine:
         JaxLLMService.completion's truncation guard (max generation
         reserve 16). Returns True when the pool now holds KV for the full
         sequence."""
-        t0 = time.perf_counter()
         pool = self.session_pool
         paged_fill = None
         if pool is not None and pool.allocator is not None:
             paged_fill = lambda ids, entry, usable: prime_fill_pages(  # noqa: E731
                 pool, self._paged_prefiller(), ids, entry, usable
             )
-        warm, stored = prime_session_pool(
+        warm, _ = prime_session_pool(
             pool, cache_key, list(token_ids),
             self.max_len, self.max_len - 1 - 16,
             self._append_prefill, self._full_prefill,
             paged_fill=paged_fill,
         )
-        if stored:
-            self.prime_count += 1
-            self.prime_ms += (time.perf_counter() - t0) * 1e3
         return warm
 
     def install_shipped_pages(
@@ -453,20 +446,16 @@ class InferenceEngine:
         pool = self.session_pool
         if pool is None or pool.allocator is None:
             return False
-        t0 = time.perf_counter()
         paged_fill = lambda ids, entry, usable: prime_fill_pages(  # noqa: E731
             pool, self._paged_prefiller(), ids, entry, usable,
             shipped=payloads, ship_have=have_pages,
         )
-        warm, stored = prime_session_pool(
+        warm, _ = prime_session_pool(
             pool, cache_key, list(token_ids),
             self.max_len, self.max_len - 1 - 16,
             self._append_prefill, self._full_prefill,
             paged_fill=paged_fill, source="ship",
         )
-        if stored:
-            self.prime_count += 1
-            self.prime_ms += (time.perf_counter() - t0) * 1e3
         return warm
 
     # -- public API ------------------------------------------------------------

@@ -45,12 +45,13 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 from functools import lru_cache, partial
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..core.manager import ServiceCapabilities, ServiceResult
 from ..models import (
     ModelConfig,
@@ -126,18 +127,18 @@ class SlotState:
     pending: List[int] = field(default_factory=list)
     prefill_p0: int = 0      # absolute position of the next chunk
     n_skip: int = 0          # leading read-only shared-prefix pages
-    # latency accounting (wall clock)
-    ttft_ms: float = 0.0
-    gaps_ms: List[float] = field(default_factory=list)
-    last_tok_t: Optional[float] = None
+
+    @property
+    def trace_id(self):
+        """The request's name in trace spans: its session's context key
+        where it has one, as the Context Manager's spans name it."""
+        return self.cache_key if self.cache_key is not None else self.request_id
 
 
 @dataclass
 class FinishedRequest:
     request_id: int
     token_ids: List[int]
-    submitted_at: float
-    finished_at: float
     # session-KV reuse accounting (0 / False without a pool hit)
     cache_hit: bool = False
     reused_tokens: int = 0
@@ -145,14 +146,6 @@ class FinishedRequest:
     warm_source: str = "none"    # "tokens" | "pages" | "none"
     # peak decode batch this request shared (1 = it ran alone)
     batch_size: int = 1
-    # wall-clock latency: submit -> first generated token determined, and
-    # the per-token decode gap distribution (time between consecutive
-    # generated tokens — inflated for residents while other tenants'
-    # prefill chunks share their steps, which is exactly the interference
-    # the chunk budget bounds)
-    ttft_ms: float = 0.0
-    decode_p50_ms: float = 0.0
-    decode_p99_ms: float = 0.0
 
 
 class BatchedServer:
@@ -192,11 +185,10 @@ class BatchedServer:
         self.slots: List[Optional[SlotState]] = [None] * n_slots
         self.queue: List = []
         self.finished: List[FinishedRequest] = []
-        self._submit_times: Dict[int, float] = {}
         self._next_tok = np.zeros((n_slots,), np.int32)
         self._req_seq = 0
-        # decode steps that ran the shared-prefix (cascade) pass
-        self.cascade_steps = 0
+        # the node this server runs on, as trace spans name it
+        self.node_id: Optional[str] = None
 
         if paged:
             # Block-granular KV: one shared page pool backs every decode
@@ -273,7 +265,6 @@ class BatchedServer:
         rid = self._req_seq
         self._req_seq += 1
         self.queue.append((rid, ids, max_new, cache_key))
-        self._submit_times[rid] = time.perf_counter()
         return rid
 
     @property
@@ -384,13 +375,11 @@ class BatchedServer:
 
         self._pos[idx] = int(pos[0])
         self._next_tok[idx] = int(jnp.argmax(logits[0]))
-        now = time.perf_counter()
         self.slots[idx] = SlotState(
             request_id=rid, pos=n, max_new=max_new,
             cache_key=cache_key, token_ids=list(ids), reused_tokens=usable,
             warm_start=warm_source != "none",
             warm_source=warm_source, prefilled=True,
-            ttft_ms=(now - self._submit_times[rid]) * 1e3, last_tok_t=now,
         )
         return True
 
@@ -553,23 +542,23 @@ class BatchedServer:
                 best = list(self.slot_pages[j][:full])
         return best
 
-    def _drain_prefill(self) -> None:
+    def _drain_prefill(self) -> int:
         """Drain up to ``prefill_chunk_tokens`` prompt tokens from the
         chunk plans, strict FIFO admission order. Chunks end on page
         boundaries (except a plan's final, possibly ragged, chunk) so
         every completed chunk leaves fully-written pages, which are
         content-indexed right away — later same-wave admissions share
         them. A plan's last chunk yields the request's first decode token:
-        ttft stops there and the lane joins the decode batch this very
-        step."""
+        the lane joins the decode batch this very step. Returns the number
+        of chunks dispatched."""
         if not self._prefill_fifo:
-            return
+            return 0
         alloc = self.allocator
         ps = alloc.page_size
         budget = self.prefill_chunk_tokens
         if budget is not None:
             budget = max(ps, budget)
-        spent = 0
+        spent = chunks = 0
         while self._prefill_fifo:
             if budget is not None and spent >= budget:
                 break
@@ -586,11 +575,20 @@ class BatchedServer:
                 if aligned > 0:
                     c = aligned
             chunk, st.pending = st.pending[:c], st.pending[c:]
-            logits = self._prefiller.run_chunk(
-                self.slot_pages[idx], chunk, st.prefill_p0, n_skip=st.n_skip
-            )
+            with tracing.span(
+                "scheduler.prefill_chunk", requests=st.trace_id
+            ) as sp:
+                sp.set("c", c)
+                sp.set("p0", st.prefill_p0)
+                logits = self._prefiller.run_chunk(
+                    self.slot_pages[idx], chunk, st.prefill_p0,
+                    n_skip=st.n_skip,
+                )
+            tracing.count("prefill_chunks")
+            tracing.count("prefill_chunk_tokens", c)
             st.prefill_p0 += c
             spent += c
+            chunks += 1
             # progressively index this lane's fully-covered prompt pages:
             # 32 tenants with one system prompt arriving as a wave share
             # them as soon as the first tenant's chunks write them
@@ -602,15 +600,14 @@ class BatchedServer:
             if not st.pending:
                 self._prefill_fifo.pop(0)
                 st.prefilled = True
-                self._next_tok[idx] = int(jnp.argmax(logits))
-                now = time.perf_counter()
-                st.ttft_ms = (now - self._submit_times[st.request_id]) * 1e3
-                st.last_tok_t = now
+                with tracing.span("scheduler.sync", requests=st.trace_id):
+                    self._next_tok[idx] = int(jnp.argmax(logits))
                 # kv_pos becomes real only now: slot == position for the
                 # whole prompt, invalid beyond (layout invariant)
                 self._kv_pos = self._kv_pos.at[idx].set(
                     jnp.where(self._iota < st.pos, self._iota, -1)
                 )
+        return chunks
 
     def _shared_prefix_run(self, width: int) -> List[int]:
         """Longest run of leading pages IDENTICAL across every
@@ -709,21 +706,15 @@ class BatchedServer:
             # inactive lanes keep decoding into the scratch page until the
             # slot is re-admitted; their kv_pos row is junk but unread
             self._table[idx, :] = SCRATCH_PAGE
-        gaps = st.gaps_ms
         self.finished.append(
             FinishedRequest(
                 st.request_id,
                 st.generated,
-                self._submit_times.pop(st.request_id),
-                time.perf_counter(),
                 cache_hit=st.reused_tokens > 0,
                 reused_tokens=st.reused_tokens,
                 warm_start=st.warm_start,
                 warm_source=st.warm_source,
                 batch_size=st.batch_size,
-                ttft_ms=st.ttft_ms,
-                decode_p50_ms=float(np.percentile(gaps, 50)) if gaps else 0.0,
-                decode_p99_ms=float(np.percentile(gaps, 99)) if gaps else 0.0,
             )
         )
         self.slots[idx] = None
@@ -785,118 +776,154 @@ class BatchedServer:
         share every step, so a long admission costs residents a bounded
         latency bump per step instead of a monolithic stall. Full-width
         mode keeps the classic loop: admission prefills in one shot and
-        every occupied slot decodes."""
-        self._admit_from_queue()
-        if self.paged:
-            # drain BEFORE counting decoders: a plan completing within this
-            # step's budget decodes its first token this very step
-            self._drain_prefill()
+        every occupied slot decodes.
+
+        Traced as ``scheduler.step`` with children for admission, each
+        prefill chunk, the decode inputs, the decode dispatch, the host
+        sync and the finish loop; a step that ends before the sync (every
+        occupied lane mid-prefill) leaves its device work to a later one
+        and counts in ``unsynced_steps``."""
+        with tracing.span("scheduler.step", self.node_id) as sp:
+            synced = self._step(sp)
+            sp.set("synced", synced)
+        tracing.count("steps")
+        if not synced:
+            tracing.count("unsynced_steps")
+
+    def _step(self, sp) -> bool:
+        """The body of :meth:`step`; True when it synced with the device."""
+        with tracing.span("scheduler.admit"):
+            self._admit_from_queue()
+        # drain BEFORE counting decoders: a plan completing within this
+        # step's budget decodes its first token this very step
+        chunks = self._drain_prefill() if self.paged else 0
         n_active = sum(s is not None for s in self.slots)
         if n_active == 0:
-            return
+            return False
+        if tracing.enabled():
+            sp.serve(s.trace_id for s in self.slots if s is not None)
         for st in self.slots:
             if st is not None:
                 st.batch_size = max(st.batch_size, n_active)
 
         if self.paged:
-            # grow-on-demand: each decode-ready slot needs a page covering
-            # the position it is about to write; a slot that cannot get one
-            # (pool exhausted, nothing evictable) retires cleanly with the
-            # tokens it has — never a silent mode="drop" KV loss.
-            # Mid-prefill lanes reserved their whole span at admission.
-            ps = self.allocator.page_size
+            with tracing.span("scheduler.decode_inputs"):
+                inputs = self._decode_inputs()
+            if inputs is None:
+                return False  # every occupied lane is mid-prefill
+            n_ready, trimmed, wp, table, tokens, dec_pos, sp_pages = inputs
+            with tracing.span("scheduler.decode"):
+                if trimmed:
+                    logits, pools, kvp = self._decode_paged(
+                        self.params, self.allocator.pools, table,
+                        self._kv_pos[:, :wp], tokens, dec_pos, sp_pages,
+                    )
+                    self._kv_pos = self._kv_pos.at[:, :wp].set(kvp)
+                else:
+                    logits, pools, self._kv_pos = self._decode_paged(
+                        self.params, self.allocator.pools, table,
+                        self._kv_pos, tokens, dec_pos, sp_pages,
+                    )
+                self.allocator.pools = pools
+        else:
+            n_ready = n_active
+            with tracing.span("scheduler.decode"):
+                logits, self.caches = self._decode(
+                    self.params, self.caches,
+                    *self._put((self._next_tok[:, None], self._pos)),
+                )
+        tracing.count("decode_steps")
+        tracing.count("decode_lanes", n_ready)
+        if chunks:
+            tracing.count("chunk_decode_steps")
+        with tracing.span("scheduler.sync"):
+            nxt = np.asarray(sample(logits[:, 0]))
+
+        with tracing.span("scheduler.finish"):
             for idx, st in enumerate(self.slots):
                 if st is None or not st.prefilled:
                     continue
-                if st.pos >= len(self.slot_pages[idx]) * ps:
-                    fresh = self._alloc_pages(1, exclude=st.cache_key)
-                    if fresh is None:
-                        self._finish_slot(idx, st)
-                        continue
-                    self.slot_pages[idx].append(fresh[0])
-                    self._table[idx, len(self.slot_pages[idx]) - 1] = fresh[0]
-            ready = [
-                i for i, s in enumerate(self.slots)
-                if s is not None and s.prefilled
-            ]
-            if not ready:
-                return  # every occupied lane is mid-prefill
-            tokens = self._put(self._next_tok[:, None])
-            # page-width bucketing: run the jitted decode at the smallest
-            # power-of-two page width covering the longest *decode-ready*
-            # lane, not at max_len — the kernel's grid (pallas) or the
-            # gathered view (reference) then scales with what sessions
-            # actually hold. The layout invariant (slot == position) makes
-            # the trimmed attention identical: every ready lane's tokens
-            # live in its own pages, all inside the trimmed width. At most
-            # log2(MP) decode shapes compile.
-            mp = self._table.shape[1]
-            need = max(len(self.slot_pages[i]) for i in ready)
-            w = 1
-            while w < max(1, need):
-                w *= 2
-            w = min(w, mp)
-            wp = w * ps
-            # mid-prefill lanes ride the batched call but touch nothing:
-            # their decode position is pushed past the trimmed table, so
-            # the KV scatter and the kv_pos relabel both drop
-            # (models/cache.py OOB sentinel), and their logits lane is
-            # never read below
-            dec_pos = self._pos.copy()
-            for i, s in enumerate(self.slots):
-                if s is None or not s.prefilled:
-                    dec_pos[i] = wp
-            # cross-session shared-prefix split (pallas only — the
-            # reference path's gathered view has no per-page DMA to save):
-            # pages every ready lane starts with are attended once per
-            # unique page for the whole batch instead of once per lane
-            sp = None
-            if self.cfg.attn_impl == "pallas":
-                run = self._shared_prefix_run(w)
-                if run:
-                    sp = self._put(np.asarray(run, np.int32))
-                    self.cascade_steps += 1
-            if w < mp:
-                logits, pools, kvp = self._decode_paged(
-                    self.params, self.allocator.pools,
-                    self._put(self._table[:, :w]),
-                    self._kv_pos[:, :wp], tokens, self._put(dec_pos), sp,
-                )
-                self._kv_pos = self._kv_pos.at[:, :wp].set(kvp)
-            else:
-                logits, pools, self._kv_pos = self._decode_paged(
-                    self.params, self.allocator.pools, self._put(self._table),
-                    self._kv_pos, tokens, self._put(dec_pos), sp,
-                )
-            self.allocator.pools = pools
-        else:
-            logits, self.caches = self._decode(
-                self.params, self.caches, *self._put((self._next_tok[:, None], self._pos))
-            )
-        nxt = np.asarray(sample(logits[:, 0]))
-        now = time.perf_counter()
+                tok = int(self._next_tok[idx])
+                st.generated.append(tok)
+                st.pos += 1
+                self._pos[idx] += 1
+                if (
+                    tok in self.stop_tokens
+                    or len(st.generated) >= st.max_new
+                    or st.pos >= self.max_len - 1
+                ):
+                    self._finish_slot(idx, st)
+                else:
+                    self._next_tok[idx] = int(nxt[idx])
+        return True
 
+    def _decode_inputs(self):
+        """Paged decode inputs: grow each decode-ready lane's pages to
+        cover the position it writes, bucket the page width, push
+        mid-prefill lanes out of the step, find the shared-prefix run, and
+        put the table, tokens and positions on the device. None when no
+        lane is decode-ready; else the ready count, whether the width is
+        trimmed below the table's, the trimmed width in slots, and the
+        decode call's inputs."""
+        # grow-on-demand: each decode-ready slot needs a page covering
+        # the position it is about to write; a slot that cannot get one
+        # (pool exhausted, nothing evictable) retires cleanly with the
+        # tokens it has — never a silent mode="drop" KV loss.
+        # Mid-prefill lanes reserved their whole span at admission.
+        ps = self.allocator.page_size
         for idx, st in enumerate(self.slots):
             if st is None or not st.prefilled:
                 continue
-            tok = int(self._next_tok[idx])
-            st.generated.append(tok)
-            st.pos += 1
-            self._pos[idx] += 1
-            # per-token decode gap: inflated for residents while other
-            # tenants' prefill chunks share their steps — exactly the
-            # interference the chunk budget bounds
-            if st.last_tok_t is not None:
-                st.gaps_ms.append((now - st.last_tok_t) * 1e3)
-            st.last_tok_t = now
-            if (
-                tok in self.stop_tokens
-                or len(st.generated) >= st.max_new
-                or st.pos >= self.max_len - 1
-            ):
-                self._finish_slot(idx, st)
-            else:
-                self._next_tok[idx] = int(nxt[idx])
+            if st.pos >= len(self.slot_pages[idx]) * ps:
+                fresh = self._alloc_pages(1, exclude=st.cache_key)
+                if fresh is None:
+                    self._finish_slot(idx, st)
+                    continue
+                self.slot_pages[idx].append(fresh[0])
+                self._table[idx, len(self.slot_pages[idx]) - 1] = fresh[0]
+        ready = [
+            i for i, s in enumerate(self.slots)
+            if s is not None and s.prefilled
+        ]
+        if not ready:
+            return None
+        # page-width bucketing: run the jitted decode at the smallest
+        # power-of-two page width covering the longest *decode-ready*
+        # lane, not at max_len — the kernel's grid (pallas) or the
+        # gathered view (reference) then scales with what sessions
+        # actually hold. The layout invariant (slot == position) makes
+        # the trimmed attention identical: every ready lane's tokens
+        # live in its own pages, all inside the trimmed width. At most
+        # log2(MP) decode shapes compile.
+        mp = self._table.shape[1]
+        need = max(len(self.slot_pages[i]) for i in ready)
+        w = 1
+        while w < max(1, need):
+            w *= 2
+        w = min(w, mp)
+        wp = w * ps
+        # mid-prefill lanes ride the batched call but touch nothing:
+        # their decode position is pushed past the trimmed table, so
+        # the KV scatter and the kv_pos relabel both drop
+        # (models/cache.py OOB sentinel), and their logits lane is
+        # never read below
+        dec_pos = self._pos.copy()
+        for i, s in enumerate(self.slots):
+            if s is None or not s.prefilled:
+                dec_pos[i] = wp
+        # cross-session shared-prefix split (pallas only — the
+        # reference path's gathered view has no per-page DMA to save):
+        # pages every ready lane starts with are attended once per
+        # unique page for the whole batch instead of once per lane
+        sp = None
+        if self.cfg.attn_impl == "pallas":
+            run = self._shared_prefix_run(w)
+            if run:
+                sp = self._put(np.asarray(run, np.int32))
+                tracing.count("cascade_steps")
+        table = self._put(self._table[:, :w] if w < mp else self._table)
+        tokens = self._put(self._next_tok[:, None])
+        return len(ready), w < mp, wp, table, tokens, self._put(dec_pos), sp
 
     # -- churn -----------------------------------------------------------
     def crash(self) -> None:
@@ -905,7 +932,6 @@ class BatchedServer:
         returns every page to the free list (the allocator survives as the
         restarted process's fresh pool)."""
         self.queue.clear()
-        self._submit_times.clear()
         for idx in range(self.n_slots):
             self.slots[idx] = None
             if self.paged and self.slot_pages[idx]:
@@ -979,6 +1005,63 @@ class BatchedServer:
         return warm
 
 
+def timed_step(server: BatchedServer) -> Tuple[float, List[int]]:
+    """Run one ``server.step()``. Returns its host duration in ms and the
+    ids of the requests that got a token in it (each lane gets at most
+    one a step), so a caller can stamp each token with the step's end on
+    whatever clock it lays steps onto."""
+    before = {s.request_id: len(s.generated) for s in server.slots if s is not None}
+    n_fin = len(server.finished)
+    w0 = time.perf_counter()
+    server.step()
+    dt = (time.perf_counter() - w0) * 1e3
+    got = [
+        s.request_id for s in server.slots
+        if s is not None and len(s.generated) > before.get(s.request_id, 0)
+    ]
+    got += [
+        f.request_id for f in server.finished[n_fin:]
+        if len(f.token_ids) > before.get(f.request_id, 0)
+    ]
+    return dt, got
+
+
+def token_latency(submitted_ms: float, token_ms: List[float]) -> Dict[str, float]:
+    """Time to first token from submission, and the 50th and 99th
+    percentiles of the gaps between consecutive tokens (all in ms)."""
+    gaps = np.diff(token_ms) if len(token_ms) > 1 else []
+    return {
+        "ttft_ms": token_ms[0] - submitted_ms if token_ms else 0.0,
+        "decode_p50_ms": float(np.percentile(gaps, 50)) if len(gaps) else 0.0,
+        "decode_p99_ms": float(np.percentile(gaps, 99)) if len(gaps) else 0.0,
+    }
+
+
+def trace_readings(trace: tracing.Trace) -> Dict[str, Optional[float]]:
+    """The scheduler's readings over a trace, each None where the trace
+    holds nothing to read:
+
+    - ``step_host_ms``: mean host time of a ``scheduler.step`` outside its
+      ``scheduler.sync`` children — the host work during which the device
+      waits for the step's inputs;
+    - ``chunk_step_share``: decode steps that also dispatched a prefill
+      chunk, over decode steps;
+    - ``decode_lanes_per_step``: decode-ready lanes summed over decode
+      steps, over decode steps (continuous-batching occupancy)."""
+    kids = trace.children()
+    host = [
+        s.ms - sum(k.ms for k in kids.get(s.id, ()) if k.name == "scheduler.sync")
+        for s in trace.named("scheduler.step")
+    ]
+    c = trace.counters
+    dec = c.get("decode_steps", 0)
+    return {
+        "step_host_ms": sum(host) / len(host) if host else None,
+        "chunk_step_share": c.get("chunk_decode_steps", 0) / dec if dec else None,
+        "decode_lanes_per_step": c.get("decode_lanes", 0) / dec if dec else None,
+    }
+
+
 @dataclass
 class _PendingBatched:
     """Per-request bookkeeping between BatchedLLMService.submit and the
@@ -988,6 +1071,8 @@ class _PendingBatched:
     submitted_ms: float
     n_input: int
     admitted_ms: Optional[float] = None
+    # the cluster-clock end of each step that gave the request a token
+    token_ms: List[float] = field(default_factory=list)
 
 
 class BatchedLLMService:
@@ -1006,7 +1091,10 @@ class BatchedLLMService:
     step's cost. Per request, ``queue_ms`` is submit→slot-admission wait
     and ``inference_ms`` is admission→completion (its share of the batch's
     prefill + decode steps); ``batch_size`` reports the peak batch it rode
-    in. ``completion()`` is the blocking shim: submit, pump synchronously,
+    in; a token's time is the end of the step that produced it, on the
+    same clock, which gives ``ttft_ms`` (from submit) and the decode gap
+    percentiles — each node counts only its own steps, where the host
+    clock would count every node sharing the process. ``completion()`` is the blocking shim: submit, pump synchronously,
     return — used by serialized callers and micro-benchmarks."""
 
     def __init__(
@@ -1073,6 +1161,15 @@ class BatchedLLMService:
         )
         tok = get_tokenizer(cfg.vocab_size, seed=tokenizer_seed, name=model)
         return cls(model=model, server=server, tokenizer=tok)
+
+    @property
+    def node_id(self) -> Optional[str]:
+        """The node this service runs on, as trace spans name it."""
+        return self.server.node_id
+
+    @node_id.setter
+    def node_id(self, node_id: Optional[str]) -> None:
+        self.server.node_id = node_id
 
     # -- LLMServiceProtocol ---------------------------------------------
     def capabilities(self) -> ServiceCapabilities:
@@ -1235,19 +1332,21 @@ class BatchedLLMService:
         ids, max_new = truncate_for_cache(
             context_ids, prompt_ids, self.server.max_len, max_new_tokens
         )
-        t0 = time.perf_counter()
         rid = self.server.submit(ids, max_new=max_new, cache_key=cache_key)
         done: Dict[int, FinishedRequest] = {}
+        elapsed, token_ms = 0.0, []
         while rid not in done:
-            self.server.step()
+            dt, got = timed_step(self.server)
+            elapsed += dt
+            if rid in got:
+                token_ms.append(elapsed)
             for f in self.server.finished[self._seen_finished:]:
                 done[f.request_id] = f
             self._seen_finished = len(self.server.finished)
         self._drain_consumed()
-        f = done[rid]
         return self._result_from(
-            f, n_input=len(ids), inference_ms=(time.perf_counter() - t0) * 1e3,
-            queue_ms=0.0,
+            done[rid], n_input=len(ids), inference_ms=elapsed, queue_ms=0.0,
+            latency=token_latency(0.0, token_ms),
         )
 
     def _drain_consumed(self) -> None:
@@ -1280,15 +1379,17 @@ class BatchedLLMService:
         if not self.server.busy:
             return
         t = net.clock.now_ms
-        queued_before = {q[0] for q in self.server.queue}
-        w0 = time.perf_counter()
-        self.server.step()
-        dt = (time.perf_counter() - w0) * 1e3
+        with tracing.span("serve.pump", self.server.node_id, net=net):
+            queued_before = {q[0] for q in self.server.queue}
+            dt, got = timed_step(self.server)
         end = t + dt
         self._busy_until = end
         for rid in queued_before - {q[0] for q in self.server.queue}:
             if rid in self._pending:
                 self._pending[rid].admitted_ms = t
+        for rid in got:
+            if rid in self._pending:
+                self._pending[rid].token_ms.append(end)
         for f in self.server.finished[self._seen_finished:]:
             p = self._pending.pop(f.request_id, None)
             if p is None:
@@ -1298,6 +1399,7 @@ class BatchedLLMService:
                 f, n_input=p.n_input,
                 inference_ms=end - admitted,
                 queue_ms=admitted - p.submitted_ms,
+                latency=token_latency(p.submitted_ms, p.token_ms),
             )
             net.schedule(end, lambda r=result, cb=p.on_done: cb(r))
         self._seen_finished = len(self.server.finished)
@@ -1312,6 +1414,7 @@ class BatchedLLMService:
         n_input: int,
         inference_ms: float,
         queue_ms: float,
+        latency: Dict[str, float],
     ) -> ServiceResult:
         stop = self.server.stop_tokens
         text = self.tokenizer.decode([t for t in f.token_ids if t not in stop])
@@ -1326,7 +1429,5 @@ class BatchedLLMService:
             warm_source=f.warm_source,
             queue_ms=queue_ms,
             batch_size=f.batch_size,
-            ttft_ms=f.ttft_ms,
-            decode_p50_ms=f.decode_p50_ms,
-            decode_p99_ms=f.decode_p99_ms,
+            **latency,
         )

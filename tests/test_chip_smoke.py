@@ -80,7 +80,7 @@ def test_smoke_phases_at_reduced_size(restore_cache_dir):
 
     smoke = _load_smoke()
     cfg = get_config("paper-qwen1.5-0.5b").reduced()
-    cluster, model, responses = smoke.served_path(cfg)
+    cluster, model, responses, trace = smoke.served_path(cfg)
     services = [n.service for n in cluster.nodes.values()]
     assert len({id(s) for s in services}) == 2
     for svc in services:
@@ -88,6 +88,7 @@ def test_smoke_phases_at_reduced_size(restore_cache_dir):
         assert svc.server.paged and svc.server.cfg.attn_impl == "pallas"
         assert svc.server.allocator.pools[0]["k"].devices() == {jax.devices()[0]}
     assert sum(len(rs) for rs in responses.values()) == 13
+    assert trace.counters["steps"] == len(trace.named("scheduler.step"))
     srv = cluster.node("edge-0").service.server
     *checks, fault = smoke.reference_check(srv.cfg, srv.params, srv.device)
     for name, d, scale in checks:

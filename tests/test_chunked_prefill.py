@@ -159,8 +159,13 @@ def test_chunk_budgets_token_identical(tiny_dense_cfg):
 
 
 def test_latency_accounting_populated(tiny_dense_cfg):
-    """FinishedRequest carries ttft and per-token decode gap percentiles;
-    a later tenant's ttft includes its queue/chunk wait."""
+    """The batched service's results carry ttft and per-token decode gap
+    percentiles, read on the cluster clock from the steps that produced
+    each token; a tenant's ttft includes its queue/chunk wait."""
+    from repro.serving import BatchedLLMService
+    from repro.store import Network
+    from repro.tokenizer import get_tokenizer
+
     cfg = tiny_dense_cfg
     params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(5)
@@ -170,11 +175,17 @@ def test_latency_accounting_populated(tiny_dense_cfg):
         session_pool=SessionCachePool(capacity=4),
         paged=True, page_size=PS, prefill_chunk_tokens=16,
     )
+    svc = BatchedLLMService("tiny", srv, get_tokenizer(cfg.vocab_size, seed=0))
+    net = Network()
+    results = []
     for i, r in enumerate(reqs):
-        srv.submit(r, max_new=5, cache_key=f"k{i}")
-    for f in srv.run_to_completion():
+        svc.submit([], r, 5, cache_key=f"k{i}", net=net, on_done=results.append)
+    end = net.run_until_quiet()
+    assert len(results) == 2
+    for f in results:
         assert f.ttft_ms > 0.0
         assert f.decode_p99_ms >= f.decode_p50_ms > 0.0
+        assert f.queue_ms <= f.ttft_ms <= f.queue_ms + f.inference_ms <= end
 
 
 def test_fifo_fair_admission_no_starvation(tiny_dense_cfg):

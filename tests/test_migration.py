@@ -11,7 +11,7 @@ run on the analytic echo service; the real-engine equivalence tests carry
 import jax
 import pytest
 
-from repro.core import ContextMode
+from repro.core import ContextMode, tracing
 from repro.edge import EchoLLMService, EdgeCluster, LLMClient
 from repro.models import ModelConfig, init_params
 from repro.serving import BatchedServer, CacheEntry, JaxLLMService, SessionCachePool
@@ -49,7 +49,11 @@ def test_roam_turn_is_warm_start():
     """A→B roam with eager warm-start: B's turn is a primed hit that
     prefills only the prompt, and both counters surface in Timing."""
     cluster = _echo_cluster("eager")
-    r1, r2, r3 = _roam(cluster, ["a", "a", "b"])
+    tracing.start()
+    try:
+        r1, r2, r3 = _roam(cluster, ["a", "a", "b"])
+    finally:
+        trace = tracing.stop()
     assert not r1.timing.migrated and not r2.timing.migrated
     assert not r1.timing.kv_warm_start and not r2.timing.kv_warm_start
     assert r2.timing.kv_cache_hit  # same-node hit, served (not primed) prefix
@@ -59,7 +63,9 @@ def test_roam_turn_is_warm_start():
     assert t3.kv_reused_tokens == r3.n_context_tokens
     assert t3.prefill_tokens == r3.n_prompt_tokens
     assert cluster.node("b").warm_starts >= 1
-    assert cluster.node("b").warm_start_ms >= 0.0
+    primes = [s for s in trace.named("node.prime") if s.node == "b"]
+    assert len(primes) >= cluster.node("b").warm_starts
+    assert all(s.ms >= 0.0 and s.attrs["tokens"] > 0 for s in primes)
 
 
 def test_roam_without_warm_start_is_cold():

@@ -204,3 +204,48 @@ def test_paged_decode_step_fits_v5e(chip_compile, monkeypatch):
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
     assert total < V5E_HBM_BYTES, total
+
+
+def test_kernel_names_in_a_decode_step_and_a_chunk(chip_compile, monkeypatch):
+    """The paged-attention, shared-prefix and chunked-prefill kernels run
+    under names they give themselves, so a device trace finds them by the
+    instruction names ``%paged_attention.N``, ``%shared_prefix.N`` and
+    ``%chunked_prefill.N`` however the code around them is arranged."""
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.chunked_prefill import ops as chunk_ops
+    from repro.kernels.paged_attention import ops as paged_ops
+    from repro.models import decode_step_paged, init_params, layer_groups
+    from repro.models.prefill import prefill_chunk_paged
+
+    for mod in (paged_ops, chunk_ops):
+        monkeypatch.setattr(mod, "_on_cpu", lambda: False)
+    cfg = get_config("paper-qwen1.5-0.5b").replace(attn_impl="pallas", n_layers=2)
+    n_slots, max_len = 4, 256
+    n_pages, mp = 1 + 12 * (max_len // PS), max_len // PS
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    pools = [
+        {name: _s((spec.n_blocks, n_pages, PS, cfg.n_kv_heads, cfg.d_head))
+         for name in ("k", "v")}
+        for spec in layer_groups(cfg)
+    ]
+    i32 = jnp.int32
+
+    def names(compiled):
+        return set(re.findall(r"%([a-z_]+)\.\d+ = [^\n]*custom-call\(",
+                              compiled.as_text()))
+
+    decode = chip_compile(
+        lambda p, pl_, t, kp, tok, pos, sp: decode_step_paged(
+            p, cfg, pl_, t, kp, tok, pos, sp),
+        params, pools, _s((n_slots, mp), i32), _s((n_slots, max_len), i32),
+        _s((n_slots, 1), i32), _s((n_slots,), i32), _s((2,), i32),
+    )
+    assert {"paged_attention", "shared_prefix"} <= names(decode)
+    chunk = chip_compile(
+        lambda p, pl_, t, tok, p0, n: prefill_chunk_paged(p, cfg, pl_, t, tok, p0, n),
+        params, pools, _s((1, mp), i32), _s((1, 64), i32), _s((1,), i32),
+        _s((1,), i32),
+    )
+    assert "chunked_prefill" in names(chunk)
