@@ -13,7 +13,9 @@ dereference ``table[b, p]`` directly, and each grid step DMAs one physical
 page into VMEM — no dense ``max_len``-width intermediate ever exists.
 
 Grid: ``(batch, MP)`` — page-blocks innermost, identical to the decode
-kernel, with the same whole-page ``(1, ps, KV, Dh)`` K/V blocks and the same
+kernel, with the same whole-page K/V blocks of one layer of the group's
+``(L, P, ps, KV*Dh)`` stack (the layer a scalar-prefetch operand, so the
+prefill step updates the stack in place and never slices it) and the same
 per-page body (``paged_attention.kernel._attend_page``, a static loop over
 the KV heads). The only difference is the query block: S chunk rows × G
 query heads share each page load, carried per KV head as one ``(S*G, Dh)``
@@ -44,11 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..paged_attention.kernel import NEG_INF, _attend_page
+from ..paged_attention.kernel import NEG_INF, _attend_page, page_block
 
 
 def _chunked_prefill_kernel(
-    table_ref, bound_ref, p0_ref,       # scalar prefetch (SMEM)
+    table_ref, bound_ref, p0_ref, layer_ref,  # scalar prefetch (SMEM)
     row_ref, q_ref, k_ref, v_ref,       # tensor blocks
     o_ref,                              # output
     acc_ref, m_ref, l_ref,              # VMEM scratch (persist over ip)
@@ -89,11 +91,12 @@ def _chunked_prefill_kernel(
 
 def chunked_prefill_pallas(
     q: jnp.ndarray,           # (B, KV, S*G, Dh) — reshaped + rope'd by ops.py
-    pool_k: jnp.ndarray,      # (P, page_size, KV, Dh) — post-scatter pool
+    pool_k: jnp.ndarray,      # (L, P, page_size, KV*Dh) — post-scatter stack
     pool_v: jnp.ndarray,
     page_table: jnp.ndarray,  # (B, MP) int32 physical page ids per lane
     page_bound: jnp.ndarray,  # (B,) int32 — ceil((p0+true_len)/ps), in [1, MP]
     p0: jnp.ndarray,          # (B,) int32 absolute position of chunk row 0
+    layer: jnp.ndarray,       # (1,) int32 — the layer of the stack to read
     *,
     g: int,
     window: int = 0,
@@ -101,30 +104,30 @@ def chunked_prefill_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     b, kvh, sg, dh = q.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     mp = page_table.shape[1]
     scale = 1.0 / (dh ** 0.5)
 
-    def page_map(bi, ip, table, bound, p0_):
+    def page_map(bi, ip, table, bound, p0_, lyr):
         # beyond-bound steps re-map to the lane's last real page: the block
         # index repeats, so the pipeline skips the DMA and table padding
         # (the scratch page) is never dereferenced for an active lane
-        return (table[bi, jnp.minimum(ip, bound[bi] - 1)], 0, 0, 0)
+        return (lyr[0], table[bi, jnp.minimum(ip, bound[bi] - 1)], 0, 0)
 
-    def lane_map(bi, ip, table, bound, p0_):
+    def lane_map(bi, ip, table, bound, p0_, lyr):
         return (bi, 0, 0, 0)
 
-    def rows_map(bi, ip, table, bound, p0_):
+    def rows_map(bi, ip, table, bound, p0_, lyr):
         return (0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, mp),
         in_specs=[
             pl.BlockSpec((sg, 1), rows_map),
             pl.BlockSpec((1, kvh, sg, dh), lane_map),
-            pl.BlockSpec((1, ps, kvh, dh), page_map),
-            pl.BlockSpec((1, ps, kvh, dh), page_map),
+            page_block(ps, kvh, dh, page_map),
+            page_block(ps, kvh, dh, page_map),
         ],
         out_specs=pl.BlockSpec((1, kvh, sg, dh), lane_map),
         scratch_shapes=[
@@ -147,5 +150,5 @@ def chunked_prefill_pallas(
         name="chunked_prefill",
     )(
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
-        p0.astype(jnp.int32), rows, q, pool_k, pool_v,
+        p0.astype(jnp.int32), layer.astype(jnp.int32), rows, q, pool_k, pool_v,
     )

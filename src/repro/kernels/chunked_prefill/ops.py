@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..paged_attention.ops import as_layer_stack
 from .kernel import chunked_prefill_pallas
 
 
@@ -18,11 +19,12 @@ def _on_cpu() -> bool:
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
 def chunked_prefill_attention(
     q,            # (B, S, H, Dh) — rope'd chunk queries
-    pool_k,       # (P, page_size, KV, Dh) — post-scatter pool, one layer
+    pool_k,       # (P, ps, KV, Dh), or a (L, P, ps, KV*Dh) stack with layer
     pool_v,
     page_table,   # (B, MP) physical page ids per lane
     p0,           # (B,) absolute position of chunk row 0
     true_len,     # (B,) real chunk lengths (bucketed input)
+    layer=None,   # () int: the layer of a stacked pool to attend
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -36,12 +38,15 @@ def chunked_prefill_attention(
     ``attention_append``. The per-lane page bound
     ``ceil((p0 + true_len) / page_size)`` relies on the layout invariant
     (slot index == absolute position for valid slots), under which no key
-    at or beyond ``p0 + true_len`` can pass any read row's causal mask."""
+    at or beyond ``p0 + true_len`` can pass any read row's causal mask.
+    A stacked pool is read in place at ``layer``, a one-layer pool as a
+    stack of one (``paged_attention``'s rule)."""
     if interpret is None:
         interpret = _on_cpu()
+    pool_k, pool_v, lyr = as_layer_stack(pool_k, pool_v, layer)
     b, s, h, dh = q.shape
-    ps = pool_k.shape[1]
-    kvh = pool_k.shape[2]
+    ps = pool_k.shape[2]
+    kvh = pool_k.shape[3] // dh
     g = h // kvh
     mp = page_table.shape[1]
     p0 = p0.reshape(b).astype(jnp.int32)
@@ -53,7 +58,7 @@ def chunked_prefill_attention(
         b, kvh, s * g, dh
     )
     out = chunked_prefill_pallas(
-        qr, pool_k, pool_v, page_table, bound, p0,
+        qr, pool_k, pool_v, page_table, bound, p0, lyr,
         g=g, window=window, softcap=softcap, interpret=interpret,
     )
     return out.reshape(b, kvh, s, g, dh).transpose(0, 2, 1, 3, 4).reshape(

@@ -2,8 +2,9 @@
 (Pallas TPU).
 
 The paged serving path (`serving/paged_kv.py`) stores each sequence as a
-*page table* over one shared physical pool, ``(P, page_size, KV, Dh)`` per
-layer. The gather-materialize fallback linearizes that table into a
+*page table* over one shared physical pool, ``(L, P, page_size, KV*Dh)``
+per layer group (`models/cache.py`, ``init_paged_pool``). The
+gather-materialize fallback linearizes that table into a
 ``(B, MP·page_size, KV, Dh)`` copy before attending — O(max_len) HBM
 traffic per token per lane, even for a 40-token session. This kernel
 attends *through* the table instead (vLLM-style paged attention): the page
@@ -12,14 +13,21 @@ table and per-lane page bounds are scalar-prefetch operands, so the K/V
 step DMAs one physical page from the pool into VMEM — no linearized copy
 ever exists.
 
+The kernels read the group's whole layer stack and take the layer as one
+more scalar-prefetch operand: the K/V index maps are ``(layer, table[b, p],
+0, 0)``. A ``pool[l]`` operand would not do: a custom call's operand is a
+buffer of its own, so the slice would copy the layer's pool every layer of
+every step, and the decode step could not update the stack in place.
+
 Grid: ``(batch, MP)`` — page-blocks innermost. Each step's K/V block is one
-whole page, ``(1, ps, KV, Dh)``: the block's trailing ``(KV, Dh)`` equals
-the pool's, which is what Mosaic's tiling rule asks of the last two block
-dims, and every head is served by that single DMA. The kernel loops over
-the KV heads inside (a static unroll); each head's online softmax carries
-``(m, l, acc)`` in VMEM scratch across the page dimension exactly like the
-dense flash-decode kernel, and all G query heads of one KV head share the
-head's page slice (GQA grouping). Two raggedness levers keep the cost
+whole page of one layer, ``(1, 1, ps, KV*Dh)`` with the layer axis
+squeezed: the block's last two dims equal the pool's, which is what
+Mosaic's tiling rule asks of a block, and every head is served by that
+single DMA. The kernel loops over the KV heads inside (a static unroll),
+head ``h`` reading lanes ``[h*Dh, (h+1)*Dh)`` of the page; each head's
+online softmax carries ``(m, l, acc)`` in VMEM scratch across the page
+dimension exactly like the dense flash-decode kernel, and all G query
+heads of one KV head share the head's page slice (GQA grouping). Two raggedness levers keep the cost
 proportional to *actual* tokens:
 
 - steps with ``p >= bound[b]`` (``bound = ceil(kv_len / page_size)``) skip
@@ -53,24 +61,35 @@ _NT = (((1,), (1,)), ((), ()))   # (M, Dh) x (N, Dh) -> (M, N)
 _NN = (((1,), (0,)), ((), ()))   # (M, K) x (K, N) -> (M, N)
 
 
+def page_block(ps: int, kvh: int, dh: int, index_map) -> pl.BlockSpec:
+    """One page of one layer of an ``(L, P, ps, KV*Dh)`` pool stack. The
+    layer axis is squeezed (``None``), so the kernel body sees the page as
+    ``(1, ps, KV*Dh)``; its last two dims are the array's, which is
+    Mosaic's tiling rule for a block."""
+    return pl.BlockSpec((None, 1, ps, kvh * dh), index_map)
+
+
 def _attend_page(
     q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
     *, kvh: int, softcap: float, scale: float, lane=(),
 ):
     """One lane x one page of the online-softmax recurrence, every KV head.
     ``q_ref`` is the lane's ``(1, KV, G, Dh)`` block, ``k_ref``/``v_ref``
-    the page's ``(1, ps, KV, Dh)`` block and ``acc/m/l_ref[lane]`` the
-    lane's ``(KV, G, ·)`` state (``lane`` prefixes the index, not a ref
+    the page's ``(1, ps, KV*Dh)`` block (head ``h`` in lanes
+    ``[h*Dh, (h+1)*Dh)``) and ``acc/m/l_ref[lane]`` the lane's
+    ``(KV, G, ·)`` state (``lane`` prefixes the index, not a ref
     view: Mosaic cannot slice the unit lane axis of the m/l scratch);
     ``mask`` is the slot mask, ``(1, ps)`` or one row per query row. ``p``
     is zeroed on masked slots (not just NEG_INF logits), so a row with no
     valid key accumulates ``l == 0`` and finalizes to exact zeros
     independent of how many pages were visited."""
+    dh = q_ref.shape[-1]
     for h in range(kvh):
         st = lane + (h,)
+        heads = slice(h * dh, (h + 1) * dh)
         q = q_ref[0, h].astype(jnp.float32)             # (G, Dh)
-        k = k_ref[0, :, h, :].astype(jnp.float32)       # (ps, Dh)
-        v = v_ref[0, :, h, :].astype(jnp.float32)
+        k = k_ref[0, :, heads].astype(jnp.float32)      # (ps, Dh)
+        v = v_ref[0, :, heads].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, _NT, preferred_element_type=jnp.float32
         ) * scale                                       # (G, ps)
@@ -90,7 +109,7 @@ def _attend_page(
 
 
 def _paged_decode_kernel(
-    table_ref, bound_ref, qpos_ref,     # scalar prefetch (SMEM)
+    table_ref, bound_ref, qpos_ref, layer_ref,  # scalar prefetch (SMEM)
     kvpos_ref, q_ref, k_ref, v_ref,     # tensor blocks
     *refs,                              # [acc0, m0, l0,] o | scratch acc, m, l
     n_pb: int, kvh: int, window: int, softcap: float, scale: float,
@@ -140,7 +159,7 @@ def _paged_decode_kernel(
 
 
 def _shared_prefix_kernel(
-    pages_ref, qpos_ref,                # scalar prefetch (SMEM)
+    pages_ref, qpos_ref, layer_ref,     # scalar prefetch (SMEM)
     q_ref, k_ref, v_ref,                # tensor blocks
     acc_o, m_o, l_o,                    # outputs: softmax stats, all lanes
     acc_ref, m_ref, l_ref,              # VMEM scratch (persist over ip)
@@ -192,10 +211,11 @@ def _shared_prefix_kernel(
 
 def shared_prefix_pallas(
     q: jnp.ndarray,             # (B, KV, G, Dh) — reshaped + rope'd by ops.py
-    pool_k: jnp.ndarray,        # (P, page_size, KV, Dh)
+    pool_k: jnp.ndarray,        # (L, P, page_size, KV*Dh) — a layer stack
     pool_v: jnp.ndarray,
     shared_pages: jnp.ndarray,  # (S,) int32 physical page ids, positions [0, S*ps)
     q_pos: jnp.ndarray,         # (B,) int32
+    layer: jnp.ndarray,         # (1,) int32 — the layer of the stack to read
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -204,14 +224,14 @@ def shared_prefix_pallas(
     """Partial-softmax stats of all lanes over the shared run:
     ``(acc, m, l)`` each ``(B, KV, G, ·)`` float32."""
     b, kvh, g, dh = q.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     n_sp = shared_pages.shape[0]
     scale = 1.0 / (dh ** 0.5)
 
-    def page_map(ip, bi, pages, qpos):
-        return (pages[ip], 0, 0, 0)
+    def page_map(ip, bi, pages, qpos, lyr):
+        return (lyr[0], pages[ip], 0, 0)
 
-    def lane_map(ip, bi, pages, qpos):
+    def lane_map(ip, bi, pages, qpos, lyr):
         return (bi, 0, 0, 0)
 
     # VMEM holds every lane's softmax state in scratch, but only one lane's
@@ -219,12 +239,12 @@ def shared_prefix_pallas(
     # a few dozen lanes
     stats = lambda last: pl.BlockSpec((1, kvh, g, last), lane_map)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_sp, b),
         in_specs=[
             pl.BlockSpec((1, kvh, g, dh), lane_map),
-            pl.BlockSpec((1, ps, kvh, dh), page_map),
-            pl.BlockSpec((1, ps, kvh, dh), page_map),
+            page_block(ps, kvh, dh, page_map),
+            page_block(ps, kvh, dh, page_map),
         ],
         out_specs=[stats(dh), stats(1), stats(1)],
         scratch_shapes=[
@@ -249,18 +269,19 @@ def shared_prefix_pallas(
         name="shared_prefix",
     )(
         shared_pages.astype(jnp.int32), q_pos.astype(jnp.int32),
-        q, pool_k, pool_v,
+        layer.astype(jnp.int32), q, pool_k, pool_v,
     )
 
 
 def paged_attention_pallas(
     q: jnp.ndarray,           # (B, KV, G, Dh) — reshaped + rope'd by ops.py
-    pool_k: jnp.ndarray,      # (P, page_size, KV, Dh) — shared pool, one layer
+    pool_k: jnp.ndarray,      # (L, P, page_size, KV*Dh) — a layer stack
     pool_v: jnp.ndarray,
     page_table: jnp.ndarray,  # (B, MP) int32 physical page ids per lane
     page_bound: jnp.ndarray,  # (B,) int32 — ceil(kv_len / ps), in [1, MP]
     q_pos: jnp.ndarray,       # (B,) int32 absolute position of the query
     kv_pos: jnp.ndarray,      # (B, MP, page_size) int32, -1 = empty slot
+    layer: jnp.ndarray,       # (1,) int32 — the layer of the stack to read
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -269,22 +290,23 @@ def paged_attention_pallas(
     init=None,                # optional (acc, m, l) stats from the shared pass
 ) -> jnp.ndarray:
     b, kvh, g, dh = q.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     mp = page_table.shape[1]
     scale = 1.0 / (dh ** 0.5)
     assert 0 <= start < mp, (start, mp)
     has_init = init is not None
 
-    def page_map(bi, ip, table, bound, qpos):
+    def page_map(bi, ip, table, bound, qpos, lyr):
         # beyond-bound steps re-map to the lane's last real page: the block
         # index repeats, so the pipeline skips the DMA and the scratch page
         # (table padding) is never dereferenced for an active lane
-        return (table[bi, jnp.minimum(ip + start, bound[bi] - 1)], 0, 0, 0)
+        p = table[bi, jnp.minimum(ip + start, bound[bi] - 1)]
+        return (lyr[0], p, 0, 0)
 
-    def kvpos_map(bi, ip, table, bound, qpos):
+    def kvpos_map(bi, ip, table, bound, qpos, lyr):
         return (bi, jnp.minimum(ip + start, bound[bi] - 1), 0, 0)
 
-    def lane_map(bi, ip, table, bound, qpos):
+    def lane_map(bi, ip, table, bound, qpos, lyr):
         return (bi, 0, 0, 0)
 
     in_specs = [
@@ -292,8 +314,8 @@ def paged_attention_pallas(
         # (1, ps) equal the array's — Mosaic's tiling rule for blocks
         pl.BlockSpec((1, 1, 1, ps), kvpos_map),
         pl.BlockSpec((1, kvh, g, dh), lane_map),
-        pl.BlockSpec((1, ps, kvh, dh), page_map),
-        pl.BlockSpec((1, ps, kvh, dh), page_map),
+        page_block(ps, kvh, dh, page_map),
+        page_block(ps, kvh, dh, page_map),
     ]
     if has_init:
         in_specs += [
@@ -302,7 +324,7 @@ def paged_attention_pallas(
             pl.BlockSpec((1, kvh, g, 1), lane_map),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, mp - start),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kvh, g, dh), lane_map),
@@ -318,8 +340,8 @@ def paged_attention_pallas(
     )
     args = [
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
-        q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32).reshape(b, mp, 1, ps),
-        q, pool_k, pool_v,
+        q_pos.astype(jnp.int32), layer.astype(jnp.int32),
+        kv_pos.astype(jnp.int32).reshape(b, mp, 1, ps), q, pool_k, pool_v,
     ]
     if has_init:
         args += list(init)

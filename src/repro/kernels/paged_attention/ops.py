@@ -18,17 +18,29 @@ def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def as_layer_stack(pool_k, pool_v, layer):
+    """``(pool_k, pool_v, layer)`` as the kernels take them: ``(L, P, ps,
+    KV*Dh)`` stacks and a ``(1,)`` int32 layer index. Without a layer the
+    pool is one layer's, ``(P, ps, KV, Dh)``, and becomes a stack of one
+    read at layer 0. A stack already flat passes through unchanged."""
+    if layer is None:
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
+    flat = lambda a: a.reshape(a.shape[:3] + (-1,))  # noqa: E731
+    return flat(pool_k), flat(pool_v), jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 @functools.partial(
     jax.jit, static_argnames=("window", "softcap", "max_pages", "interpret")
 )
 def paged_attention(
     q,            # (B, 1, H, Dh) — rope'd query token
-    pool_k,       # (P, page_size, KV, Dh) — shared physical pool, one layer
+    pool_k,       # (P, ps, KV, Dh), or a (L, P, ps, KV*Dh) stack with layer
     pool_v,
     page_table,   # (B, MP) physical page ids per lane
     q_pos,        # (B, 1) absolute position of the query token
     kv_pos,       # (B, MP*page_size) absolute positions per virtual slot
     shared_pages=None,  # (S,) page ids every lane's table starts with
+    layer=None,   # () int: the layer of a stacked pool to attend
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -53,12 +65,19 @@ def paged_attention(
     shared pass's online-softmax stats — per-step K/V traffic drops from
     O(B·kv_len) to O(unique_pages + B·suffix). The two-pass result is the
     exact continuation of the single-pass softmax recurrence.
+
+    With ``layer``, the pool is a group's stack, ``(L, P, page_size,
+    KV*Dh)`` as ``init_paged_pool`` makes it, read in place at that layer:
+    the layer scan of a decode step hands the kernel the stack it updates,
+    never a slice of it. Without, it is one layer's ``(P, page_size, KV,
+    Dh)`` pool, read as a stack of one at layer 0.
     """
     if interpret is None:
         interpret = _on_cpu()
+    pool_k, pool_v, lyr = as_layer_stack(pool_k, pool_v, layer)
     b, _, h, dh = q.shape
-    ps = pool_k.shape[1]
-    kvh = pool_k.shape[2]
+    ps = pool_k.shape[2]
+    kvh = pool_k.shape[3] // dh
     g = h // kvh
     mp = page_table.shape[1]
     if max_pages is not None and max_pages < mp:
@@ -75,12 +94,12 @@ def paged_attention(
         start = min(int(shared_pages.shape[0]), mp - 1)
         if start > 0:
             init = shared_prefix_pallas(
-                qr, pool_k, pool_v, shared_pages[:start], qp,
+                qr, pool_k, pool_v, shared_pages[:start], qp, lyr,
                 window=window, softcap=softcap, interpret=interpret,
             )
     out = paged_attention_pallas(
         qr, pool_k, pool_v, page_table, bound, qp,
-        kv_pos.reshape(b, mp, ps),
+        kv_pos.reshape(b, mp, ps), lyr,
         window=window, softcap=softcap, interpret=interpret,
         start=start, init=init,
     )

@@ -173,7 +173,7 @@ def attention_decode_paged(
     p: Params,
     x: jnp.ndarray,              # (B,1,D) — the single new token
     positions: jnp.ndarray,      # (B,1) or (3,B,1)
-    pool_k: jnp.ndarray,         # (P, ps, KV, Dh) — shared page pool, one layer
+    pool_k: jnp.ndarray,         # (P, ps, KV, Dh), or a (L, P, ps, KV*Dh) stack
     pool_v: jnp.ndarray,
     page_table: jnp.ndarray,     # (B, MP) physical page ids per lane
     kv_pos: jnp.ndarray,         # (B, MP*ps) absolute positions per virtual slot
@@ -182,6 +182,7 @@ def attention_decode_paged(
     lin_k: Optional[jnp.ndarray] = None,  # (B, MP*ps, KV, Dh) pre-gathered view
     lin_v: Optional[jnp.ndarray] = None,
     shared_pages: Optional[jnp.ndarray] = None,  # (S,) common leading pages
+    layer=None,                  # the layer of a stacked pool to attend
 ) -> jnp.ndarray:
     """Page-table-aware decode, two execution paths:
 
@@ -205,6 +206,10 @@ def attention_decode_paged(
     attends those once per unique page for the whole batch and walks only
     the per-lane suffix (docs/architecture.md, "Cross-session shared-prefix
     paging").
+
+    With ``layer``, ``pool_k/v`` are a group's ``(L, P, ps, KV*Dh)``
+    stack: the kernel reads that layer of it in place, and the reference
+    path takes the caller's gathered view.
     """
     pos1d = positions[0] if positions.ndim == 3 else positions
     if cfg.attn_impl == "pallas":
@@ -214,7 +219,7 @@ def attention_decode_paged(
         q = _project_q_step(p, x, positions, cfg)
         out = paged_ops.paged_attention(
             q, pool_k, pool_v, page_table, pos1d, kv_pos,
-            shared_pages,
+            shared_pages, layer,
             window=window, softcap=cfg.attn_softcap,
         )
         out = out.reshape(b, 1, cfg.n_heads * cfg.d_head)
@@ -269,7 +274,7 @@ def attention_chunk_paged(
     x: jnp.ndarray,              # (B,S,D) — a chunk of prompt tokens
     positions: jnp.ndarray,      # (B,S) or (3,B,S) absolute positions
     valid: jnp.ndarray,          # (B,S) bool — False for bucket padding
-    pool_k: jnp.ndarray,         # (P, ps, KV, Dh) — shared page pool, one layer
+    pool_k: jnp.ndarray,         # (P, ps, KV, Dh), or a (L, P, ps, KV*Dh) stack
     pool_v: jnp.ndarray,
     page_table: jnp.ndarray,     # (B, MP) physical page ids per lane
     p0: jnp.ndarray,             # (B,) absolute position of chunk row 0
@@ -279,6 +284,7 @@ def attention_chunk_paged(
     n_skip: int = 0,
     lin_k: Optional[jnp.ndarray] = None,  # (B, MP*ps, KV, Dh) pre-gathered view
     lin_v: Optional[jnp.ndarray] = None,
+    layer=None,                  # the layer of a stacked pool to write and attend
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunked paged prefill: S prompt tokens mid-sequence, K/V scattered
     straight into their page cells *before* attention (the paged sibling of
@@ -295,21 +301,25 @@ def attention_chunk_paged(
 
     Validity needs no kv_pos array: the layout invariant (slot == absolute
     position, written contiguously) makes slot ``t`` valid exactly when
-    ``t < p0 + true_len``. Returns (attn output, new pool_k, new pool_v)."""
+    ``t < p0 + true_len``. With ``layer``, the pools are a group's
+    ``(L, P, ps, KV*Dh)`` stack, written and read at that layer in place
+    (the reference path from the caller's gathered view).
+    Returns (attn output, new pool_k, new pool_v)."""
     from .cache import gather_pages, paged_write_chunk
 
     pos1d = positions[0] if positions.ndim == 3 else positions
     b, s, _ = x.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[1 if layer is None else 2]
     q, k, v = qkv_project(p, x, positions, cfg)
     pk, pv = paged_write_chunk(
-        pool_k, pool_v, k, v, pos1d, valid, page_table, ps, n_skip=n_skip
+        pool_k, pool_v, k, v, pos1d, valid, page_table, ps, n_skip=n_skip,
+        layer=layer,
     )
     if cfg.attn_impl == "pallas":
         from ..kernels.chunked_prefill import ops as chunk_ops
 
         out = chunk_ops.chunked_prefill_attention(
-            q, pk, pv, page_table, p0, true_len,
+            q, pk, pv, page_table, p0, true_len, layer,
             window=window, softcap=cfg.attn_softcap,
         )
         out = out.reshape(b, s, cfg.n_heads * cfg.d_head) @ p["wo"]

@@ -107,8 +107,9 @@ def trim_kv_pos(kv_pos: jnp.ndarray, n_valid) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 #
 # A paged pool replaces the per-sequence full-width (B, T, KV, Dh) cache with
-# a shared physical pool of fixed-size pages, (P, page_size, KV, Dh) per
-# layer. A sequence is a *page table* — a list of physical page ids — whose
+# a shared physical pool of fixed-size pages, (P, page_size, KV*Dh) per
+# layer, the heads of one slot side by side in one row (init_paged_pool says
+# why). A sequence is a *page table* — a list of physical page ids — whose
 # concatenation reproduces the linear slot == absolute-position layout of the
 # full cache exactly, so the position-masked attention rule is unchanged:
 # gather the pages into a linear view, attend, and scatter the new token's
@@ -122,11 +123,22 @@ def init_paged_pool(
     device=None,
 ) -> Cache:
     """Physical KV page pool for one layer group: k/v of shape
-    (L, n_pages, page_size, KV, Dh), made on (and committed to) ``device``
+    (L, n_pages, page_size, KV*Dh), made on (and committed to) ``device``
     when one is given. kv_pos is tracked per *sequence* (B, width) by the
-    owner, not per page."""
+    owner, not per page.
+
+    A slot's heads lie side by side in one row, in C order the same bytes
+    as ``(KV, Dh)``. The flat row is what lets a decode step update the
+    pool in place. The TPU stores a ``(page_size, KV*Dh)`` minor pair such
+    as ``(16, 1024)``, which fills whole tiles, in plain row-major order,
+    pages whole and one after another: the order the attention kernels
+    read, so a step hands them the pool itself. A trailing ``(KV, Dh) =
+    (16, 64)`` would waste half of every tile in that order, so the TPU
+    stores it with the page axis innermost instead, and every step that
+    handed it to a kernel first copied it into row-major order and
+    back."""
     dt = dtype or dtype_of(cfg.compute_dtype)
-    shape = (n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+    shape = (n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.d_head)
     return {
         "k": jnp.zeros(shape, dtype=dt, device=device),
         "v": jnp.zeros(shape, dtype=dt, device=device),
@@ -145,14 +157,26 @@ def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     return out.reshape(b, mp * ps, pool.shape[2], pool.shape[3])
 
 
+def _write_cells(pool, layer, phys, slot, new):
+    """Scatter ``new`` (index dims + (KV, Dh)) into the (page, offset)
+    cells ``phys, slot`` of one layer's pool or, with ``layer``, of that
+    layer of a group's stack, reshaped to the pool's cell; writes to an
+    out-of-range page are dropped."""
+    idx = (phys, slot) if layer is None else (layer, phys, slot)
+    cell = pool.shape[len(idx):]
+    new = new.reshape(phys.shape + cell).astype(pool.dtype)
+    return pool.at[idx].set(new, mode="drop")
+
+
 def paged_write_step(
-    pool_k: jnp.ndarray,    # (P, ps, KV, Dh) one layer
+    pool_k: jnp.ndarray,    # (P, ps, KV, Dh) one layer, or (L, P, ps, KV*Dh)
     pool_v: jnp.ndarray,
     k_new: jnp.ndarray,     # (B, 1, KV, Dh)
     v_new: jnp.ndarray,
     pos: jnp.ndarray,       # (B,) absolute position of the new token
     page_table: jnp.ndarray,  # (B, MP)
     page_size: int,
+    layer=None,             # the layer of a stacked pool to write
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter one decode token's K/V into its (page, offset) cell. The
     owner guarantees each active lane's current tail page is exclusively
@@ -163,22 +187,26 @@ def paged_write_step(
     its write *dropped* — an out-of-range sentinel page id plus
     ``mode="drop"`` — rather than clamped into the last page, which would
     silently overwrite resident KV of the token actually living in that
-    cell (tests/test_paged_kv.py::test_paged_write_step_drops_at_capacity)."""
+    cell (tests/test_paged_kv.py::test_paged_write_step_drops_at_capacity).
+
+    With ``layer``, the pools are a group's ``(L, P, ps, KV*Dh)`` stack and
+    the token lands in that layer's cell: the layer scan of a decode step
+    carries the stack and updates it in place."""
     b = pos.shape[0]
     bidx = jnp.arange(b)
     mp = page_table.shape[1]
-    n_pages = pool_k.shape[0]
+    n_pages = pool_k.shape[0 if layer is None else 1]
     page_idx = pos // page_size
     phys = page_table[bidx, jnp.minimum(page_idx, mp - 1)]
     phys = jnp.where(page_idx < mp, phys, n_pages)   # OOB sentinel -> dropped
     slot = pos % page_size
-    pk = pool_k.at[phys, slot].set(k_new[:, 0], mode="drop")
-    pv = pool_v.at[phys, slot].set(v_new[:, 0], mode="drop")
+    pk = _write_cells(pool_k, layer, phys, slot, k_new[:, 0])
+    pv = _write_cells(pool_v, layer, phys, slot, v_new[:, 0])
     return pk, pv
 
 
 def paged_write_chunk(
-    pool_k: jnp.ndarray,    # (P, ps, KV, Dh) one layer
+    pool_k: jnp.ndarray,    # (P, ps, KV, Dh) one layer, or (L, P, ps, KV*Dh)
     pool_v: jnp.ndarray,
     k_new: jnp.ndarray,     # (B, S, KV, Dh) — a prefill chunk's rotated K
     v_new: jnp.ndarray,
@@ -187,6 +215,7 @@ def paged_write_chunk(
     page_table: jnp.ndarray,  # (B, MP)
     page_size: int,
     n_skip: int = 0,
+    layer=None,             # the layer of a stacked pool to write
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter a prefill chunk's K/V into their (page, offset) cells — the
     multi-token sibling of :func:`paged_write_step`, and the write half of
@@ -196,25 +225,27 @@ def paged_write_chunk(
     the table, and writes landing in the first ``n_skip`` pages —
     shared-prefix pages another session owns are read-only by construction,
     so a caller that starts a chunk inside a shared region redirects those
-    slots to nowhere instead of corrupting the donor."""
+    slots to nowhere instead of corrupting the donor. ``layer`` as in
+    :func:`paged_write_step`."""
     b, s = q_pos.shape
     mp = page_table.shape[1]
-    n_pages = pool_k.shape[0]
+    n_pages = pool_k.shape[0 if layer is None else 1]
     bidx = jnp.arange(b)[:, None]
     page_idx = q_pos // page_size
     phys = page_table[bidx, jnp.clip(page_idx, 0, mp - 1)]
     drop = (~valid) | (page_idx >= mp) | (page_idx < n_skip) | (q_pos < 0)
     phys = jnp.where(drop, n_pages, phys)            # OOB sentinel -> dropped
     slot = q_pos % page_size
-    pk = pool_k.at[phys, slot].set(k_new.astype(pool_k.dtype), mode="drop")
-    pv = pool_v.at[phys, slot].set(v_new.astype(pool_v.dtype), mode="drop")
+    pk = _write_cells(pool_k, layer, phys, slot, k_new)
+    pv = _write_cells(pool_v, layer, phys, slot, v_new)
     return pk, pv
 
 
 def gather_pages_stacked(
-    pool_k: jnp.ndarray,      # (L, P, ps, KV, Dh) — a layer group's K pool
+    pool_k: jnp.ndarray,      # (L, P, ps, KV*Dh) — a layer group's K pool
     pool_v: jnp.ndarray,
     page_table: jnp.ndarray,  # (B, MP)
+    heads: Tuple[int, int],   # (KV, Dh)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Linearize an entire layer stack's K and V pools through the page
     table — the hoisted form of :func:`gather_pages` for the reference
@@ -225,11 +256,11 @@ def gather_pages_stacked(
     physical pool every step, which on a many-tenant node can exceed the
     bytes the gather itself moves. Returns ``(k, v)`` of shape
     (L, B, MP*ps, KV, Dh)."""
-    l, _, ps, kv, dh = pool_k.shape
+    l, _, ps = pool_k.shape[:3]
     b, mp = page_table.shape
-    flat = (l, b, mp * ps, kv, dh)
+    flat = (l, b, mp * ps) + tuple(heads)
     return (
-        pool_k[:, page_table].reshape(flat),    # (L, B, MP, ps, KV, Dh)
+        pool_k[:, page_table].reshape(flat),    # (L, B, MP, ps, KV*Dh)
         pool_v[:, page_table].reshape(flat),
     )
 

@@ -291,12 +291,12 @@ def prefill_append(
 
 def _dense_block_chunk_paged(
     bp, x, positions, valid, pk, pv, page_table, p0, true_len, cfg,
-    n_skip, lin_k=None, lin_v=None,
+    n_skip, lin_k=None, lin_v=None, layer=None,
 ):
     h, nk, nv = attention_chunk_paged(
         bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, valid,
         pk, pv, page_table, p0, true_len, cfg,
-        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v,
+        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v, layer=layer,
     )
     x = x + h
     x = x + mlp_forward(bp["mlp"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
@@ -305,12 +305,12 @@ def _dense_block_chunk_paged(
 
 def _moe_block_chunk_paged(
     bp, x, positions, valid, pk, pv, page_table, p0, true_len, cfg,
-    n_skip, lin_k=None, lin_v=None,
+    n_skip, lin_k=None, lin_v=None, layer=None,
 ):
     h, nk, nv = attention_chunk_paged(
         bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, valid,
         pk, pv, page_table, p0, true_len, cfg,
-        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v,
+        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v, layer=layer,
     )
     x = x + h
     m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
@@ -320,7 +320,7 @@ def _moe_block_chunk_paged(
 def prefill_chunk_paged(
     params: Params,
     cfg: ModelConfig,
-    pools: List[Cache],           # per group: {"k","v"} (L, P, ps, KV, Dh)
+    pools: List[Cache],           # per group: {"k","v"} (L, P, ps, KV*Dh)
     page_table: jnp.ndarray,      # (B, MP) physical page ids per lane
     tokens: jnp.ndarray,          # (B,S) prompt chunk (bucketed)
     p0: jnp.ndarray,              # (B,) absolute position of chunk row 0
@@ -342,8 +342,9 @@ def prefill_chunk_paged(
 
     Pure function; jit with donate_argnums on pools. Returns
     (logits at row ``true_len - 1`` (B,V), new pools). Full-cache dense/moe
-    groups only (:func:`supports_append`); same kernel/reference dispatch
-    and per-step hoisted gather as ``decode_step_paged``."""
+    groups only (:func:`supports_append`); same kernel/reference dispatch,
+    per-step hoisted gather and in-place layer scan (the pools a carry, the
+    layer index scanned) as ``decode_step_paged``."""
     assert supports_append(cfg), (
         "prefill_chunk_paged requires full-cache dense/moe groups "
         f"(arch={cfg.arch_type}, attn_variant={cfg.attn_variant})"
@@ -359,6 +360,7 @@ def prefill_chunk_paged(
     )
     x = embed_tokens(params["embed"], tokens, cfg).astype(dtype_of(cfg.compute_dtype))
     use_kernel = cfg.attn_impl == "pallas"
+    heads = (cfg.n_kv_heads, cfg.d_head)
 
     new_pools: List[Cache] = []
     for spec, gp, pool in zip(layer_groups(cfg), params["groups"], pools):
@@ -368,29 +370,23 @@ def prefill_chunk_paged(
             else _moe_block_chunk_paged
         )
 
-        if use_kernel:
-            xs = (gp, pool["k"], pool["v"])
+        lin = (
+            (None, None) if use_kernel
+            else gather_pages_stacked(pool["k"], pool["v"], page_table, heads)
+        )
 
-            def body(x, scanned, _block=block):
-                bp, pk, pv = scanned
-                x, nk, nv = _block(
-                    bp, x, positions, valid, pk, pv, page_table, p0,
-                    true_len, cfg, n_skip,
-                )
-                return x, (nk, nv)
-        else:
-            lin_k, lin_v = gather_pages_stacked(pool["k"], pool["v"], page_table)
-            xs = (gp, pool["k"], pool["v"], lin_k, lin_v)
+        def body(carry, scanned, _block=block):
+            x, pk, pv = carry
+            bp, layer, lk, lv = scanned
+            return _block(
+                bp, x, positions, valid, pk, pv, page_table, p0,
+                true_len, cfg, n_skip, lk, lv, layer,
+            ), None
 
-            def body(x, scanned, _block=block):
-                bp, pk, pv, lk, lv = scanned
-                x, nk, nv = _block(
-                    bp, x, positions, valid, pk, pv, page_table, p0,
-                    true_len, cfg, n_skip, lk, lv,
-                )
-                return x, (nk, nv)
-
-        x, (nk, nv) = scan_or_unroll(body, x, xs, cfg)
+        layers = jnp.arange(spec.n_blocks, dtype=jnp.int32)
+        (x, nk, nv), _ = scan_or_unroll(
+            body, (x, pool["k"], pool["v"]), (gp, layers) + lin, cfg
+        )
         new_pools.append({"k": nk, "v": nv})
 
     n = jnp.maximum(true_len, 1)

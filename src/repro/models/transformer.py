@@ -414,11 +414,13 @@ def _moe_block_decode(bp, x, positions, cache_k, cache_v, kv_pos, cfg, window, r
 
 def _paged_attn_sublayer(
     bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k, lin_v, shared_pages=None,
+    page_size, lin_k, lin_v, shared_pages=None, layer=None,
 ):
     """Shared attention sublayer of one paged decode block: scatter the
     token's K/V into its page cell, then attend through the page table
-    (fused kernel when ``cfg.attn_impl == "pallas"``). On the reference
+    (fused kernel when ``cfg.attn_impl == "pallas"``). With ``layer``, the
+    pools are the group's whole ``(L, P, ps, KV*Dh)`` stack, written and
+    read at that layer in place. On the reference
     path, callers that hoisted the gather pass the pre-gathered linear
     views; the new token is inserted into them here (slot == position, with
     the same at-capacity drop as the pool scatter) so they stay
@@ -427,7 +429,8 @@ def _paged_attn_sublayer(
     h_in = rms_norm(x, bp["norm1"], cfg.norm_eps)
     k_new, v_new = project_kv_step(bp["attn"], h_in, positions, cfg)
     pk, pv = paged_write_step(
-        pool_k, pool_v, k_new, v_new, pos1d[:, 0], page_table, page_size
+        pool_k, pool_v, k_new, v_new, pos1d[:, 0], page_table, page_size,
+        layer,
     )
     if lin_k is not None:
         bidx = jnp.arange(x.shape[0])
@@ -437,18 +440,20 @@ def _paged_attn_sublayer(
     h = attention_decode_paged(
         bp["attn"], h_in, positions, pk, pv, page_table, kv_pos, cfg,
         window=window, lin_k=lin_k, lin_v=lin_v, shared_pages=shared_pages,
+        layer=layer,
     )
     return x + h, pk, pv
 
 
 def _dense_block_decode_paged(
     bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k=None, lin_v=None, shared_pages=None,
+    page_size, lin_k=None, lin_v=None, shared_pages=None, layer=None,
 ):
-    """One layer paged decode. pool_k/v: (P, ps, KV, Dh)."""
+    """One layer paged decode. pool_k/v: (P, ps, KV, Dh), or the group's
+    (L, P, ps, KV*Dh) stack with ``layer``."""
     x, pk, pv = _paged_attn_sublayer(
         bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-        page_size, lin_k, lin_v, shared_pages,
+        page_size, lin_k, lin_v, shared_pages, layer,
     )
     x = x + mlp_forward(bp["mlp"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return x, pk, pv
@@ -456,11 +461,11 @@ def _dense_block_decode_paged(
 
 def _moe_block_decode_paged(
     bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k=None, lin_v=None, shared_pages=None,
+    page_size, lin_k=None, lin_v=None, shared_pages=None, layer=None,
 ):
     x, pk, pv = _paged_attn_sublayer(
         bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-        page_size, lin_k, lin_v, shared_pages,
+        page_size, lin_k, lin_v, shared_pages, layer,
     )
     m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return x + m, pk, pv
@@ -469,7 +474,7 @@ def _moe_block_decode_paged(
 def decode_step_paged(
     params: Params,
     cfg: ModelConfig,
-    pools: List[Cache],           # per group: {"k","v"} (L, P, ps, KV, Dh)
+    pools: List[Cache],           # per group: {"k","v"} (L, P, ps, KV*Dh)
     page_table: jnp.ndarray,      # (B, MP) physical page ids per lane
     kv_pos: jnp.ndarray,          # (B, MP*ps) shared across full-cache groups
     tokens: jnp.ndarray,          # (B,1)
@@ -482,9 +487,15 @@ def decode_step_paged(
     same family :func:`~repro.models.prefill.supports_append` covers).
     Pure function; jit with donate_argnums on pools and kv_pos.
 
+    The layer scan carries each group's ``(L, P, ps, KV*Dh)`` pools and
+    scans only the layer params and the layer index: every layer writes its
+    token straight into the stack, so with the pools donated the step
+    updates them in place and no layer's pool is ever copied.
+
     With ``cfg.attn_impl == "pallas"`` each layer attends straight through
-    the page table (``repro.kernels.paged_attention``) — no linearized
-    cache copy is ever built. The reference path gathers instead, hoisted:
+    the page table (``repro.kernels.paged_attention``), reading the stack
+    at its layer — no linearized cache copy and no per-layer slice is ever
+    built. The reference path gathers instead, hoisted:
     one K and one V gather per group per *step* (``gather_pages_stacked``)
     rather than two per layer, with the new token inserted into the view
     inside each block. Callers may pass a ``page_table``/``kv_pos`` pair
@@ -512,6 +523,7 @@ def decode_step_paged(
         pos.astype(jnp.int32), mode="drop"
     )
     use_kernel = cfg.attn_impl == "pallas"
+    heads = (cfg.n_kv_heads, cfg.d_head)
 
     new_pools: List[Cache] = []
     for spec, gp, pool in zip(layer_groups(cfg), params["groups"], pools):
@@ -523,29 +535,23 @@ def decode_step_paged(
             else _moe_block_decode_paged
         )
 
-        if use_kernel:
-            xs = (gp, pool["k"], pool["v"])
+        lin = (
+            (None, None) if use_kernel
+            else gather_pages_stacked(pool["k"], pool["v"], page_table, heads)
+        )
 
-            def body(x, scanned, _fn=block_fn):
-                bp, pk, pv = scanned
-                x, nk, nv = _fn(
-                    bp, x, positions, pk, pv, page_table, new_kv_pos, cfg,
-                    0, page_size, shared_pages=shared_pages,
-                )
-                return x, (nk, nv)
-        else:
-            lin_k, lin_v = gather_pages_stacked(pool["k"], pool["v"], page_table)
-            xs = (gp, pool["k"], pool["v"], lin_k, lin_v)
+        def body(carry, scanned, _fn=block_fn):
+            x, pk, pv = carry
+            bp, layer, lk, lv = scanned
+            return _fn(
+                bp, x, positions, pk, pv, page_table, new_kv_pos, cfg,
+                0, page_size, lk, lv, shared_pages, layer,
+            ), None
 
-            def body(x, scanned, _fn=block_fn):
-                bp, pk, pv, lk, lv = scanned
-                x, nk, nv = _fn(
-                    bp, x, positions, pk, pv, page_table, new_kv_pos, cfg,
-                    0, page_size, lk, lv,
-                )
-                return x, (nk, nv)
-
-        x, (nk, nv) = scan_or_unroll(body, x, xs, cfg)
+        layers = jnp.arange(spec.n_blocks, dtype=jnp.int32)
+        (x, nk, nv), _ = scan_or_unroll(
+            body, (x, pool["k"], pool["v"]), (gp, layers) + lin, cfg
+        )
         new_pools.append({"k": nk, "v": nv})
 
     logits = unembed(params["embed"], x, cfg)

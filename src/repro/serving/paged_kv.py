@@ -263,7 +263,7 @@ class PagedKVAllocator:
                 out = []
                 for pool, c in zip(pools, dense):
                     l = c["k"].shape[0]
-                    chunk_shape = (l, -1, self.page_size) + c["k"].shape[3:]
+                    chunk_shape = (l, -1, self.page_size) + pool["k"].shape[3:]
                     out.append({
                         "k": pool["k"].at[:, table].set(
                             c["k"][:, 0].reshape(chunk_shape).astype(pool["k"].dtype)
@@ -287,9 +287,9 @@ class PagedKVAllocator:
                 out = []
                 for pool in pools:
                     l = pool["k"].shape[0]
-                    k = pool["k"][:, table]          # (L, MP, ps, KV, Dh)
+                    k = pool["k"][:, table]          # (L, MP, ps, KV*Dh)
                     v = pool["v"][:, table]
-                    flat = (l, 1, width) + pool["k"].shape[3:]
+                    flat = (l, 1, width, self.cfg.n_kv_heads, self.cfg.d_head)
                     out.append({
                         "k": k.reshape(flat),
                         "v": v.reshape(flat),
@@ -329,9 +329,10 @@ class PagedKVAllocator:
 
     def export_page_bytes(self, page: int) -> bytes:
         """Serialize one physical page's bytes for shipping: per layer
-        group, the K block then the V block, each ``(L, page_size, KV, Dh)``
-        in the pool's native dtype, C order, concatenated. The native dtype
-        (bf16 for the serving configs) makes the round trip bit-exact:
+        group, the K block then the V block, each ``(L, page_size, KV*Dh)``
+        (the bytes of ``(L, page_size, KV, Dh)``) in the pool's native
+        dtype, C order, concatenated. The native dtype (bf16 for the
+        serving configs) makes the round trip bit-exact:
         ``import_page_bytes`` on an identically-configured pool reproduces
         the page byte-for-byte, so a shipped prime is greedy-equivalent to
         the local recompute it replaces."""
@@ -354,7 +355,7 @@ class PagedKVAllocator:
             entry: Dict[str, np.ndarray] = {}
             for name in ("k", "v"):
                 a = pool[name]
-                shape = (a.shape[0],) + tuple(a.shape[2:])  # (L, ps, KV, Dh)
+                shape = (a.shape[0],) + tuple(a.shape[2:])  # (L, ps, KV*Dh)
                 n_bytes = int(np.prod(shape)) * a.dtype.itemsize
                 entry[name] = np.frombuffer(
                     data[off : off + n_bytes], dtype=a.dtype

@@ -400,6 +400,65 @@ def test_attention_decode_paged_shared_matches_reference():
 
 
 # ---------------------------------------------------------------------------
+# layer stacks: the kernels read one layer of a group's pool in place
+# ---------------------------------------------------------------------------
+N_LAYERS = 5
+
+
+def _layer_stack(key, pool):
+    """``N_LAYERS`` random layers shaped like the one-layer ``pool``, as
+    ``(L, P, ps, KV, Dh)`` and as the flat ``(L, P, ps, KV*Dh)`` stack
+    ``init_paged_pool`` lays out. Every layer differs, so a kernel that
+    reads the wrong one shows."""
+    layers = jax.random.normal(key, (N_LAYERS,) + pool.shape, pool.dtype)
+    return layers, layers.reshape(layers.shape[:3] + (-1,))
+
+
+def _chunk_inputs(key, H, KV, Dh, ps=16, s=24):
+    """Two lanes of a bucketed ``s``-row chunk: a ragged first chunk
+    (13 rows at 0) and one that starts a page in and crosses the next
+    page boundary (20 rows at 16)."""
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (2, s, H, Dh))
+    pool_k = jax.random.normal(ks[1], (7, ps, KV, Dh))
+    pool_v = jax.random.normal(ks[2], (7, ps, KV, Dh))
+    table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    p0 = jnp.asarray([0, ps], jnp.int32)
+    true_len = jnp.asarray([13, 20], jnp.int32)
+    return q, pool_k, pool_v, table, p0, true_len
+
+
+@pytest.mark.parametrize("layer", [0, N_LAYERS // 2, N_LAYERS - 1])
+@pytest.mark.parametrize("H,KV,Dh", [(4, 4, 32), (8, 2, 32)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("kernel", ["paged", "shared_prefix", "chunked"])
+def test_layer_of_stack_matches_one_layer_pool(kernel, H, KV, Dh, layer):
+    """Each kernel attending through a group's pool stack at ``layer`` gives
+    the same bits as its one-layer entry on that layer's pool: the layer
+    index only picks the blocks the index maps fetch. Ragged lanes
+    throughout (empty, sub-page, page boundary, multi-page)."""
+    from repro.kernels.chunked_prefill import chunked_prefill_attention
+
+    key = jax.random.key(20)
+    if kernel == "chunked":
+        q, pool_k, pool_v, table, p0, true_len = _chunk_inputs(key, H, KV, Dh)
+        lk, sk = _layer_stack(jax.random.key(21), pool_k)
+        lv, sv = _layer_stack(jax.random.key(22), pool_v)
+        one = chunked_prefill_attention(q, lk[layer], lv[layer], table, p0, true_len)
+        stacked = chunked_prefill_attention(q, sk, sv, table, p0, true_len, layer)
+    else:
+        if kernel == "paged":
+            args, sp = _paged_inputs(key, RAGGED, 16, H, KV, Dh), None
+        else:
+            *args, sp = _shared_paged_inputs(key, 2, (0, 1, 16, 19), 16, H, KV, Dh)
+        q, pool_k, pool_v, table, q_pos, kv_pos = args
+        lk, sk = _layer_stack(jax.random.key(21), pool_k)
+        lv, sv = _layer_stack(jax.random.key(22), pool_v)
+        one = paged_attention(q, lk[layer], lv[layer], table, q_pos, kv_pos, sp)
+        stacked = paged_attention(q, sk, sv, table, q_pos, kv_pos, sp, layer)
+    assert jnp.array_equal(one, stacked)
+
+
+# ---------------------------------------------------------------------------
 # SSD
 # ---------------------------------------------------------------------------
 def _ssd_inputs(key, B, L, H, P, N):

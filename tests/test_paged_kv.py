@@ -173,6 +173,71 @@ def test_decode_step_paged_kv_pos_drops_at_capacity(cfg, params):
     assert jnp.array_equal(new_kv_pos, kv_pos)
 
 
+def _random_pools(cfg, n_pages, ps):
+    """Pool stacks shaped as a node allocates them, filled with noise so
+    that every write shows."""
+    from repro.models import layer_groups
+    from repro.models.cache import init_paged_pool
+
+    pools = [init_paged_pool(cfg, spec.n_blocks, n_pages, ps)
+             for spec in layer_groups(cfg)]
+    keys = iter(jax.random.split(jax.random.key(2), 2 * len(pools)))
+    return [{n: jax.random.normal(next(keys), p[n].shape, p[n].dtype)
+             for n in ("k", "v")} for p in pools]
+
+
+def _written_cells(before, after):
+    """The (layer, page, slot) cells in which any K or V pool of any group
+    differs between two lists of pool stacks."""
+    cells = set()
+    for b, a in zip(before, after):
+        for n in ("k", "v"):
+            diff = np.any(np.asarray(b[n]) != np.asarray(a[n]), axis=-1)
+            cells |= set(zip(*(i.tolist() for i in np.nonzero(diff))))
+    return cells
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_paged_steps_write_only_their_cells(cfg, params, step, impl):
+    """The layer scan carries each group's whole pool stack and updates it
+    in place, so a step must leave every cell it does not write as it was.
+    After one decode step and one chunk step, every layer's pool differs
+    from its input exactly at the (page, slot) cells of the tokens
+    written; the dropped writes change nothing: a lane at table capacity,
+    chunk rows inside the read-only shared pages below ``n_skip``, and the
+    chunk's bucket padding."""
+    from repro.models.prefill import prefill_chunk_paged
+
+    c = cfg.replace(attn_impl=impl)
+    ps, mp = 4, 3
+    pools = _random_pools(c, 12, ps)
+    if step == "decode":
+        # lane 0 writes position 5 (page 2, slot 1); lane 1 is at capacity;
+        # lane 2 writes position 2 (page 7, slot 2)
+        table = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+        pos = jnp.asarray([5, mp * ps, 2], jnp.int32)
+        iota = jnp.arange(mp * ps)
+        kv_pos = jnp.where(iota[None] <= jnp.minimum(pos, mp * ps - 1)[:, None],
+                           iota[None], -1).astype(jnp.int32)
+        tok = jnp.asarray([[7], [8], [9]], jnp.int32)
+        _, out, _ = decode_step_paged(params, c, pools, table, kv_pos, tok, pos)
+        written = {(2, 1), (7, 2)}
+    else:
+        # an 8-row bucket holding 5 rows at position 2, page 1 shared
+        # (n_skip=1): positions 2, 3 are dropped, 4..6 land in page 2's
+        # slots 0..2, and the padding rows (7..9) are dropped
+        table = jnp.asarray([[1, 2, 3]], jnp.int32)
+        tokens = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6]], jnp.int32)
+        _, out = prefill_chunk_paged(
+            params, c, pools, table, tokens, jnp.asarray([2], jnp.int32),
+            jnp.asarray([5], jnp.int32), n_skip=1,
+        )
+        written = {(2, 0), (2, 1), (2, 2)}
+    expect = {(layer,) + cell for layer in range(cfg.n_layers) for cell in written}
+    assert _written_cells(pools, out) == expect
+
+
 # ---------------------------------------------------------------------------
 # Pool page accounting (deterministic; the pool is the sole allocator client)
 # ---------------------------------------------------------------------------

@@ -161,33 +161,45 @@ def test_flash_attention_compiles(chip_compile, arch):
     ))
 
 
+def _pool_shapes(cfg, n_pages):
+    """Each layer group's K/V pools as a batched service allocates them."""
+    from repro.models import layer_groups
+    from repro.models.cache import init_paged_pool
+
+    return [
+        jax.eval_shape(lambda: init_paged_pool(cfg, spec.n_blocks, n_pages, PS))
+        for spec in layer_groups(cfg)
+    ]
+
+
+def _steer_to_mosaic(monkeypatch):
+    """The kernel wrappers pick interpret mode from the backend, which is
+    the CPU here; a step compiled for the chip must take the Mosaic
+    kernels."""
+    from repro.kernels.chunked_prefill import ops as chunk_ops
+    from repro.kernels.decode_attention import ops as decode_ops
+    from repro.kernels.flash_attention import ops as flash_ops
+    from repro.kernels.paged_attention import ops as paged_ops
+
+    for mod in (paged_ops, chunk_ops, decode_ops, flash_ops):
+        monkeypatch.setattr(mod, "_on_cpu", lambda: False)
+
+
 def test_paged_decode_step_fits_v5e(chip_compile, monkeypatch):
     """One whole jitted paged decode step of the paper's model at full
     width, against the page pool a default batched service allocates at
     max_len 2048, compiles with the Pallas kernels in it and fits one
     chip's HBM."""
     from repro.configs import get_config
-    from repro.kernels.chunked_prefill import ops as chunk_ops
-    from repro.kernels.decode_attention import ops as decode_ops
-    from repro.kernels.flash_attention import ops as flash_ops
-    from repro.kernels.paged_attention import ops as paged_ops
-    from repro.models import decode_step_paged, init_params, layer_groups
+    from repro.models import decode_step_paged, init_params
 
-    # the kernel wrappers pick interpret mode from the backend, which is the
-    # CPU here; the step is compiled for the chip, so steer them to Mosaic
-    for mod in (paged_ops, chunk_ops, decode_ops, flash_ops):
-        monkeypatch.setattr(mod, "_on_cpu", lambda: False)
-
+    _steer_to_mosaic(monkeypatch)
     cfg = get_config("paper-qwen1.5-0.5b").replace(attn_impl="pallas")
     n_slots, max_len, entries = 4, 2048, 8
     n_pages = 1 + (n_slots + entries) * (max_len // PS)
     mp = max_len // PS
     params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    kv, dh = cfg.n_kv_heads, cfg.d_head
-    pools = [
-        {name: _s((spec.n_blocks, n_pages, PS, kv, dh)) for name in ("k", "v")}
-        for spec in layer_groups(cfg)
-    ]
+    pools = _pool_shapes(cfg, n_pages)
     i32 = jnp.int32
     step = functools.partial(decode_step_paged, cfg=cfg)
     compiled = chip_compile(
@@ -206,6 +218,46 @@ def test_paged_decode_step_fits_v5e(chip_compile, monkeypatch):
     assert total < V5E_HBM_BYTES, total
 
 
+def test_paged_steps_update_pool_in_place(chip_compile, monkeypatch):
+    """The full-width paged decode step and one chunk step of the paper's
+    model, against a pool of 1537 pages of 16 with the pools (and the
+    decode's kv_pos) donated, update the pools in place: each program
+    aliases the donated pools to its outputs, and its scratch memory is
+    below one layer's K pool, so no step copies a layer's pool, let alone
+    the group's stack."""
+    from repro.configs import get_config
+    from repro.models import decode_step_paged, init_params
+    from repro.models.prefill import prefill_chunk_paged
+
+    _steer_to_mosaic(monkeypatch)
+    cfg = get_config("paper-qwen1.5-0.5b").replace(attn_impl="pallas")
+    n_slots, max_len, n_pages = 4, 2048, 1537
+    mp = max_len // PS
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    pools = _pool_shapes(cfg, n_pages)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    layer_k_bytes = n_pages * PS * cfg.n_kv_heads * cfg.d_head * 2
+    assert layer_k_bytes == 50_364_416
+    i32 = jnp.int32
+    decode = chip_compile(
+        lambda p, pl_, t, kp, tok, pos: decode_step_paged(
+            p, cfg, pl_, t, kp, tok, pos),
+        params, pools, _s((n_slots, mp), i32), _s((n_slots, max_len), i32),
+        _s((n_slots, 1), i32), _s((n_slots,), i32),
+        donate_argnums=(1, 3),
+    )
+    chunk = chip_compile(
+        lambda p, pl_, t, tok, p0, n: prefill_chunk_paged(p, cfg, pl_, t, tok, p0, n),
+        params, pools, _s((1, mp), i32), _s((1, 64), i32), _s((1,), i32),
+        _s((1,), i32),
+        donate_argnums=(1,),
+    )
+    for name, compiled in (("decode", decode), ("chunk", chunk)):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < layer_k_bytes, (name, mem.temp_size_in_bytes)
+        assert mem.alias_size_in_bytes >= pool_bytes, (name, mem.alias_size_in_bytes)
+
+
 def test_kernel_names_in_a_decode_step_and_a_chunk(chip_compile, monkeypatch):
     """The paged-attention, shared-prefix and chunked-prefill kernels run
     under names they give themselves, so a device trace finds them by the
@@ -214,22 +266,15 @@ def test_kernel_names_in_a_decode_step_and_a_chunk(chip_compile, monkeypatch):
     import re
 
     from repro.configs import get_config
-    from repro.kernels.chunked_prefill import ops as chunk_ops
-    from repro.kernels.paged_attention import ops as paged_ops
-    from repro.models import decode_step_paged, init_params, layer_groups
+    from repro.models import decode_step_paged, init_params
     from repro.models.prefill import prefill_chunk_paged
 
-    for mod in (paged_ops, chunk_ops):
-        monkeypatch.setattr(mod, "_on_cpu", lambda: False)
+    _steer_to_mosaic(monkeypatch)
     cfg = get_config("paper-qwen1.5-0.5b").replace(attn_impl="pallas", n_layers=2)
     n_slots, max_len = 4, 256
     n_pages, mp = 1 + 12 * (max_len // PS), max_len // PS
     params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
-    pools = [
-        {name: _s((spec.n_blocks, n_pages, PS, cfg.n_kv_heads, cfg.d_head))
-         for name in ("k", "v")}
-        for spec in layer_groups(cfg)
-    ]
+    pools = _pool_shapes(cfg, n_pages)
     i32 = jnp.int32
 
     def names(compiled):
