@@ -25,12 +25,13 @@ import shutil
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from . import flops, reference, traffic
-from .model import Dims, check_program_config, dims_of
+from .blocks import block_for
 from .stats import MISSED_MS
 from .weights import check_layout, make_params
 
@@ -97,7 +98,8 @@ class ChunkRec:
 class Run:
     """Everything one run measured; the metric readers read this."""
     spec: CellSpec
-    dims: Dims
+    block: ModuleType             # bench/blocks/<model_type>.py
+    dims: object                  # the block's Dims
     window_ms: float
     setup_s: float
     turns: List[TurnRec]          # turns sent within the window
@@ -459,10 +461,11 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
     from repro.models import abstract_params
 
     compiles = track_compiles()
-    dims = dims_of(spec.conf)
-    check_program_config(dims, spec.program_cfg)
+    block = block_for(spec.conf)
+    dims = block.dims_of(spec.conf)
+    block.check_program_config(dims, spec.program_cfg)
     dep = spec.conf["deployment"]
-    params = make_params(dims, spec.conf["weights"], seed, device)
+    params = make_params(block, dims, spec.conf["weights"], seed, device)
     check_layout(params, abstract_params(spec.program_cfg))
     cluster = build_cluster(spec, params, device)
     nodes = list(cluster.nodes)
@@ -561,10 +564,10 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     result_run = Run(
-        spec=spec, dims=dims, window_ms=seconds * 1e3, setup_s=marks["setup_s"],
-        turns=in_window, steps=rec.steps,
-        chunks=rec.chunks, counters=counters, page_size=ps, trace=tr_reduced,
-        peaks=peaks, trace_wall=trace_wall,
+        spec=spec, block=block, dims=dims, window_ms=seconds * 1e3,
+        setup_s=marks["setup_s"], turns=in_window, steps=rec.steps, chunks=rec.chunks,
+        counters=counters, page_size=ps, trace=tr_reduced, peaks=peaks,
+        trace_wall=trace_wall,
     )
     metrics = {}
     for name, (read, unit) in readers.items():
@@ -580,7 +583,7 @@ def run(spec: CellSpec, seed: int, seconds: float, trace: bool,
         np.random.default_rng(seed), want_tokens, SAMPLE_TURNS)
     del cluster, load, rec, result_run
     gc.collect()
-    gaps = reference.gaps_of(params, dims, sample, control=control)
+    gaps = reference.gaps_of(params, block, dims, sample, control=control)
     log(f"reference: {len(sample)} turns, {gaps['tokens']} served tokens compared; "
         f"widest gap {gaps['served_gap']:.6g}"
         + (f", fp8 control's {gaps['control_gap']:.6g}" if control else ""))

@@ -1,7 +1,8 @@
 """Kernels: the paged decode attention kernel's lower-bound time (per layer
-call, the larger of FLOPs over peak and bytes over bandwidth, counted from
-each traced step's lanes; the shared-prefix pass included) over its device
-time in the trace, in percent."""
+call, the larger of FLOPs over peak and bytes over bandwidth, counted by
+the block from each traced step's lanes, summed over its layers; the
+shared-prefix pass included) over its device time in the trace, in
+percent."""
 
 from bench import flops
 
@@ -14,6 +15,6 @@ def read(run):
     need = 0.0
     for s in run.traced(run.steps):
         if s.kv_lens:
-            f, b = flops.paged_attention_call(d, s.kv_lens, s.shared_pages, ps)
-            need += d.layers * flops.bound_seconds(f, b, run.peaks)
+            calls = run.block.paged_attention_call(d, s.kv_lens, s.shared_pages, ps)
+            need += flops.layers_seconds(calls, run.peaks)
     return 100.0 * need / spent if spent > 0 and need > 0 else None

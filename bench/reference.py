@@ -1,9 +1,10 @@
-"""The plain reference: a float32 forward pass of the Qwen2 block in
-``jax.numpy``, written from the published description (RMSNorm with the
-weight stored as ``weight - 1``, rotary embedding on half-split head dims,
-grouped-query attention with QKV bias, SwiGLU MLP, tied embeddings). No
-kernel, cache, paging or batching; every product at HIGHEST precision.
-It imports nothing of the program.
+"""The comparison with the plain reference that decides ``correct``. The
+reference forward pass is the block's (``bench/blocks/<model_type>.py``:
+``hidden`` and ``logits``, float32 in ``jax.numpy``, no kernel, cache,
+paging or batching, importing nothing of the program). This module holds
+what every block shares: the building blocks of its pass (``_fq``,
+``_mm``, ``_rms``, ``_rope``; every product at HIGHEST precision) and the
+served-token gaps.
 
 ``quant="fp8"`` is the control: the same pass with every matrix product's
 operands rounded to float8 e4m3 (scaled per row of the contraction), the
@@ -18,8 +19,6 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .model import Dims
 
 HIGHEST = jax.lax.Precision.HIGHEST
 ROWS = 256          # served tokens compared per call (padded)
@@ -54,60 +53,19 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _hidden(params, d: Dims, tokens, quant):
-    """Final hidden states (S, D) of one causal sequence."""
-    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    s = tokens.shape[0]
-    pos = jnp.arange(s, dtype=jnp.int32)
-    x = f32(params["embed"]["tok"][tokens])
-    causal = pos[None, :] <= pos[:, None]                    # (S_q, S_k)
-    g = d.heads // d.kv_heads
-
-    def layer(x, bp):
-        bp = jax.tree.map(f32, bp)
-        a = bp["attn"]
-        h = _rms(x, bp["norm1"], d.norm_eps)
-        q = (_mm(h, a["wq"], quant) + a["bq"]).reshape(s, d.kv_heads, g, d.d_head)
-        k = (_mm(h, a["wk"], quant) + a["bk"]).reshape(s, d.kv_heads, d.d_head)
-        v = (_mm(h, a["wv"], quant) + a["bv"]).reshape(s, d.kv_heads, d.d_head)
-        q = _rope(q.reshape(s, d.heads, d.d_head), pos, d.rope_theta)
-        q = q.reshape(s, d.kv_heads, g, d.d_head)
-        k = _rope(k, pos, d.rope_theta)
-        scores = jnp.einsum("skgd,tkd->kgst", _fq(q, -1, quant), _fq(k, -1, quant),
-                            precision=HIGHEST) / np.sqrt(d.d_head)
-        scores = jnp.where(causal[None, None], scores, -jnp.inf)
-        p = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("kgst,tkd->skgd", _fq(p, -1, quant), _fq(v, 0, quant),
-                       precision=HIGHEST)
-        x = x + _mm(o.reshape(s, d.heads * d.d_head), a["wo"], quant)
-        m = bp["mlp"]
-        h = _rms(x, bp["norm2"], d.norm_eps)
-        act = jax.nn.silu(_mm(h, m["w_gate"], quant)) * _mm(h, m["w_up"], quant)
-        return x + _mm(act, m["w_down"], quant), None
-
-    x, _ = jax.lax.scan(layer, x, params["groups"][0])
-    return x
-
-
-def _logits(params, d: Dims, rows, quant):
-    h = _rms(rows, params["embed"]["final_norm"].astype(jnp.float32), d.norm_eps)
-    tok = params["embed"]["tok"].astype(jnp.float32)
-    return jnp.matmul(_fq(h, -1, quant), _fq(tok, -1, quant).T, precision=HIGHEST)
-
-
-@partial(jax.jit, static_argnames=("d", "control"))
-def _gaps(params, tokens, rows, targets, *, d: Dims, control: bool):
+@partial(jax.jit, static_argnames=("block", "d", "control"))
+def _gaps(params, tokens, rows, targets, *, block, d, control: bool):
     """For each row: how far the reference's logit of ``targets[row]`` lies
     below its best logit. With ``control``, also that gap for the token the
     fp8 pass puts first."""
-    x = _hidden(params, d, tokens, "f32")[rows]
-    ref = _logits(params, d, x, "f32")
+    x = block.hidden(params, d, tokens, "f32")[rows]
+    ref = block.logits(params, d, x, "f32")
     best = jnp.max(ref, axis=-1)
     served = best - jnp.take_along_axis(ref, targets[:, None], axis=-1)[:, 0]
     if not control:
         return served, served
-    xq = _hidden(params, d, tokens, "fp8")[rows]
-    pick = jnp.argmax(_logits(params, d, xq, "fp8"), axis=-1)
+    xq = block.hidden(params, d, tokens, "fp8")[rows]
+    pick = jnp.argmax(block.logits(params, d, xq, "fp8"), axis=-1)
     low = best - jnp.take_along_axis(ref, pick[:, None], axis=-1)[:, 0]
     return served, low
 
@@ -119,7 +77,7 @@ def bucket(n: int) -> int:
     return b
 
 
-def served_gaps(params, d: Dims, input_ids: Sequence[int],
+def served_gaps(params, block, d, input_ids: Sequence[int],
                 generated: Sequence[int], control: bool = False
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Gaps of every served token of one turn (its prompt ``input_ids`` as
@@ -140,7 +98,7 @@ def served_gaps(params, d: Dims, input_ids: Sequence[int],
         rows[: len(r)] = n - 1 + r
         tgt = np.zeros(ROWS, np.int32)
         tgt[: len(r)] = np.asarray(generated, np.int32)[r]
-        a, b = _gaps(params, toks, rows, tgt, d=d, control=control)
+        a, b = _gaps(params, toks, rows, tgt, block=block, d=d, control=control)
         out_a.append(np.asarray(a)[: len(r)])
         out_b.append(np.asarray(b)[: len(r)])
     return np.concatenate(out_a), np.concatenate(out_b)
@@ -165,14 +123,14 @@ def turn_sample(turns: Sequence, rng: np.random.Generator, min_tokens: int,
     return [turns[i] for i in picked]
 
 
-def gaps_of(params, d: Dims, turns: Sequence, control: bool = False
+def gaps_of(params, block, d, turns: Sequence, control: bool = False
             ) -> Dict[str, float]:
     """The widest served-token gap over ``turns`` (and the control's, with
     ``control``), and how many tokens were compared."""
     worst, worst_ctrl, n = 0.0, 0.0, 0
     with jax.default_matmul_precision("highest"):
         for t in turns:
-            a, b = served_gaps(params, d, t.input_ids, t.generated, control)
+            a, b = served_gaps(params, block, d, t.input_ids, t.generated, control)
             worst = max(worst, float(a.max()))
             worst_ctrl = max(worst_ctrl, float(b.max()))
             n += len(a)
