@@ -5,6 +5,7 @@ on a TPU through the normal entry points, and check what comes out.
     python chip_smoke.py            # one chip: the served path and its checks
     python chip_smoke.py --chips 4  # four chips: one node per device vs the
                                     # same sessions with every node on one
+    python chip_smoke.py --arch deepseek-v2-lite   # another configuration
 
 One chip, in order:
 
@@ -163,7 +164,7 @@ def kernel_check(server):
     import numpy as np
 
     w, ps = 16, server.allocator.page_size
-    lowered = server._decode_paged.lower(
+    lowered = server._decode_paged_counted.lower(
         server.params, server.allocator.pools,
         server._put(server._table[:, :w]), server._kv_pos[:, : w * ps],
         server._put(server._next_tok[:, None]), server._put(server._pos),
@@ -328,6 +329,8 @@ def main(argv=None) -> int:
                     help="4: run only the four-chip layout comparison")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the random weights and probe data")
+    ap.add_argument("--arch", default=ARCH,
+                    help="the configuration to serve (repro.configs)")
     args = ap.parse_args(argv)
 
     if not (SRC / "repro").is_dir():
@@ -355,14 +358,14 @@ def main(argv=None) -> int:
 
     if args.chips == 4:
         assert len(devs) >= 4, f"--chips 4 needs 4 devices, found {len(devs)}"
-        outcomes, deltas, installed = four_chip_layouts(ARCH, devs, args.seed)
+        outcomes, deltas, installed = four_chip_layouts(args.arch, devs, args.seed)
         for o in outcomes:
             print(f"turn: tenant={o[0]} node={o[1]} tokens={o[3]} warm={o[4]}")
         print(f"four-chip: outcomes identical across layouts; page installs "
               f"{installed[0]} / {installed[1]}; probe logits max|Δ| per node "
               f"{deltas}")
     else:
-        cluster, model, responses, trace = served_path(ARCH, args.seed)
+        cluster, model, responses, trace = served_path(args.arch, args.seed)
         for tenant, rs in responses.items():
             for r in rs:
                 print(f"turn: tenant={tenant} turn={r.turn} node={r.served_by} "
@@ -374,6 +377,11 @@ def main(argv=None) -> int:
                       f"decode_p99_ms={r.timing.decode_p99_ms:.1f}")
         srv = cluster.node("edge-0").service.server
         ship = cluster.kv_ship.stats()
+        from repro.core import tracing
+
+        moe = {k: trace.counters.get(k) for k in tracing.MOE_COUNTERS}
+        print(f"served: moe_counters={moe} latent_page_installs="
+              f"{len(trace.named(tracing.LATENT_PAGE_INSTALL))}")
         print(f"served: cascade_steps={trace.counters['cascade_steps']} "
               f"prime_failures={cluster.store.prime_failures} "
               f"install_failures={ship['install_failures']} "

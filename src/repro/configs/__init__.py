@@ -1,5 +1,5 @@
-"""Architecture registry: the 10 assigned architectures + the paper's own
-model. ``get_config(name)`` accepts hyphen or underscore spellings;
+"""Architecture registry: the 10 assigned architectures, the paper's own
+model and the benchmark's DeepSeek-V2-Lite share. ``get_config(name)`` accepts hyphen or underscore spellings;
 ``--arch <id>`` in the launchers resolves through this registry.
 """
 
@@ -20,6 +20,7 @@ from .nemotron_4_340b import CONFIG as NEMOTRON_4_340B
 from .mamba2_1_3b import CONFIG as MAMBA2_1_3B
 from .chatglm3_6b import CONFIG as CHATGLM3_6B
 from .paper_qwen15_0_5b import CONFIG as PAPER_QWEN15_0_5B
+from .deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 
 ASSIGNED: Dict[str, ModelConfig] = {
     c.name: c
@@ -37,7 +38,11 @@ ASSIGNED: Dict[str, ModelConfig] = {
     ]
 }
 
-ALL: Dict[str, ModelConfig] = {**ASSIGNED, PAPER_QWEN15_0_5B.name: PAPER_QWEN15_0_5B}
+ALL: Dict[str, ModelConfig] = {
+    **ASSIGNED,
+    PAPER_QWEN15_0_5B.name: PAPER_QWEN15_0_5B,
+    DEEPSEEK_V2_LITE.name: DEEPSEEK_V2_LITE,
+}
 
 
 def get_config(name: str) -> ModelConfig:

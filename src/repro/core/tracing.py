@@ -12,7 +12,14 @@ off and returns a :class:`Trace` of what was recorded in between:
   served, the cluster clock at its start where the caller had a
   ``Network``, its attributes, and the backend compiles that ran while it
   was the innermost open span;
-- counters: named integers, incremented where the work happens.
+- counters: named integers, incremented where the work happens. The MoE
+  layers' counters (:data:`MOE_COUNTERS`, through :func:`count_moe`) come
+  from a small int array each decode step and prefill chunk returns beside
+  its logits, read at the serving step's own sync while the tracer is on:
+  (token, expert) pairs routed, pairs that reached an expert held on this
+  device, and held experts reached by at least one token, each summed over
+  the MoE layers (the last is what a kernel that reads only the experts a
+  step hits would read).
 
 While on, every span also opens a ``jax.profiler.TraceAnnotation`` named
 ``discedge.<span name>`` (followed by the node id, where there is one). A
@@ -252,3 +259,15 @@ def timed(name: str, node: Optional[str] = None, requests: RequestIds = None,
 def count(name: str, n: int = 1) -> None:
     if _T.on:
         _T.counters[name] = _T.counters.get(name, 0) + n
+
+
+MOE_COUNTERS = ("moe_routed_pairs", "moe_held_pairs", "moe_experts_hit")
+# the span around installing shipped or primed latent (MLA) pages
+LATENT_PAGE_INSTALL = "serving.latent_page_install"
+
+
+def count_moe(counts) -> None:
+    """Add one step's or chunk's MoE counts (``models.moe.N_STATS``
+    integers, in :data:`MOE_COUNTERS` order) to the counters."""
+    for name, n in zip(MOE_COUNTERS, counts):
+        count(name, int(n))

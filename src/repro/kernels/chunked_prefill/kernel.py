@@ -12,15 +12,17 @@ page bounds are scalar-prefetch operands, the K/V BlockSpec index maps
 dereference ``table[b, p]`` directly, and each grid step DMAs one physical
 page into VMEM — no dense ``max_len``-width intermediate ever exists.
 
-Grid: ``(batch, MP)`` — page-blocks innermost, identical to the decode
-kernel, with the same whole-page K/V blocks of one layer of the group's
-``(L, P, ps, KV*Dh)`` stack (the layer a scalar-prefetch operand, so the
-prefill step updates the stack in place and never slices it) and the same
-per-page body (``paged_attention.kernel._attend_page``, a static loop over
-the KV heads). The only difference is the query block: S chunk rows × G
-query heads share each page load, carried per KV head as one ``(S*G, Dh)``
-block with ``(m, l, acc)`` online-softmax scratch persisting across the
-page dimension.
+Grid: ``(batch, row blocks, MP)`` — page-blocks innermost, as in the
+decode kernel, with the same whole-page K/V blocks of one layer of the
+group's ``(L, P, ps, KV*Dh)`` stack (the layer a scalar-prefetch operand,
+so the prefill step updates the stack in place and never slices it) and
+the same per-page body (``paged_attention.kernel._attend_page``, a static
+loop over the KV heads; latent pages as there). The only difference is the
+query block: S chunk rows × G query heads share each page load, carried
+per KV head as one ``(S*G, Dh)`` block with ``(m, l, acc)`` online-softmax
+scratch persisting across the page dimension. Where that block would
+outgrow VMEM (latent attention: 16 heads of 576 lanes a chunk row), its
+rows are split into row blocks, each walking the pages on its own.
 
 Masking needs no ``kv_pos`` input at all: chunked prefill preserves the
 layout invariant (slot index == absolute position, written contiguously),
@@ -51,16 +53,18 @@ from ..paged_attention.kernel import NEG_INF, _attend_page, page_block
 
 def _chunked_prefill_kernel(
     table_ref, bound_ref, p0_ref, layer_ref,  # scalar prefetch (SMEM)
-    row_ref, q_ref, k_ref, v_ref,       # tensor blocks
-    o_ref,                              # output
-    acc_ref, m_ref, l_ref,              # VMEM scratch (persist over ip)
-    *, n_pb: int, kvh: int, ps: int, window: int, softcap: float,
-    scale: float,
+    row_ref, q_ref, k_ref,              # tensor blocks
+    *refs,                              # [v,] o | scratch acc, m, l
+    n_pb: int, kvh: int, ps: int, window: int, softcap: float,
+    scale: float, latent: bool = False,
 ):
-    """One lane x one page, every KV head: S*G query rows of online softmax
-    against the page's ps keys, causally masked per chunk row."""
+    """One lane x one row block x one page, every KV head: the block's
+    query rows of online softmax against the page's ps keys, causally
+    masked per chunk row (latent pages: no V operand)."""
+    v_ref = None if latent else refs[0]
+    o_ref, acc_ref, m_ref, l_ref = refs if latent else refs[1:]
     bi = pl.program_id(0)
-    ip = pl.program_id(1)
+    ip = pl.program_id(2)
 
     @pl.when(ip == 0)
     def _init():
@@ -92,7 +96,7 @@ def _chunked_prefill_kernel(
 def chunked_prefill_pallas(
     q: jnp.ndarray,           # (B, KV, S*G, Dh) — reshaped + rope'd by ops.py
     pool_k: jnp.ndarray,      # (L, P, page_size, KV*Dh) — post-scatter stack
-    pool_v: jnp.ndarray,
+    pool_v,                   # like pool_k, or None for latent pages
     page_table: jnp.ndarray,  # (B, MP) int32 physical page ids per lane
     page_bound: jnp.ndarray,  # (B,) int32 — ceil((p0+true_len)/ps), in [1, MP]
     p0: jnp.ndarray,          # (B,) int32 absolute position of chunk row 0
@@ -102,53 +106,60 @@ def chunked_prefill_pallas(
     window: int = 0,
     softcap: float = 0.0,
     interpret: bool = False,
+    scale: float = None,      # softmax scale; 1/sqrt(Dh) by default
+    dv: int = None,           # latent pages (pool_v None): value lanes
+    row_block: int = None,    # query rows a grid step carries (all by default)
 ) -> jnp.ndarray:
     b, kvh, sg, dh = q.shape
-    ps = pool_k.shape[2]
+    ps, row = pool_k.shape[2], pool_k.shape[3]
     mp = page_table.shape[1]
-    scale = 1.0 / (dh ** 0.5)
+    latent = pool_v is None
+    dv = dv if latent else dh
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
+    rb = row_block or sg
+    assert sg % rb == 0, (sg, rb)
 
-    def page_map(bi, ip, table, bound, p0_, lyr):
+    def page_map(bi, ri, ip, table, bound, p0_, lyr):
         # beyond-bound steps re-map to the lane's last real page: the block
         # index repeats, so the pipeline skips the DMA and table padding
         # (the scratch page) is never dereferenced for an active lane
         return (lyr[0], table[bi, jnp.minimum(ip, bound[bi] - 1)], 0, 0)
 
-    def lane_map(bi, ip, table, bound, p0_, lyr):
-        return (bi, 0, 0, 0)
+    def lane_map(bi, ri, ip, table, bound, p0_, lyr):
+        return (bi, 0, ri, 0)
 
-    def rows_map(bi, ip, table, bound, p0_, lyr):
-        return (0, 0)
+    def rows_map(bi, ri, ip, table, bound, p0_, lyr):
+        return (ri, 0)
 
+    pools = [pool_k] if latent else [pool_k, pool_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, mp),
+        grid=(b, sg // rb, mp),
         in_specs=[
-            pl.BlockSpec((sg, 1), rows_map),
-            pl.BlockSpec((1, kvh, sg, dh), lane_map),
-            page_block(ps, kvh, dh, page_map),
-            page_block(ps, kvh, dh, page_map),
-        ],
-        out_specs=pl.BlockSpec((1, kvh, sg, dh), lane_map),
+            pl.BlockSpec((rb, 1), rows_map),
+            pl.BlockSpec((1, kvh, rb, dh), lane_map),
+        ] + [page_block(ps, row, page_map) for _ in pools],
+        out_specs=pl.BlockSpec((1, kvh, rb, dv), lane_map),
         scratch_shapes=[
-            pltpu.VMEM((kvh, sg, dh), jnp.float32),
-            pltpu.VMEM((kvh, sg, 1), jnp.float32),
-            pltpu.VMEM((kvh, sg, 1), jnp.float32),
+            pltpu.VMEM((kvh, rb, dv), jnp.float32),
+            pltpu.VMEM((kvh, rb, 1), jnp.float32),
+            pltpu.VMEM((kvh, rb, 1), jnp.float32),
         ],
     )
     kern = functools.partial(
         _chunked_prefill_kernel,
         n_pb=mp, kvh=kvh, ps=ps, window=window, softcap=softcap, scale=scale,
+        latent=latent,
     )
     # chunk row offset of each query row (rows are chunk-major, G heads each)
     rows = (jnp.arange(sg, dtype=jnp.int32) // g)[:, None]
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, sg, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, sg, dv), q.dtype),
         interpret=interpret,
         name="chunked_prefill",
     )(
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
-        p0.astype(jnp.int32), layer.astype(jnp.int32), rows, q, pool_k, pool_v,
+        p0.astype(jnp.int32), layer.astype(jnp.int32), rows, q, *pools,
     )

@@ -38,6 +38,14 @@ proportional to *actual* tokens:
 - the wrapper (ops.py) can additionally trim the table width itself
   (``max_pages``) when the caller knows a tighter static bound.
 
+Latent pages (multi-head latent attention, ``models/mla.py``): the pool is
+one stack of rows ``[c_kv, k_pe]``, with no V pool. One latent head is
+shared by every query head (``KV == 1``, ``G == H``), the key is the
+whole row (``dk`` lanes, the query's width) and the value is its leading
+``dv`` lanes, read from the same page block: the kernels take no V operand
+then, and their output is ``dv`` wide. ``dk`` need not be a multiple of
+128: the page block's last two dims are the array's.
+
 Masking is positional (``0 <= kv_pos <= q_pos``, optional sliding window,
 optional logit softcap), identical to the dense decode kernel, with one
 deliberate difference: rows with *no* valid key (an empty lane) produce
@@ -61,12 +69,13 @@ _NT = (((1,), (1,)), ((), ()))   # (M, Dh) x (N, Dh) -> (M, N)
 _NN = (((1,), (0,)), ((), ()))   # (M, K) x (K, N) -> (M, N)
 
 
-def page_block(ps: int, kvh: int, dh: int, index_map) -> pl.BlockSpec:
-    """One page of one layer of an ``(L, P, ps, KV*Dh)`` pool stack. The
-    layer axis is squeezed (``None``), so the kernel body sees the page as
-    ``(1, ps, KV*Dh)``; its last two dims are the array's, which is
-    Mosaic's tiling rule for a block."""
-    return pl.BlockSpec((None, 1, ps, kvh * dh), index_map)
+def page_block(ps: int, row: int, index_map) -> pl.BlockSpec:
+    """One page of one layer of an ``(L, P, ps, row)`` pool stack (``row``
+    is ``KV*Dh``, or a latent row's width). The layer axis is squeezed
+    (``None``), so the kernel body sees the page as ``(1, ps, row)``; its
+    last two dims are the array's, which is Mosaic's tiling rule for a
+    block."""
+    return pl.BlockSpec((None, 1, ps, row), index_map)
 
 
 def _attend_page(
@@ -82,14 +91,20 @@ def _attend_page(
     ``mask`` is the slot mask, ``(1, ps)`` or one row per query row. ``p``
     is zeroed on masked slots (not just NEG_INF logits), so a row with no
     valid key accumulates ``l == 0`` and finalizes to exact zeros
-    independent of how many pages were visited."""
+    independent of how many pages were visited. Without ``v_ref`` (latent
+    pages) the value is the leading ``dv`` lanes of the key row, ``dv``
+    the accumulator's width."""
     dh = q_ref.shape[-1]
+    dv = acc_ref.shape[-1]
     for h in range(kvh):
         st = lane + (h,)
         heads = slice(h * dh, (h + 1) * dh)
         q = q_ref[0, h].astype(jnp.float32)             # (G, Dh)
         k = k_ref[0, :, heads].astype(jnp.float32)      # (ps, Dh)
-        v = v_ref[0, :, heads].astype(jnp.float32)
+        if v_ref is None:
+            v = k_ref[0, :, h * dh : h * dh + dv].astype(jnp.float32)
+        else:
+            v = v_ref[0, :, h * dv : (h + 1) * dv].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, _NT, preferred_element_type=jnp.float32
         ) * scale                                       # (G, ps)
@@ -110,17 +125,20 @@ def _attend_page(
 
 def _paged_decode_kernel(
     table_ref, bound_ref, qpos_ref, layer_ref,  # scalar prefetch (SMEM)
-    kvpos_ref, q_ref, k_ref, v_ref,     # tensor blocks
-    *refs,                              # [acc0, m0, l0,] o | scratch acc, m, l
+    kvpos_ref, q_ref, k_ref,            # tensor blocks
+    *refs,                              # [v,] [acc0, m0, l0,] o | scratch acc, m, l
     n_pb: int, kvh: int, window: int, softcap: float, scale: float,
-    start: int = 0, has_init: bool = False,
+    start: int = 0, has_init: bool = False, latent: bool = False,
 ):
     """One lane x one page of online softmax, every KV head. With
     ``start``/``has_init`` this is the *suffix* pass of the shared-prefix
     split: the grid walks pages [start, MP) only, and the softmax state is
     seeded from the shared pass's (acc, m, l) stats instead of the empty
     state — the exact continuation of the single-pass recurrence, so the
-    two-pass result is identical to walking all pages in one pass."""
+    two-pass result is identical to walking all pages in one pass.
+    ``latent``: no V operand, the value is read from the key row."""
+    v_ref = None if latent else refs[0]
+    refs = refs if latent else refs[1:]
     if has_init:
         acc0_ref, m0_ref, l0_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -160,11 +178,10 @@ def _paged_decode_kernel(
 
 def _shared_prefix_kernel(
     pages_ref, qpos_ref, layer_ref,     # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref,                # tensor blocks
-    acc_o, m_o, l_o,                    # outputs: softmax stats, all lanes
-    acc_ref, m_ref, l_ref,              # VMEM scratch (persist over ip)
-    *, n_sp: int, kvh: int, ps: int, window: int, softcap: float,
-    scale: float,
+    q_ref, k_ref,                       # tensor blocks
+    *refs,                              # [v,] stats out acc, m, l | scratch
+    n_sp: int, kvh: int, ps: int, window: int, softcap: float,
+    scale: float, latent: bool = False,
 ):
     """Shared-prefix pass: every page in ``pages`` (a run of physical pages
     holding positions [0, n_sp*ps), shared by the whole batch) is DMA'd
@@ -183,6 +200,8 @@ def _shared_prefix_kernel(
     flattened ``(B*G, Dh)`` product rounds differently from B separate
     ``(G, Dh)`` ones at G == 1, and the two-pass result must stay
     bit-identical to the single pass."""
+    v_ref = None if latent else refs[0]
+    acc_o, m_o, l_o, acc_ref, m_ref, l_ref = refs if latent else refs[1:]
     ip = pl.program_id(0)
     bi = pl.program_id(1)
 
@@ -212,7 +231,7 @@ def _shared_prefix_kernel(
 def shared_prefix_pallas(
     q: jnp.ndarray,             # (B, KV, G, Dh) — reshaped + rope'd by ops.py
     pool_k: jnp.ndarray,        # (L, P, page_size, KV*Dh) — a layer stack
-    pool_v: jnp.ndarray,
+    pool_v,                     # like pool_k, or None for latent pages
     shared_pages: jnp.ndarray,  # (S,) int32 physical page ids, positions [0, S*ps)
     q_pos: jnp.ndarray,         # (B,) int32
     layer: jnp.ndarray,         # (1,) int32 — the layer of the stack to read
@@ -220,13 +239,17 @@ def shared_prefix_pallas(
     window: int = 0,
     softcap: float = 0.0,
     interpret: bool = False,
+    scale: float = None,
+    dv: int = None,             # latent pages (pool_v None): value lanes
 ):
     """Partial-softmax stats of all lanes over the shared run:
     ``(acc, m, l)`` each ``(B, KV, G, ·)`` float32."""
     b, kvh, g, dh = q.shape
-    ps = pool_k.shape[2]
+    ps, row = pool_k.shape[2], pool_k.shape[3]
     n_sp = shared_pages.shape[0]
-    scale = 1.0 / (dh ** 0.5)
+    latent = pool_v is None
+    dv = dv if latent else dh
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
 
     def page_map(ip, bi, pages, qpos, lyr):
         return (lyr[0], pages[ip], 0, 0)
@@ -238,30 +261,28 @@ def shared_prefix_pallas(
     # q and output blocks: the pass stays within the scoped VMEM limit at
     # a few dozen lanes
     stats = lambda last: pl.BlockSpec((1, kvh, g, last), lane_map)  # noqa: E731
+    pools = [pool_k] if latent else [pool_k, pool_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_sp, b),
-        in_specs=[
-            pl.BlockSpec((1, kvh, g, dh), lane_map),
-            page_block(ps, kvh, dh, page_map),
-            page_block(ps, kvh, dh, page_map),
-        ],
-        out_specs=[stats(dh), stats(1), stats(1)],
+        in_specs=[pl.BlockSpec((1, kvh, g, dh), lane_map)]
+        + [page_block(ps, row, page_map) for _ in pools],
+        out_specs=[stats(dv), stats(1), stats(1)],
         scratch_shapes=[
-            pltpu.VMEM((b, kvh, g, dh), jnp.float32),
+            pltpu.VMEM((b, kvh, g, dv), jnp.float32),
             pltpu.VMEM((b, kvh, g, 1), jnp.float32),
             pltpu.VMEM((b, kvh, g, 1), jnp.float32),
         ],
     )
     kern = functools.partial(
         _shared_prefix_kernel, n_sp=n_sp, kvh=kvh, ps=ps, window=window,
-        softcap=softcap, scale=scale,
+        softcap=softcap, scale=scale, latent=latent,
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
+            jax.ShapeDtypeStruct((b, kvh, g, dv), jnp.float32),
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         ],
@@ -269,14 +290,14 @@ def shared_prefix_pallas(
         name="shared_prefix",
     )(
         shared_pages.astype(jnp.int32), q_pos.astype(jnp.int32),
-        layer.astype(jnp.int32), q, pool_k, pool_v,
+        layer.astype(jnp.int32), q, *pools,
     )
 
 
 def paged_attention_pallas(
     q: jnp.ndarray,           # (B, KV, G, Dh) — reshaped + rope'd by ops.py
     pool_k: jnp.ndarray,      # (L, P, page_size, KV*Dh) — a layer stack
-    pool_v: jnp.ndarray,
+    pool_v,                   # like pool_k, or None for latent pages
     page_table: jnp.ndarray,  # (B, MP) int32 physical page ids per lane
     page_bound: jnp.ndarray,  # (B,) int32 — ceil(kv_len / ps), in [1, MP]
     q_pos: jnp.ndarray,       # (B,) int32 absolute position of the query
@@ -288,11 +309,15 @@ def paged_attention_pallas(
     interpret: bool = False,
     start: int = 0,           # first page-block the grid visits
     init=None,                # optional (acc, m, l) stats from the shared pass
+    scale: float = None,      # softmax scale; 1/sqrt(Dh) by default
+    dv: int = None,           # latent pages (pool_v None): value lanes
 ) -> jnp.ndarray:
     b, kvh, g, dh = q.shape
-    ps = pool_k.shape[2]
+    ps, row = pool_k.shape[2], pool_k.shape[3]
     mp = page_table.shape[1]
-    scale = 1.0 / (dh ** 0.5)
+    latent = pool_v is None
+    dv = dv if latent else dh
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
     assert 0 <= start < mp, (start, mp)
     has_init = init is not None
 
@@ -309,17 +334,16 @@ def paged_attention_pallas(
     def lane_map(bi, ip, table, bound, qpos, lyr):
         return (bi, 0, 0, 0)
 
+    pools = [pool_k] if latent else [pool_k, pool_v]
     in_specs = [
         # a unit axis before the slots makes the block's last two dims
         # (1, ps) equal the array's — Mosaic's tiling rule for blocks
         pl.BlockSpec((1, 1, 1, ps), kvpos_map),
         pl.BlockSpec((1, kvh, g, dh), lane_map),
-        page_block(ps, kvh, dh, page_map),
-        page_block(ps, kvh, dh, page_map),
-    ]
+    ] + [page_block(ps, row, page_map) for _ in pools]
     if has_init:
         in_specs += [
-            pl.BlockSpec((1, kvh, g, dh), lane_map),
+            pl.BlockSpec((1, kvh, g, dv), lane_map),
             pl.BlockSpec((1, kvh, g, 1), lane_map),
             pl.BlockSpec((1, kvh, g, 1), lane_map),
         ]
@@ -327,9 +351,9 @@ def paged_attention_pallas(
         num_scalar_prefetch=4,
         grid=(b, mp - start),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, g, dh), lane_map),
+        out_specs=pl.BlockSpec((1, kvh, g, dv), lane_map),
         scratch_shapes=[
-            pltpu.VMEM((kvh, g, dh), jnp.float32),
+            pltpu.VMEM((kvh, g, dv), jnp.float32),
             pltpu.VMEM((kvh, g, 1), jnp.float32),
             pltpu.VMEM((kvh, g, 1), jnp.float32),
         ],
@@ -337,18 +361,19 @@ def paged_attention_pallas(
     kern = functools.partial(
         _paged_decode_kernel, n_pb=mp - start, kvh=kvh, window=window,
         softcap=softcap, scale=scale, start=start, has_init=has_init,
+        latent=latent,
     )
     args = [
         page_table.astype(jnp.int32), page_bound.astype(jnp.int32),
         q_pos.astype(jnp.int32), layer.astype(jnp.int32),
-        kv_pos.astype(jnp.int32).reshape(b, mp, 1, ps), q, pool_k, pool_v,
+        kv_pos.astype(jnp.int32).reshape(b, mp, 1, ps), q, *pools,
     ]
     if has_init:
         args += list(init)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(*args)

@@ -22,20 +22,24 @@ def as_layer_stack(pool_k, pool_v, layer):
     """``(pool_k, pool_v, layer)`` as the kernels take them: ``(L, P, ps,
     KV*Dh)`` stacks and a ``(1,)`` int32 layer index. Without a layer the
     pool is one layer's, ``(P, ps, KV, Dh)``, and becomes a stack of one
-    read at layer 0. A stack already flat passes through unchanged."""
+    read at layer 0. A stack already flat passes through unchanged; a
+    latent pool comes without ``pool_v`` (None), and stays without."""
     if layer is None:
-        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
+        pool_k, layer = pool_k[None], 0
+        pool_v = None if pool_v is None else pool_v[None]
     flat = lambda a: a.reshape(a.shape[:3] + (-1,))  # noqa: E731
-    return flat(pool_k), flat(pool_v), jnp.asarray(layer, jnp.int32).reshape(1)
+    return (flat(pool_k), None if pool_v is None else flat(pool_v),
+            jnp.asarray(layer, jnp.int32).reshape(1))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "softcap", "max_pages", "interpret")
+    jax.jit, static_argnames=("window", "softcap", "max_pages", "interpret",
+                              "scale", "v_width")
 )
 def paged_attention(
     q,            # (B, 1, H, Dh) — rope'd query token
     pool_k,       # (P, ps, KV, Dh), or a (L, P, ps, KV*Dh) stack with layer
-    pool_v,
+    pool_v,       # like pool_k; None for a latent pool
     page_table,   # (B, MP) physical page ids per lane
     q_pos,        # (B, 1) absolute position of the query token
     kv_pos,       # (B, MP*page_size) absolute positions per virtual slot
@@ -46,6 +50,8 @@ def paged_attention(
     softcap: float = 0.0,
     max_pages: Optional[int] = None,
     interpret: bool = None,
+    scale: Optional[float] = None,
+    v_width: Optional[int] = None,
 ):
     """q vs a paged KV pool -> (B, 1, H, Dh), attending through the table.
 
@@ -71,6 +77,12 @@ def paged_attention(
     the layer scan of a decode step hands the kernel the stack it updates,
     never a slice of it. Without, it is one layer's ``(P, page_size, KV,
     Dh)`` pool, read as a stack of one at layer 0.
+
+    Latent pages (``pool_v`` None): ``pool_k`` holds one row per token that
+    every query head attends (its width is the query's, so ``KV == 1``),
+    and the value is the row's leading ``v_width`` lanes; the output is
+    ``(B, 1, H, v_width)``. ``scale`` replaces the softmax scale
+    ``1/sqrt(Dh)``.
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -79,6 +91,7 @@ def paged_attention(
     ps = pool_k.shape[2]
     kvh = pool_k.shape[3] // dh
     g = h // kvh
+    dv = dh if pool_v is not None else v_width
     mp = page_table.shape[1]
     if max_pages is not None and max_pages < mp:
         mp = max(1, max_pages)
@@ -96,11 +109,12 @@ def paged_attention(
             init = shared_prefix_pallas(
                 qr, pool_k, pool_v, shared_pages[:start], qp, lyr,
                 window=window, softcap=softcap, interpret=interpret,
+                scale=scale, dv=dv,
             )
     out = paged_attention_pallas(
         qr, pool_k, pool_v, page_table, bound, qp,
         kv_pos.reshape(b, mp, ps), lyr,
         window=window, softcap=softcap, interpret=interpret,
-        start=start, init=init,
+        start=start, init=init, scale=scale, dv=dv,
     )
-    return out.reshape(b, 1, h, dh)
+    return out.reshape(b, 1, h, dv)

@@ -25,14 +25,23 @@ def paged_attention_ref(
     *,
     window: int = 0,
     softcap: float = 0.0,
+    scale=None,
+    v_width=None,
 ) -> jnp.ndarray:
+    """Latent pages (``pool_v`` None, ``pool_k`` (P, page_size, W)): one
+    row per token attended by every head, the value its leading
+    ``v_width`` lanes."""
     b, _, h, dh = q.shape
-    kvh = pool_k.shape[2]
+    if pool_v is None:
+        k = pool_k[page_table].reshape(b, -1, 1, dh)
+        v = k[..., :v_width]
+    else:
+        k = pool_k[page_table].reshape(b, -1, pool_k.shape[2], dh)   # (B, MP*ps, KV, Dh)
+        v = pool_v[page_table].reshape(b, -1, pool_k.shape[2], dh)
+    kvh, dv = k.shape[2], v.shape[-1]
     g = h // kvh
-    k = pool_k[page_table].reshape(b, -1, kvh, dh)   # (B, MP*ps, KV, Dh)
-    v = pool_v[page_table].reshape(b, -1, kvh, dh)
     qq = q.reshape(b, kvh, g, dh).astype(jnp.float32)
-    scale = 1.0 / (dh ** 0.5)
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
     logits = jnp.einsum("bkgd,btkd->bkgt", qq, k.astype(jnp.float32)) * scale
     if softcap > 0.0:
         logits = softcap * jnp.tanh(logits / softcap)
@@ -46,4 +55,4 @@ def paged_attention_ref(
     l = jnp.sum(p, axis=-1, keepdims=True)
     l = jnp.where(l == 0.0, 1.0, l)
     out = jnp.einsum("bkgt,btkd->bkgd", p / l, v.astype(jnp.float32))
-    return out.reshape(b, 1, h, dh).astype(q.dtype)
+    return out.reshape(b, 1, h, dv).astype(q.dtype)

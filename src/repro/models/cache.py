@@ -123,9 +123,10 @@ def init_paged_pool(
     device=None,
 ) -> Cache:
     """Physical KV page pool for one layer group: k/v of shape
-    (L, n_pages, page_size, KV*Dh), made on (and committed to) ``device``
-    when one is given. kv_pos is tracked per *sequence* (B, width) by the
-    owner, not per page.
+    (L, n_pages, page_size, KV*Dh), or the latent ``kv`` of (L, n_pages,
+    page_size, latent_row) (:func:`pool_cells`), made on (and committed
+    to) ``device`` when one is given. kv_pos is tracked per *sequence*
+    (B, width) by the owner, not per page.
 
     A slot's heads lie side by side in one row, in C order the same bytes
     as ``(KV, Dh)``. The flat row is what lets a decode step update the
@@ -138,11 +139,51 @@ def init_paged_pool(
     handed it to a kernel first copied it into row-major order and
     back."""
     dt = dtype or dtype_of(cfg.compute_dtype)
-    shape = (n_layers, n_pages, page_size, cfg.n_kv_heads * cfg.d_head)
     return {
-        "k": jnp.zeros(shape, dtype=dt, device=device),
-        "v": jnp.zeros(shape, dtype=dt, device=device),
+        name: jnp.zeros((n_layers, n_pages, page_size, cell[0] * cell[1]), dtype=dt,
+                        device=device)
+        for name, cell in pool_cells(cfg).items()
     }
+
+
+def pool_cells(cfg: ModelConfig) -> Dict[str, Tuple[int, int]]:
+    """The page format: each pool of a layer group and the (heads, width)
+    of one token's cell in it, whose product is the pool's row. K and V
+    of ``(KV, Dh)``; for latent attention one pool, ``kv``, of one row
+    ``[c_kv, k_pe]`` shared by every head, whose leading ``kv_lora_rank``
+    lanes are the value, padded with zeros to whole 128-lane tiles
+    (:func:`latent_row`)."""
+    if cfg.is_mla:
+        return {"kv": (1, latent_row(cfg))}
+    cell = (cfg.n_kv_heads, cfg.d_head)
+    return {"k": cell, "v": cell}
+
+
+def latent_row(cfg: ModelConfig) -> int:
+    """A latent pool's row: ``latent_width`` rounded up to whole 128-lane
+    tiles (576 -> 640 for DeepSeek-V2). The TPU stores a ``(page_size,
+    576)`` minor pair with the page axis innermost, to save the padding,
+    and a step that hands such a pool to a kernel copies it into
+    row-major order and back every step; at 640 it keeps the pool
+    row-major, as the kernels read it. The padding lanes hold zeros, and
+    the absorbed query is padded alike, so they add nothing to q.k."""
+    return -(-cfg.latent_width // 128) * 128
+
+
+def gather_pool_views(
+    pool: Cache, page_table: jnp.ndarray, cfg: ModelConfig
+) -> Cache:
+    """Each pool of a group's stack linearized through the page table,
+    ``(L, B, MP*ps, heads, width)`` per :func:`pool_cells` — the hoisted
+    gather of the reference paged paths (:func:`gather_pages_stacked`)."""
+    cells = pool_cells(cfg)
+    if not cfg.is_mla:
+        k, v = gather_pages_stacked(pool["k"], pool["v"], page_table, cells["k"])
+        return {"k": k, "v": v}
+    a = pool["kv"]
+    b, mp = page_table.shape
+    return {"kv": a[:, page_table].reshape(
+        (a.shape[0], b, mp * a.shape[2]) + cells["kv"])}
 
 
 def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
@@ -166,6 +207,54 @@ def _write_cells(pool, layer, phys, slot, new):
     cell = pool.shape[len(idx):]
     new = new.reshape(phys.shape + cell).astype(pool.dtype)
     return pool.at[idx].set(new, mode="drop")
+
+
+def _step_cells(pos, page_table, page_size: int, n_pages: int):
+    """(page, offset) cells of one decode token per lane; a position past
+    the table gets the out-of-range page ``n_pages``, so its write drops."""
+    b = pos.shape[0]
+    bidx = jnp.arange(b)
+    mp = page_table.shape[1]
+    page_idx = pos // page_size
+    phys = page_table[bidx, jnp.minimum(page_idx, mp - 1)]
+    phys = jnp.where(page_idx < mp, phys, n_pages)   # OOB sentinel -> dropped
+    return phys, pos % page_size
+
+
+def _chunk_cells(q_pos, valid, page_table, page_size: int, n_pages: int,
+                 n_skip: int):
+    """(page, offset) cells of a chunk's tokens; padding, positions past
+    the table and the first ``n_skip`` pages get page ``n_pages`` (dropped)."""
+    mp = page_table.shape[1]
+    bidx = jnp.arange(q_pos.shape[0])[:, None]
+    page_idx = q_pos // page_size
+    phys = page_table[bidx, jnp.clip(page_idx, 0, mp - 1)]
+    drop = (~valid) | (page_idx >= mp) | (page_idx < n_skip) | (q_pos < 0)
+    phys = jnp.where(drop, n_pages, phys)            # OOB sentinel -> dropped
+    return phys, q_pos % page_size
+
+
+def paged_write_rows(
+    pool: jnp.ndarray,      # (L, P, ps, W) with layer, or (P, ps, ...) one layer
+    new: jnp.ndarray,       # (B, W...) for a decode token, (B, S, W...) a chunk
+    pos: jnp.ndarray,       # (B,) or (B, S) absolute positions
+    page_table: jnp.ndarray,  # (B, MP)
+    page_size: int,
+    layer=None,
+    valid: Optional[jnp.ndarray] = None,  # (B, S) chunk rows to write
+    n_skip: int = 0,
+) -> jnp.ndarray:
+    """Write one pool's rows into their (page, offset) cells with
+    :func:`paged_write_step`'s rules for a decode token (``pos`` (B,)) and
+    :func:`paged_write_chunk`'s for a chunk (``pos`` (B, S)). The latent
+    page format (one pool, no V) writes through this."""
+    n_pages = pool.shape[0 if layer is None else 1]
+    if pos.ndim == 1:
+        phys, slot = _step_cells(pos, page_table, page_size, n_pages)
+    else:
+        ok = jnp.ones(pos.shape, bool) if valid is None else valid
+        phys, slot = _chunk_cells(pos, ok, page_table, page_size, n_pages, n_skip)
+    return _write_cells(pool, layer, phys, slot, new)
 
 
 def paged_write_step(
@@ -192,14 +281,8 @@ def paged_write_step(
     With ``layer``, the pools are a group's ``(L, P, ps, KV*Dh)`` stack and
     the token lands in that layer's cell: the layer scan of a decode step
     carries the stack and updates it in place."""
-    b = pos.shape[0]
-    bidx = jnp.arange(b)
-    mp = page_table.shape[1]
     n_pages = pool_k.shape[0 if layer is None else 1]
-    page_idx = pos // page_size
-    phys = page_table[bidx, jnp.minimum(page_idx, mp - 1)]
-    phys = jnp.where(page_idx < mp, phys, n_pages)   # OOB sentinel -> dropped
-    slot = pos % page_size
+    phys, slot = _step_cells(pos, page_table, page_size, n_pages)
     pk = _write_cells(pool_k, layer, phys, slot, k_new[:, 0])
     pv = _write_cells(pool_v, layer, phys, slot, v_new[:, 0])
     return pk, pv
@@ -227,15 +310,8 @@ def paged_write_chunk(
     so a caller that starts a chunk inside a shared region redirects those
     slots to nowhere instead of corrupting the donor. ``layer`` as in
     :func:`paged_write_step`."""
-    b, s = q_pos.shape
-    mp = page_table.shape[1]
     n_pages = pool_k.shape[0 if layer is None else 1]
-    bidx = jnp.arange(b)[:, None]
-    page_idx = q_pos // page_size
-    phys = page_table[bidx, jnp.clip(page_idx, 0, mp - 1)]
-    drop = (~valid) | (page_idx >= mp) | (page_idx < n_skip) | (q_pos < 0)
-    phys = jnp.where(drop, n_pages, phys)            # OOB sentinel -> dropped
-    slot = q_pos % page_size
+    phys, slot = _chunk_cells(q_pos, valid, page_table, page_size, n_pages, n_skip)
     pk = _write_cells(pool_k, layer, phys, slot, k_new)
     pv = _write_cells(pool_v, layer, phys, slot, v_new)
     return pk, pv

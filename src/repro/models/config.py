@@ -39,12 +39,33 @@ class ModelConfig:
     sliding_window: int = 0           # window size for local attention layers
     layer_pattern: str = "uniform"    # uniform | local_global | zamba_hybrid
     attn_variant: str = "full"        # full | sliding_window (long-context variant)
+    attn_type: str = "gqa"            # gqa | mla (latent attention, DeepSeek-V2)
+    # --- latent attention (attn_type "mla") ------------------------------------
+    kv_lora_rank: int = 0             # width of the compressed KV latent c_kv
+    qk_nope_dim: int = 0              # per-head query/key dims without RoPE
+    qk_rope_dim: int = 0              # RoPE dims: per-head query, one shared key
+    v_head_dim: int = 0               # per-head value width
+    # --- YaRN RoPE scaling (rope_factor 0 = plain RoPE) ----------------------
+    rope_factor: float = 0.0
+    rope_original_max: int = 0        # original max position embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MoE -----------------------------------------------------------------
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25     # training dispatch; 0 = dropless there too
     router_aux_coef: float = 0.01
+    moe_d_ff: int = 0                 # routed expert width (0 -> d_ff)
+    n_shared_experts: int = 0         # always-on experts: one MLP of n * moe_d_ff
+    first_dense_layers: int = 0       # leading layers with a dense MLP of d_ff
+    router_norm_topk: bool = True     # renormalise the top-k gate weights to sum 1
+    routed_scale: float = 1.0         # factor on the routed experts' gate weights
+    # (first, count) of the routed experts this device holds (expert
+    # parallelism); count 0 holds all. The router always scores n_experts.
+    experts_held: Tuple[int, int] = (0, 0)
 
     # --- SSM (Mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
@@ -90,6 +111,25 @@ class ModelConfig:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
 
     @property
+    def is_mla(self) -> bool:
+        return self.attn_type == "mla"
+
+    @property
+    def latent_width(self) -> int:
+        """MLA: one cached row per token, c_kv then the roped shared key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts whose weights are here."""
+        first, count = self.experts_held
+        return (first, count) if count else (0, self.n_experts)
+
+    @property
     def is_attention_free(self) -> bool:
         return self.arch_type == "ssm"
 
@@ -125,6 +165,16 @@ class ModelConfig:
         n_kv = max(1, min(self.n_kv_heads, n_heads))
         while n_heads % n_kv:
             n_kv -= 1
+        mla = {}
+        if self.is_mla:
+            mla = dict(
+                kv_lora_rank=min(self.kv_lora_rank, 64),
+                qk_nope_dim=min(self.qk_nope_dim, 32),
+                qk_rope_dim=min(self.qk_rope_dim, 16),
+                v_head_dim=min(self.v_head_dim, 32),
+                d_head=min(self.qk_nope_dim, 32) + min(self.qk_rope_dim, 16),
+                rope_original_max=min(self.rope_original_max, 64),
+            )
         small = dict(
             n_layers=min(self.n_layers, 2),
             d_model=d_model,
@@ -135,6 +185,10 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            moe_d_ff=min(self.moe_d_ff, 128),
+            n_shared_experts=min(self.n_shared_experts, 2),
+            first_dense_layers=min(self.first_dense_layers, 1),
+            experts_held=(0, 0),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=32,
             ssm_chunk=16,
@@ -148,6 +202,7 @@ class ModelConfig:
             grad_accum=1,
             name=self.name + "-smoke",
         )
+        small.update(mla)
         small.update(overrides)
         return replace(self, **small)
 
@@ -170,19 +225,24 @@ class ModelConfig:
         # attention (dense/moe/vlm/audio and gemma-style)
         if self.arch_type in ("dense", "moe", "vlm", "audio"):
             dh = self.d_head
-            per_layer += d * (self.n_heads * dh) + d * (2 * self.n_kv_heads * dh)
-            per_layer += (self.n_heads * dh) * d
+            if self.is_mla:
+                h, r = self.n_heads, self.kv_lora_rank
+                per_layer += d * h * dh + d * self.latent_width + r
+                per_layer += r * h * (self.qk_nope_dim + self.v_head_dim)
+                per_layer += h * self.v_head_dim * d
+            else:
+                per_layer += d * (self.n_heads * dh) + d * (2 * self.n_kv_heads * dh)
+                per_layer += (self.n_heads * dh) * d
             per_layer += 2 * d  # norms
-            if self.mlp_type in ("swiglu", "geglu"):
-                ff = 3 * d * self.d_ff
-            else:
-                ff = 2 * d * self.d_ff
-            if self.n_experts:
-                per_layer += d * self.n_experts  # router
-                per_layer += self.n_experts * ff
-            else:
-                per_layer += ff
-            n += self.n_layers * per_layer
+            mats = 3 if self.mlp_type in ("swiglu", "geglu") else 2
+            ff = mats * d * self.d_ff
+            n_moe = self.n_layers - self.first_dense_layers if self.n_experts else 0
+            n += self.n_layers * per_layer + (self.n_layers - n_moe) * ff
+            if n_moe:
+                ew = mats * d * self.expert_width
+                moe = d * self.n_experts  # router
+                moe += self.held_experts[1] * ew + self.n_shared_experts * ew
+                n += n_moe * moe
         elif self.arch_type in ("ssm", "hybrid"):
             di, ns, nh = self.d_inner, self.ssm_state, self.n_ssm_heads
             g = self.ssm_groups
@@ -204,6 +264,7 @@ class ModelConfig:
         if not self.n_experts:
             return self.param_count()
         full = self.param_count()
-        ff = (3 if self.mlp_type in ("swiglu", "geglu") else 2) * self.d_model * self.d_ff
-        inactive = self.n_layers * (self.n_experts - self.top_k) * ff
+        ff = (3 if self.mlp_type in ("swiglu", "geglu") else 2) * self.d_model * self.expert_width
+        n_moe = self.n_layers - self.first_dense_layers
+        inactive = n_moe * (self.held_experts[1] - self.top_k) * ff
         return full - inactive

@@ -6,6 +6,7 @@ shardable with pjit (no framework module state).
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -111,10 +112,54 @@ def rope_mrope(
     return _apply_rotary(x.astype(jnp.float32), cos, sin).astype(x.dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's ``yarn_get_mscale``)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(cfg: ModelConfig, d_rot: int) -> Tuple[np.ndarray, float]:
+    """YaRN's inverse frequencies for ``d_rot`` rotary dims and the factor
+    on cos/sin (arXiv:2309.00071; DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): dims that rotate more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those under ``beta_slow`` are divided by the factor, and a linear ramp
+    blends the dims between."""
+    base, factor, orig = cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max
+    exps = np.arange(0, d_rot, 2, dtype=np.float64) / d_rot
+    extra = 1.0 / base ** exps
+    inter = 1.0 / (factor * base ** exps)
+
+    def dim_of(rotations):
+        return d_rot * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), d_rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d_rot // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    inv = inter * (1.0 - keep) + extra * keep
+    m = yarn_mscale(factor, cfg.yarn_mscale) / yarn_mscale(factor, cfg.yarn_mscale_all_dim)
+    return inv.astype(np.float32), m
+
+
+def rope_yarn(cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+    """Half-split rotary embedding with YaRN frequencies. x: (B, S, H, d);
+    positions: (B, S)."""
+    inv, m = yarn_freqs(cfg, x.shape[-1])
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(inv)
+    cos, sin = jnp.cos(ang)[:, :, None, :] * m, jnp.sin(ang)[:, :, None, :] * m
+    return _apply_rotary(x.astype(jnp.float32), cos, sin).astype(x.dtype)
+
+
 def apply_rope(
     cfg: ModelConfig, x: jnp.ndarray, positions: jnp.ndarray
 ) -> jnp.ndarray:
     """positions: (B,S) for standard/chatglm2d; (3,B,S) for mrope."""
+    if cfg.rope_factor > 0:
+        return rope_yarn(cfg, x, positions)
     if cfg.rope_style == "standard":
         return rope_standard(x, positions, cfg.rope_theta)
     if cfg.rope_style == "chatglm2d":

@@ -1,6 +1,17 @@
-"""Mixture-of-Experts FFN (dbrx: 16e top-4 fine-grained; granite: 40e top-8).
+"""Mixture-of-Experts FFN (dbrx: 16e top-4 fine-grained; granite: 40e top-8;
+DeepSeek-V2: 64 fine-grained experts top-6 beside 2 shared ones).
 
-Dispatch is the static-shape, sort-based capacity algorithm: tokens are
+Serving uses :func:`moe_serve`: dropless and exact. Each token's gate
+weights over the experts held here (``cfg.held_experts``, one device's
+share under expert parallelism; the router still scores every expert)
+form a dense (tokens, held) matrix, zero where the token did not pick the
+expert, and one einsum over the held experts applies them. No token is
+dropped, and a token's output does not depend on the other tokens of its
+batch. The shared experts (``n_shared_experts``) run on every token.
+
+Training (:func:`moe_forward`) keeps the capacity dispatch, unless
+``capacity_factor`` is 0. That dispatch is the static-shape, sort-based
+capacity algorithm: tokens are
 argsorted by expert id, each expert takes up to C = ceil(T·K/E · cf) slots,
 overflow drops (capacity-based, GShard-style) — so compiled FLOPs equal
 *active* FLOPs (E·C·D·F ≈ T·K·D·F·cf), which is what the roofline's
@@ -14,28 +25,39 @@ GSPMD-auto layout against an explicit shard_map all_to_all schedule.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
-from .layers import Params, dtype_of
+from .layers import Params, dtype_of, init_mlp, mlp_forward
+
+# moe_serve's per-step counts: (token, expert) pairs routed, pairs that
+# reached an expert held here, and held experts reached by any token
+N_STATS = 3
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all ``n_experts``; weights of the held experts only;
+    the shared experts as one MLP of ``n_shared_experts * expert_width``."""
+    d, f = cfg.d_model, cfg.expert_width
+    e = cfg.held_experts[1]
     dt = dtype_of(cfg.param_dtype)
     ks = jax.random.split(key, 4)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
     p: Params = {
-        "router": (jax.random.normal(ks[0], (d, e)) * s_in).astype(dt),
+        "router": (jax.random.normal(ks[0], (d, cfg.n_experts)) * s_in).astype(dt),
         "w_up": (jax.random.normal(ks[1], (e, d, f)) * s_in).astype(dt),
         "w_down": (jax.random.normal(ks[2], (e, f, d)) * s_out).astype(dt),
     }
     if cfg.mlp_type == "swiglu":
         p["w_gate"] = (jax.random.normal(ks[3], (e, d, f)) * s_in).astype(dt)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(
+            jax.random.fold_in(key, 4), cfg, d_ff=cfg.n_shared_experts * f
+        )
     return p
 
 
@@ -86,13 +108,25 @@ def _expert_mlp(p: Params, xe: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", act, p["w_down"])
 
 
-def _route(p: Params, xf: jnp.ndarray, cfg: ModelConfig):
-    """Router probs + top-k + Switch aux loss. xf (T,D)."""
-    e, k = cfg.n_experts, cfg.top_k
+def _gates(p: Params, xf: jnp.ndarray, cfg: ModelConfig):
+    """Softmax router over all experts, greedy top-k: (probs (T,E), top_idx
+    (T,K), top_w (T,K)), the weights renormalised to sum 1 where the
+    config says so and scaled by ``routed_scale``. xf (T,D)."""
     router_logits = (xf @ p["router"]).astype(jnp.float32)       # (T,E)
     probs = jax.nn.softmax(router_logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, k)                  # (T,K)
-    top_w = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    top_vals, top_idx = jax.lax.top_k(probs, cfg.top_k)          # (T,K)
+    top_w = top_vals
+    if cfg.router_norm_topk:
+        top_w = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    if cfg.routed_scale != 1.0:
+        top_w = top_w * cfg.routed_scale
+    return probs, top_idx, top_w
+
+
+def _route(p: Params, xf: jnp.ndarray, cfg: ModelConfig):
+    """Router probs + top-k + Switch aux loss. xf (T,D)."""
+    e = cfg.n_experts
+    probs, top_idx, top_w = _gates(p, xf, cfg)
     me = jnp.mean(probs, axis=0)                                  # (E,)
     ce = jnp.mean(
         jnp.sum(jax.nn.one_hot(top_idx, e, dtype=jnp.float32), axis=1), axis=0
@@ -112,6 +146,14 @@ def moe_forward(
     data shard, all_to_all over the model/expert axis, local expert matmuls,
     reverse all_to_all (§Perf hillclimb; requires active sharding rules).
     """
+    if cfg.capacity_factor <= 0:
+        # dropless: the serving layer, with the Switch loss beside it
+        _, _, aux = _route(p, x.reshape(-1, x.shape[-1]), cfg)
+        out, _ = moe_serve(p, x, cfg)
+        return out, aux.astype(jnp.float32)
+    assert cfg.held_experts == (0, cfg.n_experts), (
+        "capacity dispatch needs every expert held"
+    )
     if cfg.moe_impl == "shard_map":
         from .pjit_rules import current_rules
 
@@ -129,7 +171,57 @@ def moe_forward(
     ye = _expert_mlp(p, xe, cfg)                                   # (E,C,D)
     ye_flat = ye.reshape(e * cap, d) * slot_w[:, None].astype(ye.dtype)
     out = jnp.zeros((t + 1, d), ye.dtype).at[slot_token].add(ye_flat)[:t]
+    if "shared" in p:
+        out = out + mlp_forward(p["shared"], xf, cfg).astype(out.dtype)
     return out.reshape(b, s, d).astype(x.dtype), aux.astype(jnp.float32)
+
+
+def held_share(
+    p: Params, xf: jnp.ndarray, cfg: ModelConfig,
+    valid: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts' contribution from the experts held here, and
+    this layer's counts. xf (T,D); ``valid`` (T,) marks the tokens the
+    counts cover (all by default). Returns ((T,D), (N_STATS,) int32).
+
+    Every token is multiplied by every held expert and weighted by its
+    gate, zero for an expert it did not pick: one einsum over the held
+    experts, no dispatch, no capacity. Summed over the shares of every
+    device of an expert-parallel group, this is the whole routed layer."""
+    first, held = cfg.held_experts
+    _, top_idx, top_w = _gates(p, xf, cfg)
+    local = top_idx - first                                       # (T,K)
+    picked = local[..., None] == jnp.arange(held)                 # (T,K,E_h)
+    gates = jnp.sum(jnp.where(picked, top_w[..., None], 0.0), axis=1)
+    # every held expert over every token: (E_h, T, D), experts as the batch
+    ye = _expert_mlp(p, jnp.broadcast_to(xf, (held,) + xf.shape), cfg)
+    out = jnp.einsum("te,etd->td", gates.astype(ye.dtype), ye)
+
+    ok = jnp.ones(xf.shape[:1], bool) if valid is None else valid
+    hit = picked & ok[:, None, None]
+    stats = jnp.stack([
+        jnp.sum(ok) * cfg.top_k,
+        jnp.sum(hit),
+        jnp.sum(jnp.any(hit, axis=(0, 1))),
+    ]).astype(jnp.int32)
+    return out, stats
+
+
+def moe_serve(
+    p: Params, x: jnp.ndarray, cfg: ModelConfig,
+    valid: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The served MoE layer: the held experts' share (:func:`held_share`)
+    plus the shared experts. x (B,S,D), ``valid`` (B,S) -> (out (B,S,D),
+    counts (N_STATS,) int32)."""
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    out, stats = held_share(
+        p, xf, cfg, None if valid is None else valid.reshape(b * s)
+    )
+    if "shared" in p:
+        out = out + mlp_forward(p["shared"], xf, cfg).astype(out.dtype)
+    return out.reshape(b, s, d).astype(x.dtype), stats
 
 
 def _moe_forward_shard_map(

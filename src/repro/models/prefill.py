@@ -16,15 +16,16 @@ from .attention import attention_append, attention_chunk_paged, attention_forwar
 from .cache import (
     Cache,
     append_kv_pos,
-    gather_pages_stacked,
+    gather_pool_views,
     prefill_kv_pos,
     ring_from_prefill,
 )
 from .config import ModelConfig
 from .layers import dtype_of, embed_tokens, mlp_forward, rms_norm, unembed
-from .moe import moe_forward
+from .mla import mla_chunk_paged
+from .moe import N_STATS, moe_serve
 from .ssm import ssm_forward
-from .transformer import GroupSpec, Params, layer_groups, scan_or_unroll
+from .transformer import GroupSpec, Params, ffn_serve, layer_groups, scan_or_unroll
 
 
 def _dense_block_prefill(bp, x, positions, cfg, window, seq_valid):
@@ -43,7 +44,7 @@ def _moe_block_prefill(bp, x, positions, cfg, window, seq_valid):
         window=window, seq_valid=seq_valid, return_kv=True,
     )
     x = x + h
-    m, aux = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
+    m, _ = moe_serve(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return x + m, k, v
 
 
@@ -83,7 +84,10 @@ def prefill(
     With true_len, the input is right-padded to a bucket length: padded
     positions are masked out of attention and the caches, and the returned
     logits/next_pos refer to position true_len-1. (Supported for full-cache
-    dense/moe groups — the serving engine's bucketing path.)"""
+    dense/moe groups — the serving engine's bucketing path.) GQA models
+    only: latent attention prefills into paged pools
+    (:func:`prefill_chunk_paged`)."""
+    assert not cfg.is_mla, "latent attention is served from paged pools"
     b, s = tokens.shape[0], tokens.shape[1]
     if true_len is not None:
         idx = jnp.arange(s, dtype=jnp.int32)
@@ -216,13 +220,14 @@ def _moe_block_append(bp, x, positions, ck, cv, kv_pos, cfg, window):
         ck, cv, kv_pos, cfg, window=window,
     )
     x = x + h
-    m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
+    m, _ = moe_serve(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return x + m, nk, nv
 
 
 def supports_append(cfg: ModelConfig) -> bool:
     """Incremental prefill is implemented for full-cache dense/moe/vlm
-    groups (slot == absolute position). Ring/SSM/hybrid state cannot be
+    groups (slot == absolute position), GQA or latent attention (the
+    latter on the paged paths only). Ring/SSM/hybrid state cannot be
     extended in place the same way yet."""
     return cfg.attn_variant == "full" and all(
         spec.kind in ("dense", "moe") for spec in layer_groups(cfg)
@@ -246,7 +251,8 @@ def prefill_append(
     (B,V), new caches, next_pos (B,)). With ``true_len`` the chunk is
     right-padded to a bucket length; padded positions write ``kv_pos = -1``
     and are overwritten by the next chunk. Supported for full-cache
-    dense/moe groups only (see :func:`supports_append`)."""
+    dense/moe groups only (see :func:`supports_append`), GQA only."""
+    assert not cfg.is_mla, "latent attention is served from paged pools"
     assert supports_append(cfg), (
         "prefill_append requires full-cache dense/moe groups "
         f"(arch={cfg.arch_type}, attn_variant={cfg.attn_variant})"
@@ -289,32 +295,29 @@ def prefill_append(
 # Paged chunked prefill — prompt chunks land straight in KV pages
 # ---------------------------------------------------------------------------
 
-def _dense_block_chunk_paged(
-    bp, x, positions, valid, pk, pv, page_table, p0, true_len, cfg,
-    n_skip, lin_k=None, lin_v=None, layer=None,
+def _attn_chunk_paged(
+    bp, x, positions, valid, pool, page_table, p0, true_len, cfg, n_skip,
+    lin=None, layer=None,
 ):
+    """The attention sublayer of one block of a paged prefill chunk,
+    residual added: the chunk's K/V (or latent rows) written into their
+    page cells, then attended through the page table. ``pool`` and ``lin``
+    by name as ``cache.pool_cells`` gives them. Returns (x, pool)."""
+    h_in = rms_norm(x, bp["norm1"], cfg.norm_eps)
+    if cfg.is_mla:
+        h, kv = mla_chunk_paged(
+            bp["attn"], h_in, positions, valid, pool["kv"], page_table, p0,
+            true_len, cfg, n_skip=n_skip,
+            lin=None if lin is None else lin["kv"], layer=layer,
+        )
+        return x + h, {"kv": kv}
     h, nk, nv = attention_chunk_paged(
-        bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, valid,
-        pk, pv, page_table, p0, true_len, cfg,
-        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v, layer=layer,
+        bp["attn"], h_in, positions, valid, pool["k"], pool["v"], page_table,
+        p0, true_len, cfg, n_skip=n_skip,
+        lin_k=None if lin is None else lin["k"],
+        lin_v=None if lin is None else lin["v"], layer=layer,
     )
-    x = x + h
-    x = x + mlp_forward(bp["mlp"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
-    return x, nk, nv
-
-
-def _moe_block_chunk_paged(
-    bp, x, positions, valid, pk, pv, page_table, p0, true_len, cfg,
-    n_skip, lin_k=None, lin_v=None, layer=None,
-):
-    h, nk, nv = attention_chunk_paged(
-        bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, valid,
-        pk, pv, page_table, p0, true_len, cfg,
-        n_skip=n_skip, lin_k=lin_k, lin_v=lin_v, layer=layer,
-    )
-    x = x + h
-    m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
-    return x + m, nk, nv
+    return x + h, {"k": nk, "v": nv}
 
 
 def prefill_chunk_paged(
@@ -326,7 +329,8 @@ def prefill_chunk_paged(
     p0: jnp.ndarray,              # (B,) absolute position of chunk row 0
     true_len: jnp.ndarray,        # (B,) real chunk lengths
     n_skip: int = 0,
-) -> Tuple[jnp.ndarray, List[Cache]]:
+    stats: bool = False,
+):
     """Prefill a prompt chunk *directly into KV pages*: each layer scatters
     the chunk's rotated K/V into page cells through the page table before
     attending (the paged sibling of :func:`prefill_append` — no dense
@@ -341,10 +345,13 @@ def prefill_chunk_paged(
     are dropped and their outputs are garbage that no one reads.
 
     Pure function; jit with donate_argnums on pools. Returns
-    (logits at row ``true_len - 1`` (B,V), new pools). Full-cache dense/moe
-    groups only (:func:`supports_append`); same kernel/reference dispatch,
-    per-step hoisted gather and in-place layer scan (the pools a carry, the
-    layer index scanned) as ``decode_step_paged``."""
+    (logits at row ``true_len - 1`` (B,V), new pools), and with ``stats``
+    also the MoE layers' counts over the chunk's real rows, as
+    ``decode_step_paged`` gives them. Full-cache dense/moe groups only
+    (:func:`supports_append`), GQA or latent attention; same
+    kernel/reference dispatch, per-step hoisted gather and in-place layer
+    scan (the pools a carry, the layer index scanned) as
+    ``decode_step_paged``."""
     assert supports_append(cfg), (
         "prefill_chunk_paged requires full-cache dense/moe groups "
         f"(arch={cfg.arch_type}, attn_variant={cfg.attn_variant})"
@@ -360,36 +367,32 @@ def prefill_chunk_paged(
     )
     x = embed_tokens(params["embed"], tokens, cfg).astype(dtype_of(cfg.compute_dtype))
     use_kernel = cfg.attn_impl == "pallas"
-    heads = (cfg.n_kv_heads, cfg.d_head)
+    total = jnp.zeros((N_STATS,), jnp.int32)
 
     new_pools: List[Cache] = []
     for spec, gp, pool in zip(layer_groups(cfg), params["groups"], pools):
         assert spec.kind in ("dense", "moe"), spec.kind
-        block = (
-            _dense_block_chunk_paged if spec.kind == "dense"
-            else _moe_block_chunk_paged
-        )
+        lin = None if use_kernel else gather_pool_views(pool, page_table, cfg)
 
-        lin = (
-            (None, None) if use_kernel
-            else gather_pages_stacked(pool["k"], pool["v"], page_table, heads)
-        )
-
-        def body(carry, scanned, _block=block):
-            x, pk, pv = carry
-            bp, layer, lk, lv = scanned
-            return _block(
-                bp, x, positions, valid, pk, pv, page_table, p0,
-                true_len, cfg, n_skip, lk, lv, layer,
-            ), None
+        def body(carry, scanned, _kind=spec.kind):
+            x, pool, st = carry
+            bp, layer, lv = scanned
+            x, pool = _attn_chunk_paged(
+                bp, x, positions, valid, pool, page_table, p0, true_len, cfg,
+                n_skip, lv, layer,
+            )
+            x, s = ffn_serve(_kind, bp, x, cfg, valid)
+            return (x, pool, st if s is None else st + s), None
 
         layers = jnp.arange(spec.n_blocks, dtype=jnp.int32)
-        (x, nk, nv), _ = scan_or_unroll(
-            body, (x, pool["k"], pool["v"]), (gp, layers) + lin, cfg
+        (x, pool, total), _ = scan_or_unroll(
+            body, (x, pool, total), (gp, layers, lin), cfg
         )
-        new_pools.append({"k": nk, "v": nv})
+        new_pools.append(pool)
 
     n = jnp.maximum(true_len, 1)
     last = x[jnp.arange(b), n - 1][:, None, :]
     logits = unembed(params["embed"], last, cfg)
+    if stats:
+        return logits[:, 0], new_pools, total
     return logits[:, 0], new_pools

@@ -6,9 +6,13 @@ compile time O(1) in depth — mandatory for the 96-layer dry-runs):
 
 - ``dense``      — [norm→attn, norm→mlp] ×L             (qwen2, chatglm3, nemotron, musicgen, qwen2-vl)
 - ``moe``        — [norm→attn, norm→moe] ×L             (dbrx, granite)
+- ``dense`` then ``moe`` — leading dense layers before the MoE ones (deepseek-v2-lite)
 - ``gemma_pair`` — [local(SW) block, global block] ×L/2 (gemma2)
 - ``mamba``      — [norm→mamba2] ×L                     (mamba2)
 - ``zamba``      — [period× mamba + shared attn blk] ×G (zamba2; shared weights closed over)
+
+The attention of dense and MoE blocks is GQA (``models/attention.py``) or
+latent attention (``cfg.attn_type == "mla"``, ``models/mla.py``).
 
 Three entry points, all pure functions of (params, inputs):
 - ``forward_full``  — training forward; returns (logits, aux_loss)
@@ -34,7 +38,7 @@ from .attention import (
 )
 from .cache import (
     Cache,
-    gather_pages_stacked,
+    gather_pool_views,
     init_attn_cache,
     init_paged_pool,
     init_ssm_cache,
@@ -56,7 +60,8 @@ from .layers import (
     rms_norm,
     unembed,
 )
-from .moe import init_moe, moe_forward
+from .mla import init_mla, mla_decode_paged, mla_forward
+from .moe import N_STATS, init_moe, moe_forward, moe_serve
 from .pjit_rules import constrain
 from .ssm import init_ssm, init_ssm_state, ssm_decode_step, ssm_forward
 
@@ -86,7 +91,9 @@ def layer_groups(cfg: ModelConfig) -> List[GroupSpec]:
     if cfg.arch_type == "ssm":
         return [GroupSpec("mamba", cfg.n_layers)]
     if cfg.n_experts > 0:
-        return [GroupSpec("moe", cfg.n_layers)]
+        n_dense = cfg.first_dense_layers
+        moe = [GroupSpec("moe", cfg.n_layers - n_dense)]
+        return ([GroupSpec("dense", n_dense)] if n_dense else []) + moe
     return [GroupSpec("dense", cfg.n_layers)]
 
 
@@ -94,12 +101,16 @@ def layer_groups(cfg: ModelConfig) -> List[GroupSpec]:
 # Block init
 # ---------------------------------------------------------------------------
 
+def _init_attn(key, cfg: ModelConfig) -> Params:
+    return init_mla(key, cfg) if cfg.is_mla else init_attention(key, cfg)
+
+
 def _init_dense_block(key, cfg: ModelConfig) -> Params:
     k1, k2 = jax.random.split(key)
     dt = dtype_of(cfg.param_dtype)
     return {
         "norm1": init_rms_norm(cfg.d_model, dt),
-        "attn": init_attention(k1, cfg),
+        "attn": _init_attn(k1, cfg),
         "norm2": init_rms_norm(cfg.d_model, dt),
         "mlp": init_mlp(k2, cfg),
     }
@@ -110,7 +121,7 @@ def _init_moe_block(key, cfg: ModelConfig) -> Params:
     dt = dtype_of(cfg.param_dtype)
     return {
         "norm1": init_rms_norm(cfg.d_model, dt),
-        "attn": init_attention(k1, cfg),
+        "attn": _init_attn(k1, cfg),
         "norm2": init_rms_norm(cfg.d_model, dt),
         "moe": init_moe(k2, cfg),
     }
@@ -169,15 +180,21 @@ def abstract_params(cfg: ModelConfig) -> Params:
 # Block forwards (full sequence)
 # ---------------------------------------------------------------------------
 
+def _attn_full(bp, x, positions, cfg, window, seq_valid):
+    h = rms_norm(x, bp["norm1"], cfg.norm_eps)
+    if cfg.is_mla:
+        return mla_forward(bp["attn"], h, positions, cfg, seq_valid)
+    return attention_forward(
+        bp["attn"], h, positions, cfg, window=window, seq_valid=seq_valid,
+    )
+
+
 def _dense_block_full(bp, x, positions, cfg, window, seq_valid):
     # Megatron-style sequence parallelism: the residual stream (and thus the
     # remat-saved activation) is sequence-sharded when the 'act_seq' rule is
     # bound; GSPMD inserts the gather before attention/MLP matmuls.
     x = constrain(x, "batch", "act_seq", None)
-    h = attention_forward(
-        bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, cfg,
-        window=window, seq_valid=seq_valid,
-    )
+    h = _attn_full(bp, x, positions, cfg, window, seq_valid)
     x = x + h
     x = x + mlp_forward(bp["mlp"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return constrain(x, "batch", "act_seq", None)
@@ -185,10 +202,7 @@ def _dense_block_full(bp, x, positions, cfg, window, seq_valid):
 
 def _moe_block_full(bp, x, positions, cfg, window, seq_valid):
     x = constrain(x, "batch", "act_seq", None)
-    h = attention_forward(
-        bp["attn"], rms_norm(x, bp["norm1"], cfg.norm_eps), positions, cfg,
-        window=window, seq_valid=seq_valid,
-    )
+    h = _attn_full(bp, x, positions, cfg, window, seq_valid)
     x = x + h
     m, aux = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return constrain(x + m, "batch", "act_seq", None), aux
@@ -342,6 +356,7 @@ def make_decode_caches(
 ) -> List[Cache]:
     """Empty caches matching layer_groups(cfg). max_len = total slots for
     full caches; ring caches use their window size."""
+    assert not cfg.is_mla, "latent attention is served from paged pools"
     caches: List[Cache] = []
     for spec in layer_groups(cfg):
         if spec.kind in ("dense", "moe"):
@@ -408,67 +423,64 @@ def _moe_block_decode(bp, x, positions, cache_k, cache_v, kv_pos, cfg, window, r
         bp["attn"], h_in, positions, ck, cv, kv_pos, valid, cfg, window=window
     )
     x = x + h
-    m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
+    m, _ = moe_serve(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
     return x + m, ck, cv
 
 
 def _paged_attn_sublayer(
-    bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k, lin_v, shared_pages=None, layer=None,
+    bp, x, positions, pool, page_table, kv_pos, cfg, window,
+    page_size, lin=None, shared_pages=None, layer=None,
 ):
     """Shared attention sublayer of one paged decode block: scatter the
-    token's K/V into its page cell, then attend through the page table
-    (fused kernel when ``cfg.attn_impl == "pallas"``). With ``layer``, the
-    pools are the group's whole ``(L, P, ps, KV*Dh)`` stack, written and
-    read at that layer in place. On the reference
-    path, callers that hoisted the gather pass the pre-gathered linear
-    views; the new token is inserted into them here (slot == position, with
-    the same at-capacity drop as the pool scatter) so they stay
-    bit-identical to gathering after the scatter."""
+    token's K/V (or, for latent attention, its latent row) into its page
+    cell, then attend through the page table (fused kernel when
+    ``cfg.attn_impl == "pallas"``). ``pool`` is the block's pools by name
+    (``cache.pool_cells``); with ``layer``, each is the group's whole
+    ``(L, P, ps, row)`` stack, written and read at that layer in place.
+    On the reference path, callers that hoisted the gather pass the
+    pre-gathered linear views (``lin``, by the same names); the new token
+    is inserted into them here (slot == position, with the same
+    at-capacity drop as the pool scatter) so they stay bit-identical to
+    gathering after the scatter. Returns (x + attention, pool)."""
     pos1d = positions[0] if positions.ndim == 3 else positions
     h_in = rms_norm(x, bp["norm1"], cfg.norm_eps)
+    if cfg.is_mla:
+        h, kv = mla_decode_paged(
+            bp["attn"], h_in, positions, pool["kv"], page_table, kv_pos, cfg,
+            page_size, None if lin is None else lin["kv"], shared_pages, layer,
+        )
+        return x + h, {"kv": kv}
     k_new, v_new = project_kv_step(bp["attn"], h_in, positions, cfg)
     pk, pv = paged_write_step(
-        pool_k, pool_v, k_new, v_new, pos1d[:, 0], page_table, page_size,
+        pool["k"], pool["v"], k_new, v_new, pos1d[:, 0], page_table, page_size,
         layer,
     )
-    if lin_k is not None:
+    lin_k = lin_v = None
+    if lin is not None:
         bidx = jnp.arange(x.shape[0])
         slot = pos1d[:, 0]
-        lin_k = lin_k.at[bidx, slot].set(k_new[:, 0].astype(lin_k.dtype), mode="drop")
-        lin_v = lin_v.at[bidx, slot].set(v_new[:, 0].astype(lin_v.dtype), mode="drop")
+        lin_k = lin["k"].at[bidx, slot].set(
+            k_new[:, 0].astype(lin["k"].dtype), mode="drop")
+        lin_v = lin["v"].at[bidx, slot].set(
+            v_new[:, 0].astype(lin["v"].dtype), mode="drop")
     h = attention_decode_paged(
         bp["attn"], h_in, positions, pk, pv, page_table, kv_pos, cfg,
         window=window, lin_k=lin_k, lin_v=lin_v, shared_pages=shared_pages,
         layer=layer,
     )
-    return x + h, pk, pv
+    return x + h, {"k": pk, "v": pv}
 
 
-def _dense_block_decode_paged(
-    bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k=None, lin_v=None, shared_pages=None, layer=None,
-):
-    """One layer paged decode. pool_k/v: (P, ps, KV, Dh), or the group's
-    (L, P, ps, KV*Dh) stack with ``layer``."""
-    x, pk, pv = _paged_attn_sublayer(
-        bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-        page_size, lin_k, lin_v, shared_pages, layer,
-    )
-    x = x + mlp_forward(bp["mlp"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
-    return x, pk, pv
-
-
-def _moe_block_decode_paged(
-    bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-    page_size, lin_k=None, lin_v=None, shared_pages=None, layer=None,
-):
-    x, pk, pv = _paged_attn_sublayer(
-        bp, x, positions, pool_k, pool_v, page_table, kv_pos, cfg, window,
-        page_size, lin_k, lin_v, shared_pages, layer,
-    )
-    m, _ = moe_forward(bp["moe"], rms_norm(x, bp["norm2"], cfg.norm_eps), cfg)
-    return x + m, pk, pv
+def ffn_serve(kind: str, bp, x, cfg: ModelConfig, valid=None):
+    """The feed-forward sublayer of a served block, residual added: the
+    dense MLP, or the dropless MoE layer (``moe.moe_serve``) whose counts
+    over the ``valid`` tokens come back beside it (None for a dense
+    block)."""
+    h = rms_norm(x, bp["norm2"], cfg.norm_eps)
+    if kind == "moe":
+        m, stats = moe_serve(bp["moe"], h, cfg, valid)
+        return x + m, stats
+    return x + mlp_forward(bp["mlp"], h, cfg), None
 
 
 def decode_step_paged(
@@ -480,11 +492,16 @@ def decode_step_paged(
     tokens: jnp.ndarray,          # (B,1)
     pos: jnp.ndarray,             # (B,) absolute position of this token
     shared_pages: Optional[jnp.ndarray] = None,  # (S,) common leading pages
-) -> Tuple[jnp.ndarray, List[Cache], jnp.ndarray]:
+    stats: bool = False,
+):
     """serve_step against a *paged* KV pool: the batch's resident KV state
     is the shared page pool plus per-lane page tables sized to actual token
     counts, not B full-width lanes. Full-cache dense/moe groups only (the
-    same family :func:`~repro.models.prefill.supports_append` covers).
+    same family :func:`~repro.models.prefill.supports_append` covers), with
+    GQA or latent attention. Returns ``(logits, pools, kv_pos)``, and with
+    ``stats`` also the MoE layers' counts summed over layers
+    (``moe.N_STATS`` int32: pairs routed, pairs held here, held experts
+    hit) over the lanes whose position lies within the table.
     Pure function; jit with donate_argnums on pools and kv_pos.
 
     The layer scan carries each group's ``(L, P, ps, KV*Dh)`` pools and
@@ -515,7 +532,7 @@ def decode_step_paged(
         jnp.broadcast_to(pos1, (3, b, 1)) if cfg.rope_style == "mrope" else pos1
     )
     x = embed_tokens(params["embed"], tokens, cfg).astype(dtype_of(cfg.compute_dtype))
-    page_size = pools[0]["k"].shape[2]
+    page_size = next(iter(pools[0].values())).shape[2]
     # drop-mode update: a lane at table capacity keeps its last slot intact
     # instead of relabeling it with the overflow position (the K/V write is
     # likewise dropped — see paged_write_step)
@@ -523,38 +540,35 @@ def decode_step_paged(
         pos.astype(jnp.int32), mode="drop"
     )
     use_kernel = cfg.attn_impl == "pallas"
-    heads = (cfg.n_kv_heads, cfg.d_head)
+    valid = (pos < page_table.shape[1] * page_size)[:, None]
+    total = jnp.zeros((N_STATS,), jnp.int32)
 
     new_pools: List[Cache] = []
     for spec, gp, pool in zip(layer_groups(cfg), params["groups"], pools):
         assert spec.kind in ("dense", "moe"), (
             f"paged decode requires full-cache dense/moe groups, got {spec.kind}"
         )
-        block_fn = (
-            _dense_block_decode_paged if spec.kind == "dense"
-            else _moe_block_decode_paged
-        )
+        lin = None if use_kernel else gather_pool_views(pool, page_table, cfg)
 
-        lin = (
-            (None, None) if use_kernel
-            else gather_pages_stacked(pool["k"], pool["v"], page_table, heads)
-        )
-
-        def body(carry, scanned, _fn=block_fn):
-            x, pk, pv = carry
-            bp, layer, lk, lv = scanned
-            return _fn(
-                bp, x, positions, pk, pv, page_table, new_kv_pos, cfg,
-                0, page_size, lk, lv, shared_pages, layer,
-            ), None
+        def body(carry, scanned, _kind=spec.kind):
+            x, pool, st = carry
+            bp, layer, lv = scanned
+            x, pool = _paged_attn_sublayer(
+                bp, x, positions, pool, page_table, new_kv_pos, cfg, 0,
+                page_size, lv, shared_pages, layer,
+            )
+            x, s = ffn_serve(_kind, bp, x, cfg, valid)
+            return (x, pool, st if s is None else st + s), None
 
         layers = jnp.arange(spec.n_blocks, dtype=jnp.int32)
-        (x, nk, nv), _ = scan_or_unroll(
-            body, (x, pool["k"], pool["v"]), (gp, layers) + lin, cfg
+        (x, pool, total), _ = scan_or_unroll(
+            body, (x, pool, total), (gp, layers, lin), cfg
         )
-        new_pools.append({"k": nk, "v": nv})
+        new_pools.append(pool)
 
     logits = unembed(params["embed"], x, cfg)
+    if stats:
+        return logits, new_pools, new_kv_pos, total
     return logits, new_pools, new_kv_pos
 
 
@@ -566,7 +580,9 @@ def decode_step(
     pos: jnp.ndarray,             # (B,) absolute position of this token
 ) -> Tuple[jnp.ndarray, List[Cache]]:
     """serve_step: one new token, updated caches. Pure function; jit with
-    donate_argnums on caches."""
+    donate_argnums on caches. GQA models only: latent attention is served
+    from paged pools (:func:`decode_step_paged`)."""
+    assert not cfg.is_mla, "latent attention is served from paged pools"
     b = tokens.shape[0]
     pos1 = pos[:, None].astype(jnp.int32)                    # (B,1)
     positions = (

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..models import ModelConfig
 from ..models.prefill import prefill_chunk_paged
 from .paged_kv import PagedKVAllocator
@@ -43,6 +44,7 @@ def _chunk_fn(cfg: ModelConfig):
     def fn(params, pools, table, tokens, p0, true_len, n_skip):
         return prefill_chunk_paged(
             params, cfg, pools, table, tokens, p0, true_len, n_skip=n_skip,
+            stats=True,
         )
 
     return fn
@@ -58,9 +60,11 @@ def _pow2(n: int) -> int:
 class PagedPrefiller:
     """Runs chunked paged prefill against one allocator's page pool.
 
-    Stateless between calls except for the jit cache; the caller owns page
-    allocation, sharing/refcounts, and chunk scheduling — this class only
-    moves tokens into pages and returns last-valid-position logits."""
+    Stateless between calls except for the jit cache and, while the tracer
+    is on, the chunks' MoE counts summed on the device (:meth:`take_counts`);
+    the caller owns page allocation, sharing/refcounts, and chunk
+    scheduling — this class only moves tokens into pages and returns
+    last-valid-position logits."""
 
     def __init__(
         self, cfg: ModelConfig, params, allocator: PagedKVAllocator
@@ -69,6 +73,13 @@ class PagedPrefiller:
         self.params = params
         self.alloc = allocator
         self._fn = _chunk_fn(cfg)
+        self._counts = None
+
+    def take_counts(self):
+        """The MoE counts of the chunks run since the last call while the
+        tracer was on, as a device array (None without any), and reset."""
+        counts, self._counts = self._counts, None
+        return counts
 
     def run_chunk(
         self,
@@ -101,10 +112,12 @@ class PagedPrefiller:
         # host-built inputs go straight to the pool's device
         host = (table[None, :], toks, np.array([p0], np.int32),
                 np.array([c], np.int32), np.int32(n_skip))
-        logits, pools = self._fn(
+        logits, pools, counts = self._fn(
             self.params, alloc.pools, *jax.device_put(host, alloc.device)
         )
         alloc.pools = pools
+        if tracing.enabled() and self.cfg.n_experts:
+            self._counts = counts if self._counts is None else self._counts + counts
         return logits[0]
 
     def prefill_ids(
@@ -133,6 +146,27 @@ class PagedPrefiller:
 
 
 def prime_fill_pages(
+    pool,
+    prefiller: PagedPrefiller,
+    token_ids: Sequence[int],
+    entry,
+    usable: int,
+    shipped: Optional[Sequence[bytes]] = None,
+    ship_have: int = 0,
+) -> Optional[List[int]]:
+    """:func:`_fill_pages`, traced as ``serving.latent_page_install``
+    (``tracing.LATENT_PAGE_INSTALL``) where the pages are latent (MLA):
+    the install of shipped pages and the prime of recomputed ones."""
+    if not prefiller.cfg.is_mla:
+        return _fill_pages(pool, prefiller, token_ids, entry, usable,
+                           shipped, ship_have)
+    with tracing.span(tracing.LATENT_PAGE_INSTALL) as sp:
+        sp.set("shipped", len(shipped) if shipped is not None else 0)
+        return _fill_pages(pool, prefiller, token_ids, entry, usable,
+                           shipped, ship_have)
+
+
+def _fill_pages(
     pool,
     prefiller: PagedPrefiller,
     token_ids: Sequence[int],

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import ModelConfig, layer_groups, supports_append
-from ..models.cache import init_paged_pool
+from ..models.cache import init_paged_pool, pool_cells
 # Canonical digest lives with the KV-ship wire protocol (jax-free store
 # layer) and is re-exported here for the original PR-7 callers.
 from ..store.kv_ship import page_digests  # noqa: F401  (re-export)
@@ -164,11 +164,11 @@ class PagedKVAllocator:
 
     @property
     def page_bytes(self) -> int:
-        """Bytes of one physical page across all layers/groups (k + v)."""
+        """Bytes of one physical page across all layers/groups (k + v, or
+        the latent rows)."""
         total = 0
         for pool in self.pools:
-            for name in ("k", "v"):
-                a = pool[name]
+            for a in pool.values():
                 total += (a.size // a.shape[1]) * a.dtype.itemsize
         return total
 
@@ -262,16 +262,13 @@ class PagedKVAllocator:
             def fn(pools, dense, table):
                 out = []
                 for pool, c in zip(pools, dense):
-                    l = c["k"].shape[0]
-                    chunk_shape = (l, -1, self.page_size) + pool["k"].shape[3:]
-                    out.append({
-                        "k": pool["k"].at[:, table].set(
-                            c["k"][:, 0].reshape(chunk_shape).astype(pool["k"].dtype)
-                        ),
-                        "v": pool["v"].at[:, table].set(
-                            c["v"][:, 0].reshape(chunk_shape).astype(pool["v"].dtype)
-                        ),
-                    })
+                    entry = {}
+                    for name, a in pool.items():
+                        shape = (a.shape[0], -1, self.page_size) + a.shape[3:]
+                        entry[name] = a.at[:, table].set(
+                            c[name][:, 0].reshape(shape).astype(a.dtype)
+                        )
+                    out.append(entry)
                 return out
 
             self._scatter_fns[width] = fn
@@ -286,15 +283,12 @@ class PagedKVAllocator:
                 kv_pos = jnp.where(j < n_valid, j, -1)[None, :]
                 out = []
                 for pool in pools:
-                    l = pool["k"].shape[0]
-                    k = pool["k"][:, table]          # (L, MP, ps, KV*Dh)
-                    v = pool["v"][:, table]
-                    flat = (l, 1, width, self.cfg.n_kv_heads, self.cfg.d_head)
-                    out.append({
-                        "k": k.reshape(flat),
-                        "v": v.reshape(flat),
-                        "kv_pos": kv_pos,
-                    })
+                    entry = {}
+                    for name, cell in pool_cells(self.cfg).items():
+                        a = pool[name][:, table]     # (L, MP, ps, row)
+                        entry[name] = a.reshape((a.shape[0], 1, width) + cell)
+                    entry["kv_pos"] = kv_pos
+                    out.append(entry)
                 return out
 
             self._gather_fns[width] = fn
@@ -315,10 +309,7 @@ class PagedKVAllocator:
             @partial(jax.jit, donate_argnums=(0,))
             def fn(pools, s, d):
                 return [
-                    {
-                        "k": pool["k"].at[:, d].set(pool["k"][:, s]),
-                        "v": pool["v"].at[:, d].set(pool["v"][:, s]),
-                    }
+                    {n: a.at[:, d].set(a[:, s]) for n, a in pool.items()}
                     for pool in pools
                 ]
 
@@ -330,16 +321,17 @@ class PagedKVAllocator:
     def export_page_bytes(self, page: int) -> bytes:
         """Serialize one physical page's bytes for shipping: per layer
         group, the K block then the V block, each ``(L, page_size, KV*Dh)``
-        (the bytes of ``(L, page_size, KV, Dh)``) in the pool's native
-        dtype, C order, concatenated. The native dtype (bf16 for the
+        (the bytes of ``(L, page_size, KV, Dh)``) — or, for latent pages,
+        the one ``(L, page_size, kv_lora_rank + qk_rope_dim)`` block — in
+        the pool's native dtype, C order, concatenated. The native dtype (bf16 for the
         serving configs) makes the round trip bit-exact:
         ``import_page_bytes`` on an identically-configured pool reproduces
         the page byte-for-byte, so a shipped prime is greedy-equivalent to
         the local recompute it replaces."""
         parts: List[bytes] = []
         for pool in self.pools:
-            for name in ("k", "v"):
-                parts.append(np.asarray(pool[name][:, page]).tobytes())
+            for a in pool.values():
+                parts.append(np.asarray(a[:, page]).tobytes())
         return b"".join(parts)
 
     def import_page_bytes(self, page: int, data: bytes) -> None:
@@ -353,9 +345,8 @@ class PagedKVAllocator:
         blocks: List[Dict[str, np.ndarray]] = []
         for pool in self.pools:
             entry: Dict[str, np.ndarray] = {}
-            for name in ("k", "v"):
-                a = pool[name]
-                shape = (a.shape[0],) + tuple(a.shape[2:])  # (L, ps, KV*Dh)
+            for name, a in pool.items():
+                shape = (a.shape[0],) + tuple(a.shape[2:])  # (L, ps, row)
                 n_bytes = int(np.prod(shape)) * a.dtype.itemsize
                 entry[name] = np.frombuffer(
                     data[off : off + n_bytes], dtype=a.dtype
@@ -370,7 +361,7 @@ class PagedKVAllocator:
             @partial(jax.jit, donate_argnums=(0,))
             def fn(pools, blocks, d):
                 return [
-                    {n: pool[n].at[:, d].set(b[n]) for n in ("k", "v")}
+                    {n: a.at[:, d].set(b[n]) for n, a in pool.items()}
                     for pool, b in zip(pools, blocks)
                 ]
 
@@ -388,7 +379,7 @@ class PagedKVAllocator:
         redirected to the scratch page): shared prefix pages are read-only
         for every sharer — that is the copy-on-write guarantee — and their
         bytes are already exactly what the dense lane holds there."""
-        width = int(dense[0]["k"].shape[2])
+        width = int(next(iter(dense[0].values())).shape[2])
         table = self.table_for(pages, width)
         table[: min(n_skip, len(pages))] = SCRATCH_PAGE
         self.pools = self._scatter_fn(width)(self.pools, dense, jnp.asarray(table))

@@ -101,6 +101,7 @@ def _server_fns(cfg: ModelConfig, max_len: int):
                      shared_pages=None):
         return decode_step_paged(
             params, cfg, pools, table, kv_pos, tokens, pos, shared_pages,
+            stats=True,
         )
 
     return prefill_one, append_one, decode, decode_paged
@@ -167,6 +168,7 @@ class BatchedServer:
         assert cfg.attn_variant == "full" and cfg.arch_type in ("dense", "moe", "vlm"), (
             "batched server currently supports full-cache attention archs"
         )
+        assert paged or not cfg.is_mla, "latent attention is served paged"
         # params, caches and page pool are committed to one device, so the
         # jitted prefill and decode steps run there (one chip per node in a
         # multi-chip fleet); host-built step inputs are put there directly
@@ -236,7 +238,10 @@ class BatchedServer:
             ))
 
         (self._prefill_one, self._append_one, self._decode,
-         self._decode_paged) = _server_fns(cfg, max_len)
+         self._decode_paged_counted) = _server_fns(cfg, max_len)
+        # the paged decode step's MoE counts (models.moe.N_STATS), left by
+        # the last call of _decode_paged for the step's sync to read
+        self._step_counts = None
         # host-side so mid-prefill lanes can be excluded from decode writes
         # (their entry is pushed past the trimmed table per step) without a
         # device round-trip per lane
@@ -244,6 +249,12 @@ class BatchedServer:
 
     def _put(self, x):
         return jax.device_put(x, self.device)
+
+    def _decode_paged(self, *args):
+        """The jitted paged decode step: (logits, pools, kv_pos). Its MoE
+        counts stay behind in ``_step_counts``."""
+        logits, pools, kv_pos, self._step_counts = self._decode_paged_counted(*args)
+        return logits, pools, kv_pos
 
     # ------------------------------------------------------------------
     def submit(
@@ -837,7 +848,16 @@ class BatchedServer:
         if chunks:
             tracing.count("chunk_decode_steps")
         with tracing.span("scheduler.sync"):
-            nxt = np.asarray(sample(logits[:, 0]))
+            if self.paged and self.cfg.n_experts and tracing.enabled():
+                # the MoE counts ride the step's own sync
+                nxt, *counts = jax.device_get((
+                    sample(logits[:, 0]), self._step_counts,
+                    self._prefiller.take_counts()))
+                for c in counts:
+                    if c is not None:
+                        tracing.count_moe(c)
+            else:
+                nxt = np.asarray(sample(logits[:, 0]))
 
         with tracing.span("scheduler.finish"):
             for idx, st in enumerate(self.slots):

@@ -294,3 +294,111 @@ def test_kernel_names_in_a_decode_step_and_a_chunk(chip_compile, monkeypatch):
         _s((1,), i32),
     )
     assert "chunked_prefill" in names(chunk)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (DeepSeek-V2-Lite): one shared latent head per token
+# (576 lanes, 640 in the pool), 16 query heads, the value the row's
+# leading 512 lanes
+# ---------------------------------------------------------------------------
+
+MLA = "deepseek-v2-lite"
+
+
+def _mla_dims():
+    """The config, its heads, the pool's row (576 lanes padded to 640),
+    the value lanes and the softmax scale."""
+    from repro.configs import get_config
+    from repro.models.cache import latent_row
+    from repro.models.mla import softmax_scale
+
+    cfg = get_config(MLA)
+    return cfg, cfg.n_heads, latent_row(cfg), cfg.kv_lora_rank, softmax_scale(cfg)
+
+
+@pytest.mark.parametrize("shared", [0, 8])
+def test_mla_paged_decode_compiles(chip_compile, shared):
+    """The latent decode kernel, and with a shared run the cascade pass
+    beside it, at 4 lanes and at the published widths."""
+    from repro.kernels.paged_attention import paged_attention
+
+    _, h, w, r, scale = _mla_dims()
+    i32 = jnp.int32
+    fn = functools.partial(paged_attention, interpret=False, scale=scale, v_width=r)
+    shapes = [_s((B, 1, h, w)), _s((N_PAGES, PS, 1, w)), None, _s((B, MP), i32),
+              _s((B, 1), i32), _s((B, MP * PS), i32)]
+    if shared:
+        shapes.append(_s((shared,), i32))
+    compiled = chip_compile(fn, *shapes)
+    assert compiled.as_text().count("tpu_custom_call") >= (2 if shared else 1)
+
+
+@pytest.mark.parametrize("chunk", [64, 256])
+def test_mla_chunked_prefill_compiles(chip_compile, chunk):
+    """A latent prefill chunk at the step's 64-token budget and at the
+    256-token cap, where its 4096 query rows are split into row blocks."""
+    from repro.kernels.chunked_prefill import chunked_prefill_attention
+
+    _, h, w, r, scale = _mla_dims()
+    i32 = jnp.int32
+    fn = functools.partial(chunked_prefill_attention, interpret=False,
+                           scale=scale, v_width=r)
+    _assert_kernel(chip_compile(
+        fn, _s((1, chunk, h, w)), _s((N_PAGES, PS, 1, w)), None,
+        _s((1, MP), i32), _s((1,), i32), _s((1,), i32),
+    ))
+
+
+def test_mla_moe_steps_compile_and_fit_v5e(chip_compile, monkeypatch):
+    """DeepSeek-V2-Lite's paged decode step (with a shared run) and a
+    64-token chunk step at two layers (the dense layer and one MoE layer),
+    with the kernels under their names; then the whole 27-layer decode
+    step, against one node's pool of 1537 pages, fits one chip and
+    updates the latent pool in place, copying none of it."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import decode_step_paged, init_params
+    from repro.models.prefill import prefill_chunk_paged
+
+    _steer_to_mosaic(monkeypatch)
+    i32 = jnp.int32
+    n_slots, max_len = 4, 2048
+    mp = max_len // PS
+
+    def names(compiled):
+        return set(re.findall(r"%([a-z_]+)\.\d+ = [^\n]*custom-call\(",
+                              compiled.as_text()))
+
+    for n_layers, n_pages in ((2, 1 + 12 * 8), (27, 1537)):
+        cfg = get_config(MLA).replace(attn_impl="pallas", n_layers=n_layers)
+        params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+        pools = _pool_shapes(cfg, n_pages)
+        decode = chip_compile(
+            lambda p, pl_, t, kp, tok, pos, sp: decode_step_paged(
+                p, cfg, pl_, t, kp, tok, pos, sp, stats=True),
+            params, pools, _s((n_slots, mp), i32), _s((n_slots, max_len), i32),
+            _s((n_slots, 1), i32), _s((n_slots,), i32), _s((2,), i32),
+            donate_argnums=(1, 3),
+        )
+        if n_layers == 2:
+            assert {"paged_attention", "shared_prefix"} <= names(decode)
+            chunk = chip_compile(
+                lambda p, pl_, t, tok, p0, n: prefill_chunk_paged(
+                    p, cfg, pl_, t, tok, p0, n, stats=True),
+                params, pools, _s((1, mp), i32), _s((1, 64), i32),
+                _s((1,), i32), _s((1,), i32),
+                donate_argnums=(1,),
+            )
+            assert "chunked_prefill" in names(chunk)
+            continue
+        mem = decode.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        assert total < V5E_HBM_BYTES, total
+        assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+        # no copy of a layer's pool: a 576-lane row would be stored with
+        # the page axis innermost and copied to row-major every step
+        layer_bytes = pools[1]["kv"].size // n_layers * 2
+        assert mem.temp_size_in_bytes < layer_bytes, mem.temp_size_in_bytes
