@@ -15,11 +15,14 @@ off and returns a :class:`Trace` of what was recorded in between:
 - counters: named integers, incremented where the work happens. The MoE
   layers' counters (:data:`MOE_COUNTERS`, through :func:`count_moe`) come
   from a small int array each decode step and prefill chunk returns beside
-  its logits, read at the serving step's own sync while the tracer is on:
-  (token, expert) pairs routed, pairs that reached an expert held on this
-  device, and held experts reached by at least one token, each summed over
-  the MoE layers (the last is what a kernel that reads only the experts a
-  step hits would read).
+  its logits, read while the tracer is on at the next serving step's sync
+  (or when the server falls idle or crashes): (token, expert) pairs
+  routed, pairs that reached an expert held on this device, and held
+  experts reached by at least one token, each summed over the MoE layers
+  (the last is what a kernel that reads only the experts a step hits
+  would read). ``host_paced_steps`` (:data:`HOST_PACED_STEPS`) counts the
+  paged decode steps whose input tokens were ready on the device when the
+  step came to read them: the host, not the chip, set their pace.
 
 While on, every span also opens a ``jax.profiler.TraceAnnotation`` named
 ``discedge.<span name>`` (followed by the node id, where there is one). A
@@ -262,6 +265,7 @@ def count(name: str, n: int = 1) -> None:
 
 
 MOE_COUNTERS = ("moe_routed_pairs", "moe_held_pairs", "moe_experts_hit")
+HOST_PACED_STEPS = "host_paced_steps"
 # the span around installing shipped or primed latent (MLA) pages
 LATENT_PAGE_INSTALL = "serving.latent_page_install"
 

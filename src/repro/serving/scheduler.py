@@ -99,12 +99,22 @@ def _server_fns(cfg: ModelConfig, max_len: int):
     @partial(jax.jit, donate_argnums=(1, 3))
     def decode_paged(params, pools, table, kv_pos, tokens, pos,
                      shared_pages=None):
-        return decode_step_paged(
+        logits, pools, kv_pos, counts = decode_step_paged(
             params, cfg, pools, table, kv_pos, tokens, pos, shared_pages,
             stats=True,
         )
+        # the next step's input tokens, sampled in the same program
+        return logits, pools, kv_pos, counts, sample(logits[:, 0])[:, None]
 
     return prefill_one, append_one, decode, decode_paged
+
+
+@jax.jit
+def _set_first_token(tokens, lane, logits):
+    """Write the argmax of a completed plan's last-chunk logits into
+    ``lane`` of the device token vector (one program per vocabulary: the
+    lane is traced)."""
+    return tokens.at[lane, 0].set(jnp.argmax(logits).astype(jnp.int32))
 
 
 @dataclass
@@ -239,9 +249,17 @@ class BatchedServer:
 
         (self._prefill_one, self._append_one, self._decode,
          self._decode_paged_counted) = _server_fns(cfg, max_len)
-        # the paged decode step's MoE counts (models.moe.N_STATS), left by
-        # the last call of _decode_paged for the step's sync to read
-        self._step_counts = None
+        # the paged decode step's MoE counts (models.moe.N_STATS) and its
+        # sampled tokens, left by the last call of _decode_paged
+        self._step_counts = self._step_sampled = None
+        if paged:
+            # the paged step's input tokens, (n_slots, 1) on the device and
+            # written only by the step: a decode's sampled tokens, and a
+            # completed plan's first token (docs/architecture.md, "The
+            # paged step's pipeline"); _next_tok mirrors what a step read
+            self._tokens = self._put(np.zeros((n_slots, 1), np.int32))
+            # MoE counts of dispatched work not yet read (tracer on)
+            self._moe_unread: List = []
         # host-side so mid-prefill lanes can be excluded from decode writes
         # (their entry is pushed past the trimmed table per step) without a
         # device round-trip per lane
@@ -252,8 +270,10 @@ class BatchedServer:
 
     def _decode_paged(self, *args):
         """The jitted paged decode step: (logits, pools, kv_pos). Its MoE
-        counts stay behind in ``_step_counts``."""
-        logits, pools, kv_pos, self._step_counts = self._decode_paged_counted(*args)
+        counts and sampled tokens stay behind in ``_step_counts`` and
+        ``_step_sampled`` for the caller to take at once."""
+        (logits, pools, kv_pos, self._step_counts,
+         self._step_sampled) = self._decode_paged_counted(*args)
         return logits, pools, kv_pos
 
     # ------------------------------------------------------------------
@@ -611,8 +631,10 @@ class BatchedServer:
             if not st.pending:
                 self._prefill_fifo.pop(0)
                 st.prefilled = True
-                with tracing.span("scheduler.sync", requests=st.trace_id):
-                    self._next_tok[idx] = int(jnp.argmax(logits))
+                # the first token stays on the device: this step's decode
+                # reads it there, and the step reads it back after that
+                self._tokens = _set_first_token(
+                    self._tokens, np.int32(idx), logits)
                 # kv_pos becomes real only now: slot == position for the
                 # whole prompt, invalid beyond (layout invariant)
                 self._kv_pos = self._kv_pos.at[idx].set(
@@ -789,11 +811,19 @@ class BatchedServer:
         mode keeps the classic loop: admission prefills in one shot and
         every occupied slot decodes.
 
+        The paged step never waits for the decode it dispatches: the
+        decode samples its next tokens on the device, and the step reads
+        back only the decode's *input* tokens, which earlier work made
+        (the previous decode's samples, or a completed plan's first
+        token). Those are the tokens each lane reports this step, so the
+        stop checks run on the host a step behind the decode while the
+        decode runs on.
+
         Traced as ``scheduler.step`` with children for admission, each
         prefill chunk, the decode inputs, the decode dispatch, the host
-        sync and the finish loop; a step that ends before the sync (every
-        occupied lane mid-prefill) leaves its device work to a later one
-        and counts in ``unsynced_steps``."""
+        sync (the wait for earlier work) and the finish loop; a step that
+        ends before the sync (every occupied lane mid-prefill) leaves its
+        device work to a later one and counts in ``unsynced_steps``."""
         with tracing.span("scheduler.step", self.node_id) as sp:
             synced = self._step(sp)
             sp.set("synced", synced)
@@ -817,12 +847,14 @@ class BatchedServer:
             if st is not None:
                 st.batch_size = max(st.batch_size, n_active)
 
+        nxt = None
         if self.paged:
             with tracing.span("scheduler.decode_inputs"):
                 inputs = self._decode_inputs()
             if inputs is None:
                 return False  # every occupied lane is mid-prefill
-            n_ready, trimmed, wp, table, tokens, dec_pos, sp_pages = inputs
+            n_ready, trimmed, wp, table, dec_pos, sp_pages = inputs
+            tokens = self._tokens
             with tracing.span("scheduler.decode"):
                 if trimmed:
                     logits, pools, kvp = self._decode_paged(
@@ -836,6 +868,7 @@ class BatchedServer:
                         self._kv_pos, tokens, dec_pos, sp_pages,
                     )
                 self.allocator.pools = pools
+                self._tokens = self._step_sampled
         else:
             n_ready = n_active
             with tracing.span("scheduler.decode"):
@@ -848,14 +881,10 @@ class BatchedServer:
         if chunks:
             tracing.count("chunk_decode_steps")
         with tracing.span("scheduler.sync"):
-            if self.paged and self.cfg.n_experts and tracing.enabled():
-                # the MoE counts ride the step's own sync
-                nxt, *counts = jax.device_get((
-                    sample(logits[:, 0]), self._step_counts,
-                    self._prefiller.take_counts()))
-                for c in counts:
-                    if c is not None:
-                        tracing.count_moe(c)
+            if self.paged:
+                # the decode just dispatched runs on while the host reads
+                # its input, which earlier work produced
+                self._next_tok[:] = self._read_tokens(tokens)[:, 0]
             else:
                 nxt = np.asarray(sample(logits[:, 0]))
 
@@ -873,18 +902,49 @@ class BatchedServer:
                     or st.pos >= self.max_len - 1
                 ):
                     self._finish_slot(idx, st)
-                else:
+                elif nxt is not None:
                     self._next_tok[idx] = int(nxt[idx])
+        if self.paged and not self.busy:
+            self._read_moe_counts()
         return True
+
+    def _read_tokens(self, tokens):
+        """The one read of a paged decode step: its input tokens, with the
+        MoE counts of the work dispatched before it while the tracer is on
+        (this decode's counts wait for the next read)."""
+        if not tracing.enabled():
+            self._moe_unread = []
+            return jax.device_get(tokens)
+        if tokens.is_ready():
+            # the chip had finished the step's inputs before the host came
+            # for them: the host, not the chip, set this step's pace
+            tracing.count(tracing.HOST_PACED_STEPS)
+        if not self.cfg.n_experts:
+            return jax.device_get(tokens)
+        earlier = self._moe_unread + [self._prefiller.take_counts()]
+        self._moe_unread = [self._step_counts]
+        got, counts = jax.device_get(
+            (tokens, [c for c in earlier if c is not None]))
+        for c in counts:
+            tracing.count_moe(c)
+        return got
+
+    def _read_moe_counts(self) -> None:
+        """Read the MoE counts still unread (tracer on): when the server
+        falls idle or crashes, no later step reads them."""
+        if self._moe_unread and tracing.enabled():
+            for c in jax.device_get(self._moe_unread):
+                tracing.count_moe(c)
+        self._moe_unread = []
 
     def _decode_inputs(self):
         """Paged decode inputs: grow each decode-ready lane's pages to
         cover the position it writes, bucket the page width, push
         mid-prefill lanes out of the step, find the shared-prefix run, and
-        put the table, tokens and positions on the device. None when no
-        lane is decode-ready; else the ready count, whether the width is
-        trimmed below the table's, the trimmed width in slots, and the
-        decode call's inputs."""
+        put the table and positions on the device. None when no lane is
+        decode-ready; else the ready count, whether the width is trimmed
+        below the table's, the trimmed width in slots, and the decode
+        call's host-built inputs (the tokens are already on the device)."""
         # grow-on-demand: each decode-ready slot needs a page covering
         # the position it is about to write; a slot that cannot get one
         # (pool exhausted, nothing evictable) retires cleanly with the
@@ -941,9 +1001,11 @@ class BatchedServer:
             if run:
                 sp = self._put(np.asarray(run, np.int32))
                 tracing.count("cascade_steps")
-        table = self._put(self._table[:, :w] if w < mp else self._table)
-        tokens = self._put(self._next_tok[:, None])
-        return len(ready), w < mp, wp, table, tokens, self._put(dec_pos), sp
+        # a copy: the host edits the table while this step's decode may
+        # still read it, and a put may alias the host array (the CPU's
+        # zero-copy put of an aligned array does)
+        table = self._put(self._table[:, :w].copy())
+        return len(ready), w < mp, wp, table, self._put(dec_pos), sp
 
     # -- churn -----------------------------------------------------------
     def crash(self) -> None:
@@ -960,6 +1022,7 @@ class BatchedServer:
         if self.paged:
             self._table[:, :] = SCRATCH_PAGE
             self._prefill_fifo.clear()
+            self._read_moe_counts()
         if self.session_pool is not None:
             self.session_pool.clear()
         self.finished.clear()

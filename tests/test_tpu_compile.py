@@ -258,6 +258,43 @@ def test_paged_steps_update_pool_in_place(chip_compile, monkeypatch):
         assert mem.alias_size_in_bytes >= pool_bytes, (name, mem.alias_size_in_bytes)
 
 
+def test_served_decode_program_samples_and_updates_pool_in_place(
+        chip_compile, monkeypatch):
+    """The batched server's own paged decode program for the paper's
+    model, against the same pool of 1537 pages with the pools and kv_pos
+    donated, returns the next step's tokens, ``(n_slots, 1)`` int32, from
+    the same program, and still aliases the donated pools to its outputs
+    with scratch below one layer's K pool."""
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.serving.scheduler import _server_fns
+
+    _steer_to_mosaic(monkeypatch)
+    cfg = get_config("paper-qwen1.5-0.5b").replace(attn_impl="pallas")
+    n_slots, max_len, n_pages = 4, 2048, 1537
+    mp = max_len // PS
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    pools = _pool_shapes(cfg, n_pages)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pools))
+    layer_k_bytes = n_pages * PS * cfg.n_kv_heads * cfg.d_head * 2
+    decode_paged = _server_fns(cfg, max_len)[3]
+    i32 = jnp.int32
+    compiled = chip_compile(
+        lambda p, pl_, t, kp, tok, pos: decode_paged(p, pl_, t, kp, tok, pos),
+        params, pools, _s((n_slots, mp), i32), _s((n_slots, max_len), i32),
+        _s((n_slots, 1), i32), _s((n_slots,), i32),
+        donate_argnums=(1, 3),
+    )
+    logits, out_pools, kv_pos, counts, tokens = compiled.out_info
+    assert (tokens.shape, tokens.dtype) == ((n_slots, 1), jnp.int32)
+    assert logits.shape == (n_slots, 1, cfg.vocab_size)
+    assert jax.tree.map(lambda a: a.shape, out_pools) == jax.tree.map(
+        lambda a: a.shape, pools)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_k_bytes, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= pool_bytes, mem.alias_size_in_bytes
+
+
 def test_kernel_names_in_a_decode_step_and_a_chunk(chip_compile, monkeypatch):
     """The paged-attention, shared-prefix and chunked-prefill kernels run
     under names they give themselves, so a device trace finds them by the
